@@ -1223,20 +1223,6 @@ def _native_env() -> str:
         f"ANOMOD_NATIVE must be auto, on/1 or off/0, got {raw!r}")
 
 
-def _jit_cache_env() -> bool:
-    """ANOMOD_JIT_CACHE: persistent XLA compilation cache switch.
-
-    When on AND ``ANOMOD_CACHE_DIR`` caching is enabled, the serve/bench
-    entry points point jax's persistent compilation cache at
-    ``<cache_dir>/jit`` (anomod.utils.platform.enable_jit_cache), so a
-    warm restart skips the (width x lane-bucket) compile wall — and the
-    2nd..Nth shard's identical-HLO grids compile once, not N times.
-    Default OFF: mutating global jax config is an operator opt-in.
-    """
-    return _env("ANOMOD_JIT_CACHE", "0").strip().lower() \
-        not in ("0", "false", "off", "no", "")
-
-
 def _obs_http_env() -> bool:
     """ANOMOD_OBS_HTTP: embedded /metrics endpoint plane
     (anomod.obs.http).
@@ -1576,9 +1562,6 @@ class Config:
     # loads), on (required, fail loud with the build reason), off
     # (pure-Python paths; anomod.io.native).
     native: str = dataclasses.field(default_factory=_native_env)
-    # ANOMOD_JIT_CACHE — persistent XLA compilation cache under
-    # ANOMOD_CACHE_DIR/jit (anomod.utils.platform.enable_jit_cache).
-    jit_cache: bool = dataclasses.field(default_factory=_jit_cache_env)
     # ANOMOD_SERVE_MAX_BACKLOG — global admission backlog bound in spans
     # (anomod.serve.queues; the backpressure/shed budget).
     serve_max_backlog: int = dataclasses.field(

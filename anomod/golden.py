@@ -477,7 +477,7 @@ def format_markdown(report: dict) -> str:
         "",
         "Generated by `anomod golden` against the shipped checkout "
         "(`/root/reference`); regenerate with "
-        "`ANOMOD_PLATFORM=cpu anomod golden --markdown`.  Pinned by "
+        "`JAX_PLATFORMS=cpu anomod golden --markdown`.  Pinned by "
         "`tests/test_golden.py`.",
         "",
         "## Loadability census (typed loaders, synth fallback disabled)",

@@ -12,10 +12,6 @@ the XLA scatter-max path (anomod.ops.hll / anomod.replay hll plane).
 from __future__ import annotations
 
 
-from anomod.ops.compat import tpu_compiler_params as _compiler_params
-
-
-
 def make_pallas_hll_fn(p: int = 10, block: int = 2048, interpret: bool = False):
     """Returns fn(items int32 [N]) -> registers int32 [2^p]; N % block == 0."""
     import jax
@@ -69,7 +65,7 @@ def make_pallas_hll_fn(p: int = 10, block: int = 2048, interpret: bool = False):
             in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
             out_specs=pl.BlockSpec((m,), lambda i: (0,)),
             out_shape=jax.ShapeDtypeStruct((m,), jnp.int32),
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(items)

@@ -5,10 +5,10 @@ hi/lo moment split, one-hot construction, histogram bucketing, and the MXU
 matmul — into one kernel whose [F+H, SW+1] accumulator stays VMEM-resident
 across the entire grid (state never round-trips to HBM between blocks).
 
-Measured on v5e (30.4M-span replicated TT corpus, block sweep 1024-8192 all
-within 3%): **3.0e8 spans/sec/chip vs 2.5e8 for the XLA scan path** — the
-hand-written kernel is the fast path and the bench default on TPU
-(``ANOMOD_BENCH_KERNEL`` overrides).
+Builder-side capture of 2026-07-30 (before PR 1; 30.4M-span replicated TT
+corpus, block sweep 1024-8192 all within 3%): 3.0e8 spans/sec/chip vs
+2.5e8 for the XLA scan path.  PERF.md carries what the chip has said since
+PR 21 (``ANOMOD_BENCH_KERNEL`` selects the kernel).
 
 Three structural fixes over the round-1 kernel (which measured 6.0e7
 spans/sec vs 1.1e8 for the XLA scan path):
@@ -35,10 +35,6 @@ Falls back to interpret mode off-TPU (used by the CPU-mesh tests).
 
 from __future__ import annotations
 
-
-from anomod.ops.compat import tpu_compiler_params as _compiler_params
-
-
 import numpy as np
 
 # staged-column order fed to the kernel (matches anomod.replay plane order:
@@ -57,6 +53,13 @@ def _build_rhs_t(planes, block, n_hist):
 
     exact = planes[0:3].astype(jnp.bfloat16)  # valid / err / 5xx
     moments = planes[3:6]                     # dur_raw / dur / dur^2
+    # The same values as replay._split_hi_lo, written as the convert pair
+    # that function must avoid: ``lax.reduce_precision`` has no Pallas TPU
+    # lowering in JAX 0.9.0 (tests/test_pallas_lowering.py trips the day
+    # it gets one — then call _split_hi_lo here), and Mosaic, unlike
+    # XLA:TPU, does not elide the pair.  That it keeps the lo term is
+    # pinned compiled, < 1e-4 against float64 for every kernel that calls
+    # this (tpu_tests/test_mosaic_parity.py).
     hi = moments.astype(jnp.bfloat16)
     lo = (moments - hi.astype(jnp.float32)).astype(jnp.bfloat16)
     valid = planes[0]
@@ -138,7 +141,7 @@ def make_pallas_replay_fn(n_segments: int, n_hist: int = 16,
             ],
             out_specs=pl.BlockSpec((ROWS, SW1), lambda r, i: (0, 0)),
             out_shape=jax.ShapeDtypeStruct((ROWS, SW1), jnp.float32),
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
         )(sid, planes)
@@ -176,6 +179,7 @@ def make_pallas_lane_delta_fn(n_segments: int, n_hist: int = 16,
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     SW1 = n_segments + 1          # + dead lane
     ROWS = 3 + 6 + n_hist         # exact + (hi, lo) moments + histogram
@@ -192,7 +196,7 @@ def make_pallas_lane_delta_fn(n_segments: int, n_hist: int = 16,
             def _init():
                 out_ref[:] = jnp.zeros_like(out_ref)
 
-            s = sid_ref[0]                        # [B] int32, this lane
+            s = sid_ref[0, 0]                     # [B] int32, this lane
             rhs_t = _build_rhs_t(planes_ref[0], blk, n_hist)
             seg_iota = jax.lax.broadcasted_iota(jnp.int32, (blk, SW1), 1)
             onehot = (seg_iota == s[:, None]).astype(jnp.bfloat16)
@@ -204,15 +208,18 @@ def make_pallas_lane_delta_fn(n_segments: int, n_hist: int = 16,
             kernel,
             grid=(L, W // blk),
             in_specs=[
-                pl.BlockSpec((1, blk), lambda l, i: (l, i)),
+                # sid rides as [L, 1, W]: a (1, blk) block of an [L, W]
+                # array breaks Mosaic's rule that the block's last two
+                # dims are (8, 128)-divisible or the array's full dims
+                pl.BlockSpec((1, 1, blk), lambda l, i: (l, 0, i)),
                 pl.BlockSpec((1, N_PLANES, blk), lambda l, i: (l, 0, i)),
             ],
             out_specs=pl.BlockSpec((1, ROWS, SW1), lambda l, i: (l, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((L, ROWS, SW1), jnp.float32),
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
-        )(sid, planes)
+        )(sid[:, None, :], planes)
         return _recombine_moments(acc, n_segments)
 
     return run
@@ -257,8 +264,7 @@ def stage_sorted_planes(sid, planes, n_segments, k: int = 128,
 def make_pallas_replay_sorted_fn(n_segments: int, n_hist: int = 16,
                                  k: int = 128, block: int = 4096,
                                  interpret: bool = False,
-                                 inner_repeats: int = 1,
-                                 bf16_onehot: bool = False):
+                                 inner_repeats: int = 1):
     """Sorted-window variant of :func:`make_pallas_replay_fn`:
     ``fn(sid_local[T], planes[6, T], wids[T // block]) -> agg[SW, 6+H]``
     over arrays staged by :func:`stage_sorted_planes`.
@@ -291,18 +297,8 @@ def make_pallas_replay_sorted_fn(n_segments: int, n_hist: int = 16,
         # [6, B] f32 -> shared bf16 rhs build (same split as the unsorted
         # kernel, so the two paths cannot diverge numerically)
         rhs_t = _build_rhs_t(planes_ref[:], block, n_hist)
-        if bf16_onehot:
-            # the one-hot construction is the kernel's VPU bottleneck
-            # (scripts/bench_kernel_roofline.py ablations); window-local
-            # ids are < k <= 128, exactly representable in bf16, and
-            # 16-bit lanes compare at 2x packing — the [B, k] compare
-            # halves its cycle count where the int32 iota cannot
-            seg_iota = jax.lax.broadcasted_iota(jnp.bfloat16, (block, k), 1)
-            onehot = (seg_iota == sid.astype(jnp.bfloat16)[:, None]
-                      ).astype(jnp.bfloat16)                      # [B, k]
-        else:
-            seg_iota = jax.lax.broadcasted_iota(jnp.int32, (block, k), 1)
-            onehot = (seg_iota == sid[:, None]).astype(jnp.bfloat16)
+        seg_iota = jax.lax.broadcasted_iota(jnp.int32, (block, k), 1)
+        onehot = (seg_iota == sid[:, None]).astype(jnp.bfloat16)  # [B, k]
         partial = jax.lax.dot_general(
             rhs_t, onehot, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # [ROWS, k]
@@ -332,7 +328,7 @@ def make_pallas_replay_sorted_fn(n_segments: int, n_hist: int = 16,
                 out_specs=pl.BlockSpec((ROWS, NWK), lambda r, i, w: (0, 0)),
             ),
             out_shape=jax.ShapeDtypeStruct((ROWS, NWK), jnp.float32),
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary")),
             interpret=interpret,
         )(wids, sid_local, planes)
@@ -383,9 +379,13 @@ def make_pallas_window_gather_fn(n_services: int, n_windows: int,
 
     def kernel(slots_ref, cols_ref, pool_ref, out_ref):
         del slots_ref                  # consumed by the index map
-        row = pool_ref[0].reshape(S, W, F)
         c = cols_ref[pl.program_id(0)]
-        out_ref[0] = jax.lax.dynamic_slice_in_dim(row, c, 1, axis=1)[:, 0]
+        # service s's window column c is row s*W + c of the [S*W, F]
+        # plane: S dynamic-offset row loads straight off the ref (Mosaic
+        # lowers neither dynamic_slice on a value nor the in-kernel
+        # reshape to [S, W, F])
+        for s in range(S):
+            out_ref[0, pl.ds(s, 1), :] = pool_ref[0, pl.ds(s * W + c, 1), :]
 
     @jax.jit
     def run(pool, slots, cols):
@@ -404,7 +404,7 @@ def make_pallas_window_gather_fn(n_services: int, n_windows: int,
                 out_specs=pl.BlockSpec((1, S, F), lambda t, s, c: (t, 0, 0)),
             ),
             out_shape=jax.ShapeDtypeStruct((T, S, F), jnp.float32),
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
             interpret=interpret,
         )(slots, cols, pool)

@@ -20,10 +20,6 @@ mode covers CPU test runs.
 
 from __future__ import annotations
 
-
-from anomod.ops.compat import tpu_compiler_params as _compiler_params
-
-
 import functools
 from typing import Tuple
 
@@ -91,7 +87,7 @@ def make_pallas_tdigest_fn(n_centroids: int, length: int,
             in_specs=[pl.BlockSpec((SUB, L), lambda i: (i, 0))] * 3,
             out_specs=[pl.BlockSpec((SUB, K), lambda i: (i, 0))] * 2,
             out_shape=out_shape,
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
         )(bucket.astype(jnp.int32), w.astype(jnp.float32),

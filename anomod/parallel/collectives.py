@@ -21,9 +21,7 @@ def ring_allreduce(x, axis: str):
     """Ring all-reduce via ppermute (call inside shard_map over ``axis``)."""
     import jax
 
-    # axis_size is a newer lax addition; psum(1) is the portable spelling
-    n = (jax.lax.axis_size(axis) if hasattr(jax.lax, "axis_size")
-         else int(jax.lax.psum(1, axis)))
+    n = jax.lax.axis_size(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def body(i, carry):

@@ -8,45 +8,9 @@ stream sharding; model axes come with the GNN), XLA collectives over ICI/DCN.
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Optional
 
 import numpy as np
-
-
-def pvary_compat(x, axis_names: Sequence[str]):
-    """Mark ``x`` device-varying over the named mesh axes (shard_map vma
-    checking requires loop carries to match varying outputs).  Single home
-    for the pcast/pvary API shim: ``jax.lax.pcast(..., to="varying")``
-    replaced the deprecated ``pvary``.  No-op when already varying."""
-    from jax import lax
-    vma = getattr(getattr(x, "aval", None), "vma", frozenset())
-    if all(a in vma for a in axis_names):
-        return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, tuple(axis_names), to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, tuple(axis_names))
-    return x  # pre-vma jax: nothing to annotate
-
-
-def shard_map_compat(f, **kwargs):
-    """``jax.shard_map`` across the API migration — single home for the
-    shim: newer jax exports it at top level with ``check_vma``; older
-    releases have ``jax.experimental.shard_map.shard_map`` with the same
-    knob spelled ``check_rep``."""
-    import jax
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    if "check_vma" in kwargs:
-        import inspect
-        try:
-            params = inspect.signature(sm).parameters
-        except (TypeError, ValueError):
-            params = {}
-        if "check_vma" not in params and "check_rep" in params:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return sm(f, **kwargs)
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "data"):

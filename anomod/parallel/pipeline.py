@@ -104,8 +104,7 @@ def make_pipeline_forward(mesh, cfg: PipelineConfig, S: int, W: int):
         return h
 
     def _varying(x):
-        from anomod.parallel.mesh import pvary_compat
-        return pvary_compat(x, (AXIS,))
+        return lax.pcast(x, (AXIS,), to="varying")
 
     def pipeline_local(stage_params, micro):
         # stage_params leading [1, lps, ...] (my shard); micro [M, mb, L, d]
@@ -114,7 +113,7 @@ def make_pipeline_forward(mesh, cfg: PipelineConfig, S: int, W: int):
         T = M + n_stages - 1
         micro = _varying(micro)
         state0 = _varying(jnp.zeros(micro.shape[1:], micro.dtype))
-        out0 = _varying(jnp.zeros_like(micro))
+        out0 = jnp.zeros_like(micro)         # inherits micro's vma
         perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
 
         def tick(carry, t):
@@ -136,9 +135,8 @@ def make_pipeline_forward(mesh, cfg: PipelineConfig, S: int, W: int):
         mask = (idx == n_stages - 1).astype(micro.dtype)
         return lax.psum(out * mask, AXIS)
 
-    from anomod.parallel.mesh import shard_map_compat
-    pipe = shard_map_compat(pipeline_local, mesh=mesh,
-                            in_specs=(P(AXIS), P()), out_specs=P())
+    pipe = jax.shard_map(pipeline_local, mesh=mesh,
+                         in_specs=(P(AXIS), P()), out_specs=P())
 
     def _embed_all(params, x):
         return jax.vmap(lambda xi: embed.apply(params["embed"], xi))(x)

@@ -80,11 +80,11 @@ def make_sharded_replay_fn(cfg: ReplayConfig, mesh, axis: str = "data",
         else:
             # the carry is device-varying from step 1 on, so the initial
             # zeros must be cast to varying over the data axis too
-            from anomod.parallel.mesh import pvary_compat
             state = ReplayState(
-                agg=pvary_compat(jnp.zeros((SW, N_FEATS), jnp.float32),
-                                 (axis,)),
-                hist=pvary_compat(jnp.zeros((SW, H), jnp.float32), (axis,)))
+                agg=jax.lax.pcast(jnp.zeros((SW, N_FEATS), jnp.float32),
+                                  (axis,), to="varying"),
+                hist=jax.lax.pcast(jnp.zeros((SW, H), jnp.float32),
+                                   (axis,), to="varying"))
             state, _ = jax.lax.scan(make_chunk_step(cfg), state, chunks)
         hll = None
         if with_hll:
@@ -100,7 +100,6 @@ def make_sharded_replay_fn(cfg: ReplayConfig, mesh, axis: str = "data",
                            hist=jax.lax.psum(state.hist, axis),
                            hll=hll)
 
-    from anomod.parallel.mesh import shard_map_compat
     # the pallas kernel's internal constants (iota tiles, zero-init) carry
     # no mesh varying-axes metadata, so shard_map's static vma checker
     # rejects the mix unconditionally (interpret or compiled, with or
@@ -109,7 +108,7 @@ def make_sharded_replay_fn(cfg: ReplayConfig, mesh, axis: str = "data",
     # static checker is off for this variant
     kwargs = {"check_vma": False} if kernel == "pallas" else {}
     state_spec = P(axis) if merge == "scattered" else P()
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=({k: P(axis) for k in
                    ("sid", "dur", "dur_raw", "err", "s5", "valid",
@@ -162,4 +161,7 @@ def sharded_throughput(batch: SpanBatch, mesh,
     wall = sorted(times)[len(times) // 2]
     return ThroughputResult(n_spans=n, wall_s=wall,
                             spans_per_sec=n / wall, compile_s=compile_s,
-                            kernel=kernel, raw_wall_s=tuple(times))
+                            kernel=kernel, raw_wall_s=tuple(times),
+                            state=np.concatenate(
+                                [np.asarray(out.agg), np.asarray(out.hist)],
+                                axis=1))

@@ -68,8 +68,7 @@ def ring_attention_local(q, k, v, axis_name: str):
     def _varying(x):
         # fresh constants are unvarying over the mesh axis; the loop carry
         # must match the varying outputs (shard_map vma checking)
-        from anomod.parallel.mesh import pvary_compat
-        return pvary_compat(x, (axis_name,))
+        return lax.pcast(x, (axis_name,), to="varying")
 
     num0 = jnp.zeros_like(q)
     den0 = _varying(jnp.zeros((Lq, H), q.dtype))
@@ -90,8 +89,7 @@ def make_sharded_attention(local_fn, mesh, axis: str = "data"):
 
     @functools.partial(jax.jit, out_shardings=NamedSharding(mesh, spec))
     def attend(q, k, v):
-        from anomod.parallel.mesh import shard_map_compat
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             functools.partial(local_fn, axis_name=axis),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
         return fn(q, k, v)

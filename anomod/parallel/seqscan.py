@@ -48,15 +48,12 @@ def make_seqpar_recurrence(mesh, axis: str = "data"):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from anomod.parallel.mesh import shard_map_compat
-
     n_dev = mesh.shape[axis]
 
     def body(xs_local, decay):
         # decay is replicated (P()) hence device-invariant; mark it varying so
         # every derived carry/aggregate has consistent vma annotations
-        from anomod.parallel.mesh import pvary_compat
-        decay = pvary_compat(decay, (axis,))
+        decay = jax.lax.pcast(decay, (axis,), to="varying")
         # local block scan
         h_local = linear_recurrence(xs_local, decay)             # [T/D, ...]
         t_local = xs_local.shape[0]
@@ -83,7 +80,6 @@ def make_seqpar_recurrence(mesh, axis: str = "data"):
         corr = (a[None] ** t_idx) * carry_in[None]
         return h_local + corr
 
-    fn = shard_map_compat(body, mesh=mesh,
-                   in_specs=(P(axis), P()),
-                   out_specs=P(axis))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
+                       out_specs=P(axis))
     return jax.jit(fn)

@@ -23,25 +23,17 @@ taxonomy's intensity axis for evaluation purposes.
 from __future__ import annotations
 
 import dataclasses
-import sys
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
 from anomod import detect, synth
-from anomod.utils import platform
 from anomod.rca import (_apply_model, _stack, build_dataset,
                         experiment_stream, init_params, make_model, rca_loss,
                         standardize_features, topk_eval)
 
 #: The default sweep grid: full-strength down to the hard regime.
 SEVERITIES = (1.0, 0.4, 0.2, 0.1, 0.05)
-
-#: Diagnostic breadcrumb: set to a one-line note when the most recent sweep
-#: lost its device backend mid-run and completed on the CPU failover path
-#: (utils.platform.with_cpu_failover); the CLI copies it into the
-#: provenance record so a mixed-backend table is labeled as such.
-LAST_FAILOVER: Optional[str] = None
 
 #: The de-saturated operating point used by the regression floor test and
 #: docs/BENCHMARKS.md "hard regime" table: mild effects + decoys + noise.
@@ -320,16 +312,6 @@ def _eval_grid(testbed, model_names, eval_modes: Dict[object, "synth.HardMode"],
             row[(name, key)] = topk_eval(scores, ev)
         return row
 
-    global LAST_FAILOVER
-    LAST_FAILOVER = None
-
-    def _note_failover(exc, _model=None):
-        global LAST_FAILOVER
-        LAST_FAILOVER = (f"device backend lost mid-sweep at model "
-                         f"{_model!r} ({type(exc).__name__}); remaining "
-                         f"rows completed on the CPU failover backend")
-        print(f"[anomod.quality] {LAST_FAILOVER}", file=sys.stderr)
-
     cells: Dict[Tuple[str, object], Tuple[float, float, float, int]] = {}
     for name in model_names:
         if name in ("zscore", "stream"):
@@ -340,9 +322,7 @@ def _eval_grid(testbed, model_names, eval_modes: Dict[object, "synth.HardMode"],
                 if verbose:
                     print(f"{name} {key}: top1={cells[(name, key)][0]:.2f}")
             continue
-        row = platform.with_cpu_failover(
-            lambda: _train_and_eval(name),
-            on_failover=lambda e, _m=name: _note_failover(e, _m))
+        row = _train_and_eval(name)
         cells.update(row)
         if verbose:
             for (n, key), cell in row.items():

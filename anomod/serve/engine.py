@@ -474,14 +474,16 @@ def run_power_law(n_tenants: int = 200, n_services: int = 8,
                   tier_cold_dir=None,
                   tier_prefetch: Optional[int] = None,
                   worker: Optional[str] = None,
-                  fold: Optional[str] = None
+                  fold: Optional[str] = None,
+                  served_log: Optional[list] = None
                   ) -> Tuple["ServeEngine", ServeReport]:
     """The canonical seeded serve run shared by ``anomod serve`` and
     ``bench.py --mode serve``: a power-law tenant fleet offering
     ``overload``× the engine's capacity, with ``fault_tenants`` busiest
     tenants given a scripted latency fault once calibration is past —
     so one invocation measures sustained throughput, shed behavior AND
-    alert latency under load."""
+    alert latency under load.  ``served_log`` (see
+    :meth:`ServeEngine.run`) collects what each tick served."""
     from anomod.serve.traffic import PowerLawTraffic, TenantFault
     onset_s = (baseline_windows + 2) * window_s
     if duration_s <= onset_s + 2 * window_s:
@@ -617,7 +619,8 @@ def run_power_law(n_tenants: int = 200, n_services: int = 8,
             # header records what the original actually served with
             # (the forensic record; also what the replay defaults to)
             worker=engine.worker_mode, fold=engine.fold_mode)
-    report = engine.run(traffic, duration_s=duration_s)
+    report = engine.run(traffic, duration_s=duration_s,
+                        served_log=served_log)
     return engine, report
 
 
@@ -672,11 +675,9 @@ class ServeEngine:
                  worker: Optional[str] = None,
                  fold: Optional[str] = None):
         from anomod.config import get_config
-        from anomod.utils.platform import enable_jit_cache
         if capacity_spans_per_s <= 0:
             raise ValueError("capacity must be positive")
         app_cfg = get_config()
-        enable_jit_cache()           # no-op unless ANOMOD_JIT_CACHE is on
         self.specs = list(specs)
         self.services = tuple(services)
         self.cfg = cfg or ReplayConfig(n_services=len(self.services),
@@ -1030,7 +1031,13 @@ class ServeEngine:
             raise ValueError(f"unknown serve worker mode {_worker!r} "
                              "(thread|process)")
         if _worker == "process":
+            import jax
+            backend = jax.default_backend()
             blocker = (
+                "a chip belongs to one process: each worker child "
+                "imports jax and compiles, and cannot get the "
+                f"{backend} device this process holds"
+                if backend != "cpu" else
                 "the mesh plane manages its own sharded dispatch"
                 if mesh is not None else
                 "the multimodal sidecar planes share coordinator memory"
@@ -3410,15 +3417,18 @@ class ServeEngine:
                    now: float) -> None:
         self._rca_run_items(self._rca_planes[shard_id], items, out, now)
 
-    def run(self, traffic, duration_s: float,
-            warm: bool = True) -> "ServeReport":
+    def run(self, traffic, duration_s: float, warm: bool = True,
+            served_log: Optional[list] = None) -> "ServeReport":
         """Drive the engine from a traffic source for ``duration_s``
-        virtual seconds, then close every tenant's last window."""
+        virtual seconds, then close every tenant's last window.  A
+        ``served_log`` list receives each tick's served batches in tick
+        order — what a sequential re-scoring of the same run is fed
+        (``chip_smoke.fused_vs_sequential``)."""
         if warm and self.mesh is None:
             if self._use_workers and self.worker_mode == "process":
                 # the thread discipline, over the pipe: shard 0 warms
-                # first and alone (with ANOMOD_JIT_CACHE on it
-                # populates the persistent cache for the siblings),
+                # first and alone (it populates the persistent compile
+                # cache for the siblings),
                 # then the rest overlap — all sends complete before
                 # any recv.  Replies carry each child's compile walls
                 # into the coordinator mirrors.
@@ -3441,8 +3451,8 @@ class ServeEngine:
                     # mode keeps RCA evidence out of the children)
                     self._rca_planes[0].runner.warm()
             elif self._use_workers:
-                # warm shard 0 FIRST, alone: with ANOMOD_JIT_CACHE on
-                # it populates the persistent cache, so the remaining
+                # warm shard 0 FIRST, alone: it populates the
+                # persistent compile cache, so the remaining
                 # shards' identical-HLO grids (warmed in parallel on
                 # their own workers next) are cache reads instead of N
                 # concurrent compilers thrashing the host — compiles
@@ -3469,8 +3479,11 @@ class ServeEngine:
             for _ in range(n_ticks):
                 lo = self.clock.now_s
                 hi = lo + self.clock.tick_s
-                self.tick(traffic.arrivals(lo, hi),
-                          mod_src(lo, hi) if mod_src is not None else ())
+                served = self.tick(
+                    traffic.arrivals(lo, hi),
+                    mod_src(lo, hi) if mod_src is not None else ())
+                if served_log is not None:
+                    served_log.append(served)
         if self._deferred is not None:
             # the run-end barrier: the last tick's deferred commit must
             # land before finish() reads any tenant state (its wall

@@ -112,22 +112,6 @@ def restore_train_state(path: Path) -> Tuple[Any, Any, int, dict]:
     return params, opt_state, step, meta
 
 
-def checkpoint_mtime(path) -> Optional[float]:
-    """Publish time (meta.json mtime) of the live checkpoint, or None.
-
-    meta.json is atomically replaced as the LAST step of every save, so its
-    mtime is the moment the checkpoint became live — callers use it to tell
-    "saved by this run" from "stale leftover of an earlier run" (the CLI's
-    failover retry must not resume a pre-existing checkpoint)."""
-    path = Path(path)
-    if not has_checkpoint(path):
-        return None
-    try:
-        return (path / "meta.json").stat().st_mtime
-    except OSError:
-        return None
-
-
 def has_checkpoint(path) -> bool:
     """True when a published AND restorable checkpoint exists at ``path``.
 

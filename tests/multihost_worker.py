@@ -41,7 +41,7 @@ def main() -> int:
     initialize_distributed(f"127.0.0.1:{port}", nproc, pid)
 
     import numpy as np
-    from anomod.parallel.mesh import shard_map_compat as shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from anomod.ops.hll import hll_add, hll_estimate, hll_init

@@ -61,7 +61,7 @@ def test_tracer_jaeger_roundtrip(tmp_path):
 def test_ring_allreduce_matches_psum():
     import jax
     import jax.numpy as jnp
-    from anomod.parallel.mesh import shard_map_compat as shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from anomod.parallel import make_mesh
     from anomod.parallel.collectives import ring_allreduce
@@ -83,7 +83,7 @@ def test_ring_allreduce_matches_psum():
 def test_hll_pmax_merge_across_shards():
     import jax
     import jax.numpy as jnp
-    from anomod.parallel.mesh import shard_map_compat as shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from anomod.ops import hll_add, hll_estimate, hll_init
     from anomod.parallel import make_mesh
@@ -110,7 +110,7 @@ def test_hll_pmax_merge_across_shards():
 def test_tdigest_allgather_merge_across_shards():
     import jax
     import jax.numpy as jnp
-    from anomod.parallel.mesh import shard_map_compat as shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from anomod.ops import tdigest_build, tdigest_quantile
     from anomod.parallel import make_mesh

@@ -77,7 +77,7 @@ def test_level_groups_cover_union():
     for level in ("performance", "service", "database"):
         union.update(mc.metrics_for_level(level))
     assert union == set(mc.TT_METRIC_NAMES)
-    # ~31 unique metrics in the three groups (VERDICT.md item 3)
+    # ~31 unique metrics in the three groups
     assert len(mc.TT_METRIC_NAMES) >= 30
 
 

@@ -477,9 +477,9 @@ def test_env_contract_script_passes_on_repo():
     out = json.loads(r.stdout)
     assert out["status"] == "ok"
     # the PINNED inventory size: a new ANOMOD_* knob must land here and
-    # in docs/CONFIGURATION.md in the same PR (ISSUE-20 took it to 79
-    # with ANOMOD_SERVE_WORKER / _WORKER_START_TIMEOUT_S / _FOLD)
-    assert out["n_vars"] == 79
+    # in the docs in the same PR (PR 21 took it from 79 to 72: the probe /
+    # platform-pin / jit-cache names went with the machinery they set)
+    assert out["n_vars"] == 72
 
 
 def test_env_contract_script_catches_rogue_var(tmp_path):

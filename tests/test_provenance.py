@@ -61,3 +61,14 @@ def test_git_sha_dirty_only_for_tracked_changes(tmp_path):
     assert provenance.git_sha(cwd=str(r)) == clean
     (r / "a.txt").write_text("changed")
     assert provenance.git_sha(cwd=str(r)).endswith("-dirty")
+
+
+def test_git_sha_of_a_copy_without_git(tmp_path, monkeypatch):
+    # a `git archive` checkout (what the chip machine runs) has no .git:
+    # it names its tree in .source_tree, and is '' without one
+    monkeypatch.setenv("GIT_CEILING_DIRECTORIES", str(tmp_path))
+    copy = tmp_path / "copy"
+    copy.mkdir()
+    assert provenance.git_sha(cwd=str(copy)) == ""
+    (copy / ".source_tree").write_text("0123abcd\n")
+    assert provenance.git_sha(cwd=str(copy)) == "tree:0123abcd"

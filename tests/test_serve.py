@@ -1115,11 +1115,9 @@ def test_shard_env_knobs_registered_and_validated(monkeypatch):
     from anomod.config import Config
     monkeypatch.setenv("ANOMOD_SERVE_SHARDS", "4")
     monkeypatch.setenv("ANOMOD_SERVE_PIPELINE", "3")
-    monkeypatch.setenv("ANOMOD_JIT_CACHE", "1")
     cfg = Config()
     assert cfg.serve_shards == 4
     assert cfg.serve_pipeline == 3
-    assert cfg.jit_cache is True
 
     for var, bad in (("ANOMOD_SERVE_SHARDS", "0"),
                      ("ANOMOD_SERVE_SHARDS", "many"),
@@ -1130,14 +1128,10 @@ def test_shard_env_knobs_registered_and_validated(monkeypatch):
         with pytest.raises(ValueError, match=var):
             Config()
         monkeypatch.delenv(var)
-    monkeypatch.setenv("ANOMOD_JIT_CACHE", "off")
-    assert Config().jit_cache is False
-    monkeypatch.delenv("ANOMOD_JIT_CACHE")
     cfg = Config()
     assert cfg.serve_shards == 1          # default: the escape hatch
     assert cfg.serve_pipeline == 2
-    assert cfg.jit_cache is False
-    # the env-contract gate sees all three knobs as Config-covered
+    # the env-contract gate sees both knobs as Config-covered
     import sys as _sys
     from pathlib import Path as _Path
     _sys.path.insert(0, str(_Path(__file__).parent.parent / "scripts"))
@@ -1145,8 +1139,7 @@ def test_shard_env_knobs_registered_and_validated(monkeypatch):
         import check_env_contract as cec
         refs = cec.referenced_vars(_Path(cec.ROOT))
         corpus = cec.covered_vars(_Path(cec.ROOT))
-        for knob in ("ANOMOD_SERVE_SHARDS", "ANOMOD_SERVE_PIPELINE",
-                     "ANOMOD_JIT_CACHE"):
+        for knob in ("ANOMOD_SERVE_SHARDS", "ANOMOD_SERVE_PIPELINE"):
             assert knob in refs and knob in corpus
     finally:
         _sys.path.pop(0)

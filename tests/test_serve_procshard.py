@@ -377,6 +377,27 @@ def test_process_refused_with_in_process_planes(blocker_kw):
         _mk_engine(worker="process", **blocker_kw)
 
 
+def test_process_refused_on_a_chip_backend(monkeypatch):
+    """One process per chip: each worker child imports jax and compiles,
+    and the device already belongs to the coordinator — on a non-CPU
+    backend an explicit worker='process' is refused with the reason, and
+    an env-sourced one degrades to threads (the matrix's idiom)."""
+    import jax
+
+    from anomod.config import Config, set_config
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="a chip belongs to one process.*"
+                                         "tpu device this process holds"):
+        _mk_engine(worker="process")
+    monkeypatch.setenv("ANOMOD_SERVE_WORKER", "process")
+    set_config(Config())
+    try:
+        assert _mk_engine().worker_mode == "thread"
+    finally:
+        monkeypatch.delenv("ANOMOD_SERVE_WORKER")
+        set_config(Config())
+
+
 def test_mesh_refuses_explicit_process_worker():
     from anomod.parallel import make_mesh
     with pytest.raises(ValueError, match="mesh"):

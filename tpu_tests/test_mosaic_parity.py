@@ -49,6 +49,55 @@ def test_replay_kernel_compiled_production_shape(tt_corpus):
                                atol=1e-2)
 
 
+def moment_error(agg, chunks, sw):
+    """Max relative error of the latency-moment columns ``agg[:, 3:6]``
+    against a float64 oracle over the staged ``chunks``.  The hi/lo split
+    is good to ~6e-6; with the lo term lost it is 3e-3."""
+    sid = np.asarray(chunks["sid"]).reshape(-1)
+    valid = np.asarray(chunks["valid"]).reshape(-1) > 0
+    dur = np.asarray(chunks["dur"]).reshape(-1).astype(np.float64)
+    raw = np.asarray(chunks["dur_raw"]).reshape(-1).astype(np.float64)
+    oracle = np.zeros((sw + 1, 3), np.float64)
+    np.add.at(oracle, sid[valid],
+              np.stack([raw, dur, dur * dur], axis=1)[valid])
+    oracle = oracle[:sw]
+    live = oracle[:, 1] > 0
+    assert live.any()
+    rel = np.abs(agg[live, 3:6] - oracle[live]) / np.abs(oracle[live])
+    return rel.max()
+
+
+def test_latency_moments_keep_the_lo_term(tt_corpus):
+    """The bf16 hi/lo split must survive compilation on EVERY device
+    path.  XLA's TPU pipeline elides an f32->bf16->f32 convert pair, which
+    once zeroed the lo term of the XLA scan (and the serve plane's matmul
+    lanes) and left the moments at bf16 precision — 3.3e-3 max relative
+    error, inside the 2e-3-rtol parity pins below on most segments.  The
+    Pallas kernels still write that pair (``reduce_precision`` has no
+    Mosaic lowering), so each of them is held to the float64 oracle too;
+    the lane kernels are in test_lane_delta_kernel_compiled."""
+    from anomod.ops.pallas_replay import (make_pallas_replay_fn,
+                                          make_pallas_replay_sorted_fn,
+                                          stage_sorted_planes)
+    from anomod.replay import (make_replay_fn, pallas_block,
+                               stage_pallas_planes)
+
+    _, cfg, chunks, _ = tt_corpus
+    block = pallas_block(cfg.chunk_size)
+    s, p = stage_pallas_planes(chunks)
+    got = {
+        "xla": make_replay_fn(cfg)(chunks).agg,
+        "pallas": make_pallas_replay_fn(
+            cfg.sw, cfg.n_hist_buckets, block=block)(s, p),
+        "pallas-sorted": make_pallas_replay_sorted_fn(
+            cfg.sw, cfg.n_hist_buckets, block=block)(
+                *stage_sorted_planes(s, p, cfg.sw, block=block)),
+    }
+    for name, agg in got.items():
+        err = moment_error(np.asarray(agg), chunks, cfg.sw)
+        assert err < 1e-4, (name, err)
+
+
 def test_replay_kernel_compiled_inner_repeats(tt_corpus):
     """The bench measurement trick — replaying the staged corpus via the
     outer grid dimension — must accumulate exactly r copies of the state
@@ -95,12 +144,15 @@ def test_replay_sorted_kernel_compiled(tt_corpus):
                                atol=3e-2)
 
 
-def test_lane_delta_kernel_compiled():
-    """The serving plane's fused lane-stacked score kernel (ISSUE-7),
-    Mosaic-compiled at serve shapes: [lanes, width] stacked chunks →
-    per-lane deltas as ONE kernel launch, vs the per-lane numpy oracle.
-    Dead pad lanes must come back exactly zero.  (The CPU-interpret twin
-    runs in tier-1: tests/test_replay.py.)"""
+@pytest.mark.parametrize("engine", ["pallas", "matmul"])
+def test_lane_delta_kernel_compiled(engine):
+    """The serving plane's lane-stacked score step at serve shapes —
+    the fused Mosaic kernel (ISSUE-7) and the default vmap'd one-hot
+    matmul: [lanes, width] stacked chunks → per-lane deltas in ONE
+    launch, vs the per-lane numpy oracle, with the latency moments held
+    to the float64 oracle (the lo term alive).  Dead pad lanes must come
+    back exactly zero.  (The CPU-interpret twin runs in tier-1:
+    tests/test_replay.py.)"""
     import jax
 
     from anomod.replay import (ReplayConfig, dead_chunk, make_lane_delta,
@@ -119,7 +171,7 @@ def test_lane_delta_kernel_compiled():
     lanes.append(dead_chunk(cfg, cfg.chunk_size, xp=np))
     stack = {k: np.stack([np.asarray(c[k]) for c in lanes])
              for k in lanes[0]}
-    fn = jax.jit(make_lane_delta(cfg, engine="pallas"))
+    fn = jax.jit(make_lane_delta(cfg, engine=engine))
     dagg, dhist = fn(stack)
     dagg, dhist = np.asarray(dagg), np.asarray(dhist)
     for i, chunk in enumerate(lanes):
@@ -130,7 +182,35 @@ def test_lane_delta_kernel_compiled():
         np.testing.assert_allclose(dhist[i], ref.hist, rtol=0, atol=0)
         np.testing.assert_allclose(dagg[i, :, 3:6], ref.agg[:, 3:6],
                                    rtol=2e-3, atol=1e-2)
+    for i, chunk in enumerate(lanes[:-1]):
+        err = moment_error(dagg[i], chunk, cfg.sw)
+        assert err < 1e-4, (engine, i, err)
     assert (dagg[-1] == 0).all() and (dhist[-1] == 0).all()
+
+
+def test_window_gather_kernel_compiled():
+    """The device pool's batched-scoring gather, Mosaic-compiled at the
+    serve plane's shape (12 services x 32 windows, a 256-slot pool): a
+    pure copy, so bit-equal to the XLA take_along_axis gather."""
+    from anomod.replay import ReplayConfig, TenantStatePool
+
+    cfg = ReplayConfig(n_services=12, n_windows=32,
+                       window_us=5_000_000, chunk_size=4096)
+    rng = np.random.default_rng(5)
+    pools = {g: TenantStatePool(cfg, capacity=256, engine="jax",
+                                gather_engine=g) for g in ("xla", "pallas")}
+    for slot in (1, 7, 200, 256):
+        st = pools["xla"].zero_state()
+        st = st._replace(agg=rng.normal(size=st.agg.shape)
+                         .astype(np.float32))
+        for pool in pools.values():
+            pool.put(slot, st)
+    slots = np.array([1, 7, 200, 256, 7], np.int32)
+    cols = np.array([0, 31, 5, 17, 30], np.int32)
+    want = pools["xla"].gather_window(slots, cols)
+    got = pools["pallas"].gather_window(slots, cols)
+    assert want.shape == (5, 12, 6) and np.abs(want).sum() > 0
+    np.testing.assert_array_equal(got, want)
 
 
 def test_sharded_replay_pallas_compiled(tt_corpus):
