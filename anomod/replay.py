@@ -22,7 +22,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import time
-from functools import lru_cache, partial
+from functools import lru_cache
 from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
@@ -222,6 +222,22 @@ def _split_acc(acc, state: ReplayState):
     agg = state.agg + jnp.concatenate([acc[:, :3], a_dur], axis=1)
     hist = state.hist + acc[:, 9:]
     return agg, hist
+
+
+def named_jit(name: str, fn, **jit_kw):
+    """``jax.jit(fn)`` under a name the device trace keeps: the XLA module
+    reads ``jit_<name>`` and every op's metadata carries the
+    ``jax.named_scope`` of the same name, so a trace reduction finds the
+    callable after a refactor.  Names and metadata only: the traced
+    arithmetic, shapes, donation and layouts are ``fn``'s own."""
+    import jax
+
+    def named(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named, **jit_kw)
 
 
 def default_step_engine() -> str:
@@ -527,15 +543,12 @@ class TenantStatePool:
         # place instead of copying megabytes per fold — the rebind
         # below always installs the op's output before anything can
         # read again.
-        @partial(jax.jit, donate_argnums=(0, 1))
         def _scatter(agg, hist, slots, dagg, dhist):
             return agg.at[slots].add(dagg), hist.at[slots].add(dhist)
 
-        @partial(jax.jit, donate_argnums=(0, 1))
         def _put(agg, hist, slot, ragg, rhist):
             return agg.at[slot].set(ragg), hist.at[slot].set(rhist)
 
-        @partial(jax.jit, donate_argnums=(0, 1))
         def _roll(agg, hist, slot, shift):
             # device twin of anomod.stream.roll_ring_state on one row:
             # shift plane columns left, zero the tail.  Taken values
@@ -552,7 +565,6 @@ class TenantStatePool:
 
             return (roll2(agg, N_FEATS), roll2(hist, cfg.n_hist_buckets))
 
-        @jax.jit
         def _gather_window(agg, slots, cols):
             # [T, S, F]: ONE dispatch materializing only the scored
             # window column of each requested tenant — the batched
@@ -561,10 +573,14 @@ class TenantStatePool:
             return jnp.take_along_axis(
                 rows, cols[:, None, None, None], axis=2)[:, :, 0]
 
-        self._scatter_fn = _scatter
-        self._put_fn = _put
-        self._roll_fn = _roll
-        self._gather_window_fn = _gather_window
+        self._scatter_fn = named_jit("anomod_pool_scatter", _scatter,
+                                     donate_argnums=(0, 1))
+        self._put_fn = named_jit("anomod_pool_put", _put,
+                                 donate_argnums=(0, 1))
+        self._roll_fn = named_jit("anomod_pool_roll", _roll,
+                                  donate_argnums=(0, 1))
+        self._gather_window_fn = named_jit("anomod_pool_gather_window",
+                                           _gather_window)
 
     @property
     def capacity(self) -> int:

@@ -82,9 +82,10 @@ from anomod.replay import (N_FEATS, STAGE_KEYS, ReplayConfig, ReplayState,
                            TenantStatePool, dead_chunk,
                            default_lane_engine, default_step_engine,
                            fold_delta, make_chunk_step, make_lane_delta,
-                           stage_columns_fused)
+                           named_jit, stage_columns_fused)
 from anomod.schemas import SpanBatch
 from anomod.stream import StreamReplay
+from anomod.utils.tracing import span_of
 
 
 def split_plan(n_spans: int, chunk_size: int,
@@ -130,8 +131,7 @@ class BucketRunner:
                  lane_engine: Optional[str] = None,
                  state: Optional[str] = None,
                  pool_slots: int = 32,
-                 perf=None):
-        import jax
+                 perf=None, tracer=None):
         from anomod.config import get_config
         if buckets is None:
             buckets = get_config().serve_buckets
@@ -182,6 +182,10 @@ class BucketRunner:
         #: rounding and recording costs no extra perf_counter call on
         #: the already-timed points
         self.perf = perf
+        #: the engine's tracer (``span(name, **tags)``), or None: one
+        #: span per lane fill, lane dispatch and fold retire — per
+        #: dispatch, never per batch or per tenant
+        self.tracer = tracer
         #: max in-flight fused dispatches is ``pipeline - 1`` (depth 1 =
         #: fully synchronous, the pre-pipelining behavior); the submit/
         #: drain path keeps ``pipeline`` pinned scratch slots per
@@ -203,9 +207,11 @@ class BucketRunner:
         #: single-chunk dispatch stay BIT-identical on every backend
         self.lane_engine = _lane_eng
         step = make_chunk_step(cfg, with_hll=False, engine=self.engine)
-        self._step = jax.jit(lambda st, ch: step(st, ch)[0])
-        self._lane_fn = jax.jit(make_lane_delta(cfg,
-                                                engine=self.lane_engine))
+        self._step = named_jit("anomod_chunk_step",
+                               lambda st, ch: step(st, ch)[0])
+        self._lane_fn = named_jit(
+            "anomod_lane_delta",
+            make_lane_delta(cfg, engine=self.lane_engine))
         #: AOT-compiled lane executables, one per (width, lane-bucket)
         #: shape: calling the compiled object skips the pjit python
         #: dispatch path (~5-10 ms per call on this class of host for
@@ -537,30 +543,33 @@ class BucketRunner:
         key = (width, lanes, slot)
         while any(e[3] == key for e in self._inflight):
             self._retire_one()
-        t0 = time.perf_counter()
-        scratch = self._lane_scratch.get(key)
-        if scratch is None:
-            scratch = {k: native_io.aligned_empty((lanes, width), v.dtype)
-                       for k, v in self._dead_cols_for(width).items()}
-            self._lane_scratch[key] = scratch
-            if self.native_stage:
-                self._stage_plans[key] = native_io.make_stage_plan(
-                    scratch, self._pad_fill, mat_keys=STAGE_KEYS)
-        elif self.perf is not None:
-            # an existing scratch slot is being REUSED: stamp the
-            # slot-refilled event on the dispatch that last held it
-            self.perf.note_refill(key, t0)
-        plan = self._stage_plans.get(key)
-        if plan is not None and plan.stage(group_cols):
-            self.native_staged += 1
-            self._obs_native.inc()
-        else:
-            self._fill_slot_py(scratch, group_cols, width, lanes)
-        dt = time.perf_counter() - t0
-        self.stage_wall_s += dt
-        self._obs_stage_s.inc(dt)
-        if self.perf is not None:
-            self.perf.note_staged(key, t0, t0 + dt)
+        with span_of(self.tracer, "serve.lane_fill", width=width,
+                     lanes=lanes, live=len(group_cols)):
+            t0 = time.perf_counter()
+            scratch = self._lane_scratch.get(key)
+            if scratch is None:
+                scratch = {k: native_io.aligned_empty((lanes, width),
+                                                      v.dtype)
+                           for k, v in self._dead_cols_for(width).items()}
+                self._lane_scratch[key] = scratch
+                if self.native_stage:
+                    self._stage_plans[key] = native_io.make_stage_plan(
+                        scratch, self._pad_fill, mat_keys=STAGE_KEYS)
+            elif self.perf is not None:
+                # an existing scratch slot is being REUSED: stamp the
+                # slot-refilled event on the dispatch that last held it
+                self.perf.note_refill(key, t0)
+            plan = self._stage_plans.get(key)
+            if plan is not None and plan.stage(group_cols):
+                self.native_staged += 1
+                self._obs_native.inc()
+            else:
+                self._fill_slot_py(scratch, group_cols, width, lanes)
+            dt = time.perf_counter() - t0
+            self.stage_wall_s += dt
+            self._obs_stage_s.inc(dt)
+            if self.perf is not None:
+                self.perf.note_staged(key, t0, t0 + dt)
         return scratch, key
 
     def _fill_slot_py(self, scratch: dict, group_cols: List[dict],
@@ -616,22 +625,27 @@ class BucketRunner:
                                            [cols for _, cols in group])
             exe = self._lane_exec_for((width, lanes), scratch)
             prf = self.perf
-            t0 = time.perf_counter()
-            dagg, dhist = exe(scratch)
-            t1 = time.perf_counter()
+            with span_of(self.tracer, "serve.lane_dispatch", width=width,
+                         lanes=lanes):
+                t0 = time.perf_counter()
+                dagg, dhist = exe(scratch)
+                t1 = time.perf_counter()
             if prf is not None:
                 prf.note_submitted(key, t0, t1)
                 prf.note_retire(key, t1)
-            # materialize before the scratch is reused: the host copy is
-            # the execute barrier, and the scatter-back below reads it
-            dagg = np.asarray(dagg)
-            dhist = np.asarray(dhist)
-            if prf is not None:
-                t_mat = time.perf_counter()
-                prf.note_materialized(key, t_mat)
-            for i, (st, _) in enumerate(group):
-                out.append(fold_delta(st, dagg[i], dhist[i]))
-            t2 = time.perf_counter()
+            with span_of(self.tracer, "serve.fold_retire", lanes=lanes,
+                         device=False):
+                # materialize before the scratch is reused: the host
+                # copy is the execute barrier, and the scatter-back
+                # below reads it
+                dagg = np.asarray(dagg)
+                dhist = np.asarray(dhist)
+                if prf is not None:
+                    t_mat = time.perf_counter()
+                    prf.note_materialized(key, t_mat)
+                for i, (st, _) in enumerate(group):
+                    out.append(fold_delta(st, dagg[i], dhist[i]))
+                t2 = time.perf_counter()
             if prf is not None:
                 prf.note_folded(key, t2)
             self.dispatch_wall_s += t1 - t0
@@ -664,9 +678,11 @@ class BucketRunner:
             scratch, key = self._fill_slot(width, lanes,
                                            [cols for _, cols in group])
             exe = self._lane_exec_for((width, lanes), scratch)
-            t0 = time.perf_counter()
-            dagg, dhist = exe(scratch)
-            dt = time.perf_counter() - t0
+            with span_of(self.tracer, "serve.lane_dispatch", width=width,
+                         lanes=lanes):
+                t0 = time.perf_counter()
+                dagg, dhist = exe(scratch)
+                dt = time.perf_counter() - t0
             self.dispatch_wall_s += dt
             self._obs_dispatch_s.inc(dt)
             if self.perf is not None:
@@ -702,24 +718,27 @@ class BucketRunner:
         if prf is not None:
             prf.note_retire(key, t0)
         pool = self.pool
-        if pool is not None and replays and all(
-                getattr(r, "_slot", None) is not None
-                and getattr(r, "_runner", None) is self
-                for r in replays):
-            pool.scatter_fold([r._slot for r in replays], dagg, dhist)
-            dagg.block_until_ready()           # scratch-reuse barrier
-            if prf is not None:
-                t_wait = time.perf_counter() - t0
-                prf.note_materialized(key, t0 + t_wait)
-        else:
-            dagg = np.asarray(dagg)
-            dhist = np.asarray(dhist)
-            if prf is not None:
-                t_wait = time.perf_counter() - t0
-                prf.note_materialized(key, t0 + t_wait)
-            for i, replay in enumerate(replays):
-                replay.set_state(fold_delta(replay.get_state(),
-                                            dagg[i], dhist[i]))
+        on_device = bool(pool is not None and replays and all(
+            getattr(r, "_slot", None) is not None
+            and getattr(r, "_runner", None) is self
+            for r in replays))
+        with span_of(self.tracer, "serve.fold_retire", lanes=key[1],
+                     device=on_device):
+            if on_device:
+                pool.scatter_fold([r._slot for r in replays], dagg, dhist)
+                dagg.block_until_ready()       # scratch-reuse barrier
+                if prf is not None:
+                    t_wait = time.perf_counter() - t0
+                    prf.note_materialized(key, t0 + t_wait)
+            else:
+                dagg = np.asarray(dagg)
+                dhist = np.asarray(dhist)
+                if prf is not None:
+                    t_wait = time.perf_counter() - t0
+                    prf.note_materialized(key, t0 + t_wait)
+                for i, replay in enumerate(replays):
+                    replay.set_state(fold_delta(replay.get_state(),
+                                                dagg[i], dhist[i]))
         dt = time.perf_counter() - t0
         self.fold_wall_s += dt
         self._obs_fold_s.inc(dt)

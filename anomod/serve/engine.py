@@ -55,6 +55,7 @@ from anomod.serve.batcher import (BucketedStreamReplay, BucketRunner,
                                   PooledStreamReplay)
 from anomod.serve.queues import (AdmissionController, QueuedBatch,
                                  TenantSpec)
+from anomod.utils.tracing import span_of
 
 #: t-digest centroid capacity for the latency sketches (compact enough to
 #: keep per tenant, accurate to well under a tick at the tails)
@@ -1094,12 +1095,21 @@ class ServeEngine:
         self._obs_fold_payload = (
             obs.counter("anomod_serve_fold_payload_bytes_total")
             if self._use_workers else None)
+        # tracing is ON by default, gated on the one telemetry switch
+        # (ANOMOD_OBS_ENABLED) so "telemetry off" means off end to end;
+        # pass an explicit Tracer to force it on regardless.  The runners
+        # open their per-dispatch spans on the same tracer.
+        if tracer is None and obs.get_registry().enabled:
+            from anomod.utils.tracing import Tracer
+            tracer = Tracer("anomod-serve")
+        self.tracer = tracer
         #: the runner recipe a policy-time scale-up rebuilds from (the
         #: same arguments every initial shard runner got)
         self._runner_kw = dict(lane_buckets=lane_buckets,
                                pipeline=self.pipeline,
                                native_stage=native,
-                               state=self.serve_state)
+                               state=self.serve_state,
+                               tracer=tracer)
         self._buckets_arg = _buckets
         if self._use_workers:
             from anomod.serve.shard import plan_shards
@@ -1166,7 +1176,8 @@ class ServeEngine:
                                            if self.tier_hot
                                            else len(self.specs), 1),
                                        perf=(self._perf_recs[0]
-                                             if self.perf else None))
+                                             if self.perf else None),
+                                       tracer=tracer)
             self._runners = [self.runner]
         self._workers = None
         #: online RCA (ANOMOD_SERVE_RCA): when a tenant's detector fires
@@ -1224,13 +1235,6 @@ class ServeEngine:
                           RcaRunner(_rca_buckets, registry=reg),
                           topk=_topk, windows=_windows)
                 for reg in _regs]
-        # tracing is ON by default, gated on the one telemetry switch
-        # (ANOMOD_OBS_ENABLED) so "telemetry off" means off end to end;
-        # pass an explicit Tracer to force it on regardless
-        if tracer is None and obs.get_registry().enabled:
-            from anomod.utils.tracing import Tracer
-            tracer = Tracer("anomod-serve")
-        self.tracer = tracer
         self._det_kw = dict(baseline_windows=baseline_windows,
                             z_threshold=z_threshold,
                             consecutive=consecutive, min_count=min_count)
@@ -1577,9 +1581,7 @@ class ServeEngine:
     # -- the tick loop ----------------------------------------------------
 
     def _span(self, name: str, **tags):
-        import contextlib
-        return (self.tracer.span(name, **tags) if self.tracer is not None
-                else contextlib.nullcontext())
+        return span_of(self.tracer, name, **tags)
 
     def tick(self, arrivals, modality_arrivals=()) -> List[QueuedBatch]:
         """One virtual tick: admit this tick's arrivals (modality
@@ -1593,6 +1595,11 @@ class ServeEngine:
         scoring dispatches are issued but not drained, and the
         PREVIOUS tick commits at this tick's barrier — same decisions,
         overlapped walls."""
+        with self._span("serve.tick", tick=self.clock.ticks,
+                        offers=len(arrivals)):
+            return self._tick(arrivals, modality_arrivals)
+
+    def _tick(self, arrivals, modality_arrivals) -> List[QueuedBatch]:
         t_wall = time.perf_counter()
         now = self.clock.now_s + self.clock.tick_s   # decisions at tick end
         if self._chaos is not None:
@@ -1609,7 +1616,7 @@ class ServeEngine:
             with self._span("serve.modality"):
                 for tenant_id, kind, batch in modality_arrivals:
                     self.offer_modality(tenant_id, kind, batch)
-        with self._span("serve.admit"):
+        with self._span("serve.admit", offers=len(arrivals)):
             for tenant_id, spans in arrivals:
                 # one shared service table per engine: a batch whose ids
                 # mean different services would silently corrupt the
@@ -1723,44 +1730,46 @@ class ServeEngine:
         # (the latency samples depend only on admission times and the
         # tick clock, so fused and unfused runs record identical values
         # in identical per-tenant order)
-        for qb in served:
-            self._slo[qb.tenant_id].record(now - qb.enqueued_s)
-            self.n_spans_served += qb.n_spans
+        with self._span("serve.slo"):
+            self._slo_record(now, served)
         if self.rca:
             self._rca_step(now, served)
-        # the perf-timeline drain rides INSIDE the measured wall (the
-        # bench perf block prices the recorder, never hides it); it
-        # runs after the score barrier, so every dispatch of this tick
-        # has folded and its record is complete
-        self._perf_tick_doc = self._perf_drain() if self.perf else None
-        if self._census_tracker is not None:
-            # hot-set bookkeeping every tick (O(served)); the full
-            # resident-bytes census drains on its cadence, INSIDE the
-            # measured wall (the bench census block prices it, never
-            # hides it) and after the perf drain so the recorder
-            # retentions it counts are this tick's.  The census wall
-            # accumulates separately so the bench prices the overhead
-            # IN-RUN (census_wall_s / serve_wall_s — the ckpt_wall
-            # idiom: exact, immune to this box's A/B leg noise).
-            t0 = time.perf_counter()
-            self._census_tracker.observe(self.clock.ticks, served)
-            self._census_tick_doc = (
-                self._census_drain()
-                if self.census
-                and self._census_tracker.due(self.clock.ticks) else None)
-            if self.census:
-                self.census_wall_s += time.perf_counter() - t0
-            else:
-                # the tracker is alive only to feed the tiering decay
-                # plane (coldest_candidates): its bookkeeping wall is
-                # tiering overhead, never a census price
-                self.tier_wall_s += time.perf_counter() - t0
-        if self.flight_recorder is not None:
-            # the journal entry rides INSIDE the measured wall (the
-            # serve_wall_s accumulation below) — the bench's flight
-            # overhead leg prices the recorder, never hides it
-            self._flight_tick(now, served,
-                              time.perf_counter() - t_wall)
+        with self._span("serve.recorders"):
+            # the perf-timeline drain rides INSIDE the measured wall (the
+            # bench perf block prices the recorder, never hides it); it
+            # runs after the score barrier, so every dispatch of this
+            # tick has folded and its record is complete
+            self._perf_tick_doc = self._perf_drain() if self.perf else None
+            if self._census_tracker is not None:
+                # hot-set bookkeeping every tick (O(served)); the full
+                # resident-bytes census drains on its cadence, INSIDE
+                # the measured wall (the bench census block prices it,
+                # never hides it) and after the perf drain so the
+                # recorder retentions it counts are this tick's.  The
+                # census wall accumulates separately so the bench prices
+                # the overhead IN-RUN (census_wall_s / serve_wall_s —
+                # the ckpt_wall idiom: exact, immune to this box's A/B
+                # leg noise).
+                t0 = time.perf_counter()
+                self._census_tracker.observe(self.clock.ticks, served)
+                self._census_tick_doc = (
+                    self._census_drain()
+                    if self.census
+                    and self._census_tracker.due(self.clock.ticks)
+                    else None)
+                if self.census:
+                    self.census_wall_s += time.perf_counter() - t0
+                else:
+                    # the tracker is alive only to feed the tiering
+                    # decay plane (coldest_candidates): its bookkeeping
+                    # wall is tiering overhead, never a census price
+                    self.tier_wall_s += time.perf_counter() - t0
+            if self.flight_recorder is not None:
+                # the journal entry rides INSIDE the measured wall (the
+                # serve_wall_s accumulation below) — the bench's flight
+                # overhead leg prices the recorder, never hides it
+                self._flight_tick(now, served,
+                                  time.perf_counter() - t_wall)
         if self.policy is not None:
             # the elastic-policy step runs AFTER this tick's journal
             # record (a scale-down must not remove a runner whose
@@ -1787,12 +1796,13 @@ class ServeEngine:
         # telemetry work stays INSIDE the measured wall: the bench's
         # enabled-vs-off overhead number must price the scrape, not
         # hide it
-        self._obs_tick.observe(time.perf_counter() - t_wall)
-        self._obs_ticks.inc()
-        self._obs_tenants.set(len(self._tenant_det)
-                              or len(self._tenant_replay))
-        if self.clock.ticks % self._scrape_every == 0:
-            self._registry.scrape(now_s=now)
+        with self._span("serve.scrape"):
+            self._obs_tick.observe(time.perf_counter() - t_wall)
+            self._obs_ticks.inc()
+            self._obs_tenants.set(len(self._tenant_det)
+                                  or len(self._tenant_replay))
+            if self.clock.ticks % self._scrape_every == 0:
+                self._registry.scrape(now_s=now)
         t_tick = time.perf_counter() - t_wall
         self.serve_wall_s += t_tick
         self.tick_walls.append(t_tick)
@@ -1838,9 +1848,8 @@ class ServeEngine:
         synchronous commit: the supervisor's snapshot must cover this
         tick's folds, or a restore would lose them.
         """
-        for qb in served:
-            self._slo[qb.tenant_id].record(now - qb.enqueued_s)
-            self.n_spans_served += qb.n_spans
+        with self._span("serve.slo"):
+            self._slo_record(now, served)
         self._commit_deferred()
         if self._perf_recs:
             # tick-boundary stamp, POST-barrier: the workers are
@@ -1897,12 +1906,13 @@ class ServeEngine:
         if sup is not None:
             sup.end_tick()
         self.clock.advance()
-        self._obs_tick.observe(time.perf_counter() - t_wall)
-        self._obs_ticks.inc()
-        self._obs_tenants.set(len(self._tenant_det)
-                              or len(self._tenant_replay))
-        if self.clock.ticks % self._scrape_every == 0:
-            self._registry.scrape(now_s=now)
+        with self._span("serve.scrape"):
+            self._obs_tick.observe(time.perf_counter() - t_wall)
+            self._obs_ticks.inc()
+            self._obs_tenants.set(len(self._tenant_det)
+                                  or len(self._tenant_replay))
+            if self.clock.ticks % self._scrape_every == 0:
+                self._registry.scrape(now_s=now)
         t_tick = time.perf_counter() - t_wall
         self.serve_wall_s += t_tick
         self.tick_walls.append(t_tick)
@@ -1954,19 +1964,20 @@ class ServeEngine:
         now, served = d["now"], d["served"]
         if self.rca:
             self._rca_step(now, served)
-        self._perf_tick_doc = self._perf_drain() if self.perf else None
-        if self._census_tracker is not None:
-            t0 = time.perf_counter()
-            self._census_tracker.observe(d["tick"], served)
-            self._census_tick_doc = (
-                self._census_drain(t_idx=d["tick"])
-                if self._census_tracker.due(d["tick"]) else None)
-            self.census_wall_s += time.perf_counter() - t0
-        if self.flight_recorder is not None:
-            self._flight_tick(now, served,
-                              d["coord_wall"]
-                              + (time.perf_counter() - t_barrier),
-                              t_idx=d["tick"], tot=d["tot"])
+        with self._span("serve.recorders"):
+            self._perf_tick_doc = self._perf_drain() if self.perf else None
+            if self._census_tracker is not None:
+                t0 = time.perf_counter()
+                self._census_tracker.observe(d["tick"], served)
+                self._census_tick_doc = (
+                    self._census_drain(t_idx=d["tick"])
+                    if self._census_tracker.due(d["tick"]) else None)
+                self.census_wall_s += time.perf_counter() - t0
+            if self.flight_recorder is not None:
+                self._flight_tick(now, served,
+                                  d["coord_wall"]
+                                  + (time.perf_counter() - t_barrier),
+                                  t_idx=d["tick"], tot=d["tot"])
         if self.policy is not None:
             t0 = time.perf_counter()
             with self._span("serve.policy"):
@@ -2092,6 +2103,12 @@ class ServeEngine:
             self._last_failures = failures
             raise failures[0][1]
 
+    def _slo_record(self, now: float, served: List[QueuedBatch]) -> None:
+        """Per-batch SLO accounting of one tick's served batches."""
+        for qb in served:
+            self._slo[qb.tenant_id].record(now - qb.enqueued_s)
+            self.n_spans_served += qb.n_spans
+
     def _rca_step(self, now: float, served: List[QueuedBatch]) -> None:
         """One tick's RCA pass: evidence buffering on the COORDINATOR
         (shard-count-invariant content), then the alert→culprit pass;
@@ -2198,25 +2215,26 @@ class ServeEngine:
         inline and sharded paths: same-tenant batches concatenate in
         arrival order into one staging; returns the ordered
         ``(det, replay, n_spans, w_ret, plan)`` work list."""
-        per_tenant: Dict[int, List[QueuedBatch]] = {}
-        for qb in served:
-            per_tenant.setdefault(qb.tenant_id, []).append(qb)
-        pending = []
-        for tid, qbs in per_tenant.items():
-            batch = qbs[0].spans if len(qbs) == 1 else \
-                concat_span_batches([qb.spans for qb in qbs])
-            if self.score:
-                det = self._detector_for(tid)
-                replay = det.replay
-            else:
-                det = None
-                replay = self._replay_for(tid)
-            t0 = time.perf_counter()
-            rb = det.replay_batch(batch) if det is not None else batch
-            w_ret, plan = replay.plan_push(rb)
-            if det is not None:
-                det.push_wall_s += time.perf_counter() - t0
-            pending.append((det, replay, batch.n_spans, w_ret, plan))
+        with self._span("serve.stage", batches=len(served)):
+            per_tenant: Dict[int, List[QueuedBatch]] = {}
+            for qb in served:
+                per_tenant.setdefault(qb.tenant_id, []).append(qb)
+            pending = []
+            for tid, qbs in per_tenant.items():
+                batch = qbs[0].spans if len(qbs) == 1 else \
+                    concat_span_batches([qb.spans for qb in qbs])
+                if self.score:
+                    det = self._detector_for(tid)
+                    replay = det.replay
+                else:
+                    det = None
+                    replay = self._replay_for(tid)
+                t0 = time.perf_counter()
+                rb = det.replay_batch(batch) if det is not None else batch
+                w_ret, plan = replay.plan_push(rb)
+                if det is not None:
+                    det.push_wall_s += time.perf_counter() - t0
+                pending.append((det, replay, batch.n_spans, w_ret, plan))
         return pending
 
     def _commit_pending(self, pending: list, runner,
@@ -2234,28 +2252,33 @@ class ServeEngine:
         The wall lands in the ``score`` leg of the serve
         decomposition."""
         from anomod.stream import score_closed_windows_batched
-        t0 = time.perf_counter()
-        work = []
-        for det, replay, n_in, w_ret, plan in pending:
-            if det is None:
-                continue
-            if det.batch_scorable:
-                through = det.note_bookkeep(n_in, w_ret)
-                rng = (det.scoring_window_range(through)
-                       if through is not None else None)
-                if rng is not None:
-                    work.append((det, rng[0], rng[1]))
-            else:
-                det.note_pushed(n_in, w_ret)
-        if chaos_hook is not None:
-            # the SCORE injection point: replay folds committed and
-            # window bookkeeping advanced, batched scoring not yet run
-            chaos_hook("score")
-        if work:
-            score_closed_windows_batched(work, _plane_col_gather(work))
-        dt = time.perf_counter() - t0
-        runner.score_wall_s += dt
-        runner._obs_score_s.inc(dt)
+        with self._span("serve.commit"):
+            t0 = time.perf_counter()
+            work = []
+            with self._span("serve.bookkeep", tenants=len(pending)):
+                for det, replay, n_in, w_ret, plan in pending:
+                    if det is None:
+                        continue
+                    if det.batch_scorable:
+                        through = det.note_bookkeep(n_in, w_ret)
+                        rng = (det.scoring_window_range(through)
+                               if through is not None else None)
+                        if rng is not None:
+                            work.append((det, rng[0], rng[1]))
+                    else:
+                        det.note_pushed(n_in, w_ret)
+            if chaos_hook is not None:
+                # the SCORE injection point: replay folds committed and
+                # window bookkeeping advanced, batched scoring not yet
+                # run
+                chaos_hook("score")
+            with self._span("serve.score_windows", tenants=len(work)):
+                if work:
+                    score_closed_windows_batched(work,
+                                                 _plane_col_gather(work))
+            dt = time.perf_counter() - t0
+            runner.score_wall_s += dt
+            runner._obs_score_s.inc(dt)
 
     # -- the performance observatory (anomod.obs.perf) --------------------
 

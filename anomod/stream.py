@@ -65,7 +65,8 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from anomod.replay import (F_COUNT, F_ERR, F_LOGLAT, N_FEATS, ReplayConfig,
-                           ReplayState, make_chunk_step, stage_columns)
+                           ReplayState, make_chunk_step, named_jit,
+                           stage_columns)
 from anomod.schemas import LOG_ERROR, SpanBatch, take_spans
 
 
@@ -256,7 +257,6 @@ class StreamReplay:
 
     def __init__(self, cfg: ReplayConfig, t0_us: int,
                  with_hll: bool = False):
-        import jax
         import jax.numpy as jnp
 
         self.cfg = cfg
@@ -264,7 +264,8 @@ class StreamReplay:
         self.window_offset = 0     # absolute window index of plane column 0
         self.n_spans = 0
         step = make_chunk_step(cfg, with_hll=with_hll)
-        self._step = jax.jit(lambda st, ch: step(st, ch)[0])
+        self._step = named_jit("anomod_chunk_step",
+                               lambda st, ch: step(st, ch)[0])
         self.state = ReplayState(
             agg=jnp.zeros((cfg.sw, N_FEATS), jnp.float32),
             hist=jnp.zeros((cfg.sw, cfg.n_hist_buckets), jnp.float32),

@@ -17,6 +17,13 @@ right, and the Jaeger shape has no way to say "maybe").
 Durability contract: :meth:`Tracer.dump` publishes atomically
 (same-directory tmp + ``os.replace``, the anomod.io.cache idiom), so a
 run killed mid-write never leaves a truncated JSON behind a valid path.
+
+Clock contract: every :meth:`Tracer.span` is also a
+``jax.profiler.TraceAnnotation`` of the same name for the span's life, so
+inside a ``jax.profiler`` session the spans land on the profiler's host
+plane beside the device ops (one timeline, one clock).  With no session
+the annotation is a flag test.  The class is imported once, when the
+tracer is built; a tracer built where JAX is absent annotates nothing.
 """
 
 from __future__ import annotations
@@ -28,6 +35,27 @@ import threading
 import time
 from pathlib import Path
 from typing import List, Optional
+
+
+#: the one shared no-op a seam enters when it holds no tracer
+NO_SPAN = contextlib.nullcontext()
+
+
+def span_of(tracer, name: str, **tags):
+    """``tracer.span(name, **tags)``, or the shared no-op where the seam
+    holds no tracer.  Tags are passed when the span opens and never set on
+    what it yields: a :class:`Tracer` yields a :class:`Span`, the
+    benchmark's tracer a list, no tracer ``None``."""
+    return tracer.span(name, **tags) if tracer is not None else NO_SPAN
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is absent."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class Span:
@@ -62,14 +90,7 @@ class Tracer:
         # of all collapsing onto lane 0, so a sharded run's concurrency
         # structure is visually inspectable
         self._tids: dict = {}
-
-    def _tid(self) -> int:
-        ident = threading.get_ident()
-        with self._lock:
-            got = self._tids.get(ident)
-            if got is None:
-                got = self._tids[ident] = len(self._tids)
-            return got
+        self._annotate = _profiler_annotation()
 
     def _stack(self) -> List[int]:
         stack = getattr(self._tls, "stack", None)
@@ -86,16 +107,25 @@ class Tracer:
     def span(self, name: str, **tags):
         stack = self._stack()
         parent = stack[-1] if stack else None
+        ident = threading.get_ident()
         start = time.time()
         rec = {"name": name, "start": start, "dur": 0.0, "parent": parent,
-               "tid": self._tid(),
+               "tid": 0,
                "tags": {str(k): v for k, v in tags.items()}, "events": []}
         with self._lock:
+            tid = self._tids.get(ident)
+            if tid is None:
+                tid = self._tids[ident] = len(self._tids)
+            rec["tid"] = tid
             idx = len(self._spans)
             self._spans.append(rec)
         stack.append(idx)
         try:
-            yield Span(rec)
+            if self._annotate is None:
+                yield Span(rec)
+            else:
+                with self._annotate(name):
+                    yield Span(rec)
         finally:
             stack.pop()
             rec["dur"] = time.time() - start
