@@ -250,7 +250,7 @@ def _serve_state_env() -> str:
     ``host`` is the pre-device-pool seam: per-tenant numpy state pytrees,
     the lane fold materializes every dispatch's deltas to host and adds
     them per lane.  ``device`` keeps every shard's tenant states in ONE
-    device-resident pool ([slots, SW, F] agg + hist planes, tenants
+    device-resident pool (agg + hist planes of flat tenant rows, tenants
     mapped to slots at first service) and folds lane deltas with an
     on-device scatter-add in dispatch order — pinned BIT-identical to
     the host seam (an XLA f32 scatter-add with unique per-dispatch slots

@@ -177,11 +177,21 @@ def span_batch_nbytes(batch) -> int:
 
 
 def pool_slot_nbytes(cfg) -> int:
-    """Per-slot bytes of one tenant's replay state: the [SW, F] f32
-    agg row plus the [SW, H] f32 hist row — the SAME shape whether the
-    state lives in a device pool slot or a host-seam pytree."""
+    """Bytes of one tenant's replay state as a host-seam pytree: the
+    [SW, F] f32 agg plus the [SW, H] f32 hist."""
     from anomod.replay import N_FEATS
     return cfg.sw * (N_FEATS + cfg.n_hist_buckets) * 4
+
+
+def pool_row_nbytes(cfg) -> int:
+    """Bytes one slot of a :class:`anomod.replay.TenantStatePool` holds
+    for the same state: each half is a flat row held at a multiple of
+    128 floats (:func:`anomod.replay.pool_row_width`), so this is
+    :func:`pool_slot_nbytes` plus the padding (none at the serve
+    plane's default shape; 256 B on TT's 126,720)."""
+    from anomod.replay import N_FEATS, pool_row_width
+    return 4 * (pool_row_width(cfg.sw * N_FEATS)
+                + pool_row_width(cfg.sw * cfg.n_hist_buckets))
 
 
 def tdigest_nbytes(digest) -> int:
@@ -216,18 +226,20 @@ def collect_resident_bytes(engine) -> Tuple[List[dict], Dict[str, int],
     ``planes`` is the per-(shard, plane) record list in (shard, plane)
     order (coordinator-owned planes use shard ``-1``), ``by_plane``
     sums bytes per plane name, and ``pool_reconciled`` is the pin that
-    every state pool's array bytes equal ``(capacity + 1) × per-slot
-    nbytes`` exactly.  A pure read: no clocks, no RNG, no mutation —
-    the same engine state always censuses to the same bytes."""
+    every state pool's array bytes equal ``(capacity + 1) × per-row
+    nbytes`` (:func:`pool_row_nbytes`) exactly.  A pure read: no
+    clocks, no RNG, no mutation — the same engine state always
+    censuses to the same bytes."""
     planes: List[dict] = []
     reconciled = True
     cfg = engine.cfg
-    slot_b = pool_slot_nbytes(cfg)
+    slot_b, row_b = pool_slot_nbytes(cfg), pool_row_nbytes(cfg)
 
     # tenant states: device pools per shard runner, or the host seam's
-    # per-tenant pytrees (same per-slot shape — counted per owned
-    # resident replay, NEVER read through .state: a pooled gather
-    # would copy megabytes for a byte count the shapes already give)
+    # per-tenant pytrees (the same state, a pool row padded to the lane
+    # tile — counted per owned resident replay, NEVER read through
+    # .state: a pooled gather would copy megabytes for a byte count
+    # the shapes already give)
     owned: Dict[int, int] = {}
     for tid in engine._tenant_replay:
         s = engine.shard_of.get(tid, 0)
@@ -236,14 +248,14 @@ def collect_resident_bytes(engine) -> Tuple[List[dict], Dict[str, int],
         pool = runner.pool
         if pool is not None:
             arr_b = plane_nbytes(pool.agg) + plane_nbytes(pool.hist)
-            expect = (pool.capacity + 1) * slot_b
+            expect = (pool.capacity + 1) * row_b
             ok = arr_b == expect
             reconciled = reconciled and ok
             planes.append({"shard": s, "plane": "pool",
                            "mode": "device", "bytes": arr_b,
                            "slots_used": int(pool.live_slots),
                            "capacity": int(pool.capacity),
-                           "slot_bytes": slot_b, "reconciled": ok})
+                           "slot_bytes": row_b, "reconciled": ok})
         else:
             n = owned.get(s, 0)
             planes.append({"shard": s, "plane": "pool", "mode": "host",

@@ -447,27 +447,64 @@ def fold_delta(state: ReplayState, dagg, dhist) -> ReplayState:
                        hist=np.asarray(state.hist) + dhist)
 
 
+#: the TPU's lane tile: a pool plane holds its rows at a multiple of it
+POOL_ROW_ALIGN = 128
+
+
+def pool_row_width(n_floats: int) -> int:
+    """Columns a :class:`TenantStatePool` plane holds for a flat state row
+    of ``n_floats`` floats: the next multiple of the 128-lane tile (TT's
+    agg row of 8,640 is held at 8,704, +0.74% of that plane and +0.2% of
+    the pool; TT's hist row of 23,040 and both SN rows need none)."""
+    return -(-int(n_floats) // POOL_ROW_ALIGN) * POOL_ROW_ALIGN
+
+
 class TenantStatePool:
     """POOL-RESIDENT per-tenant replay states for the serving plane.
 
-    One ``[slots, SW, F]`` agg plane plus a matching ``[slots, SW, H]``
-    hist plane per shard runner; tenants map to slots at first service
-    (:meth:`acquire`).  Row 0 is the DEAD slot: dead pad lanes (and the
-    non-current occurrences of a duplicated slot, see
-    :meth:`scatter_fold`) scatter their deltas there, and it is never
-    read.  The hot-loop fold becomes one scatter-add per retired
-    dispatch — the per-lane interpreter adds (and, on accelerator
-    backends, the per-tick device→host materialization barrier) of the
-    host seam disappear — while :meth:`gather`/:meth:`put` keep the
-    ``get_state``/``set_state`` round-trip bit-exact for parity checks,
-    checkpoints and (future) migration.
+    One ``[slots, row(SW·F)]`` agg plane plus a matching
+    ``[slots, row(SW·H)]`` hist plane per shard runner: a tenant's
+    ``[SW, F]`` / ``[SW, H]`` state is ONE FLAT ROW of its plane, held
+    at :func:`pool_row_width` columns (the row's floats, then zero
+    padding up to a multiple of 128 that nothing reads).  Tenants map
+    to slots at first service (:meth:`acquire`).  Row 0 is the DEAD
+    slot: dead pad lanes (and the non-current occurrences of a
+    duplicated slot, see :meth:`scatter_fold`) scatter their deltas
+    there, and it is never read.  The hot-loop fold becomes one
+    scatter-add per retired dispatch — the per-lane interpreter adds
+    (and, on accelerator backends, the per-tick device→host
+    materialization barrier) of the host seam disappear — while
+    :meth:`gather`/:meth:`put` keep the ``get_state``/``set_state``
+    round-trip bit-exact for parity checks, checkpoints and (future)
+    migration.
 
-    Two fold ENGINES behind one seam, picked by backend (``auto``):
+    Why flat rows (one v5e, PERF.md section 6, PR 27): a TPU array
+    lives in (8, 128) tiles over its two minor dimensions, and the
+    device orders each shape's dimensions itself (for every shape read
+    here, the order that pads least).  The ``[slots, SW, F]`` planes
+    this class held before came out
+    SLOT-MINOR (``f32[34501,1440,6]{0,1,2:T(8,128)}``: 128 tenants of
+    one cell side by side in a tile), while a scatter over rows
+    compiles against slot-MAJOR operands — so every fold transposed
+    both whole planes and back (27.6 ms a dispatch at 34,501 rows for
+    0.05 ms of adds), and a one-row put or gather touched every tile of
+    the pool.  Donation kept the BUFFER in place, not the LAYOUT.  A
+    2-D plane whose row is a multiple of the 128-lane tile comes out
+    row-major (``{1,0:T(8,128)}``), which is the layout the scatter,
+    the row updates and the row gathers all compile to: device time
+    per op follows the rows touched, never the rows held
+    (tpu_tests/test_pool_layout.py guards it).  The row is padded in
+    the SHAPE because an unpadded width that is no multiple of 128
+    (TT's 8,640 = 67.5 tiles) comes out slot-minor again.
+
+    Two fold ENGINES behind one seam, picked by backend (``auto``),
+    both holding the same flat planes:
 
     - ``jax`` (accelerator backends): the planes are device arrays, the
-      ops are jitted with buffer DONATION (XLA updates them in place —
-      no per-op pool copy), the scored-window gather is one fused
-      dispatch materializing only the requested columns.
+      ops are jitted with buffer DONATION (the output aliases the
+      input, and in the flat layout XLA updates the touched rows in
+      place), the scored-window gather is one fused dispatch
+      materializing only the requested columns.
     - ``numpy`` (the CPU backend): "device" memory IS host RAM there,
       and XLA:CPU's fixed per-dispatch overhead (~0.2-0.5 ms/call)
       swamps these row shapes — so the planes are host arrays and every
@@ -487,6 +524,13 @@ class TenantStatePool:
     not a tolerance trade.
     """
 
+    #: rows one step of the jitted window gather holds at once.  On one
+    #: v5e at 34,501 TT rows (PERF.md section 6, PR 27): a request of
+    #: 4,096 takes 3.5 ms in chunks of 512 against 4.9 ms whole, one of
+    #: 65,536 (the warm grid's largest) 51 ms and 0.27 GB of temporaries
+    #: against 73 ms and 5.4 GB
+    _GATHER_CHUNK = 512
+
     def __init__(self, cfg: ReplayConfig, capacity: int = 32,
                  engine: str = "auto", gather_engine: str = "xla"):
         import jax
@@ -505,23 +549,37 @@ class TenantStatePool:
         #: batched-scoring gather formulation: "xla" (take_along_axis /
         #: the numpy engine's fancy-index twin) or "pallas" (the fused
         #: Mosaic gather kernel, anomod.ops.pallas_replay.
-        #: make_pallas_window_gather_fn — the serve plane routes
+        #: make_pallas_window_gather_fn, over the touched rows reshaped
+        #: to its [T, SW, F] operand — the serve plane routes
         #: ANOMOD_SERVE_LANE_ENGINE=pallas here).  A pure copy either
         #: way: bit-identical outputs.  The scatter FOLD stays on the
         #: engine's scatter-add (one fused dispatch / one vectorized
         #: in-place add already; see the kernel's docstring for why a
         #: Mosaic scatter is the unverifiable half).
         self.gather_engine = gather_engine
+        S, W, H = cfg.n_services, cfg.n_windows, cfg.n_hist_buckets
+        #: floats of one tenant's agg / hist row (its plane holds them
+        #: at pool_row_width columns: the tail is padding nothing reads)
+        self._wa, self._wh = cfg.sw * N_FEATS, cfg.sw * H
+        wa, wh = self._wa, self._wh
         self._pallas_gather = None
         if gather_engine == "pallas":
             from anomod.ops.pallas_replay import make_pallas_window_gather_fn
-            self._pallas_gather = make_pallas_window_gather_fn(
+            kernel = make_pallas_window_gather_fn(
                 cfg.n_services, cfg.n_windows, N_FEATS,
                 interpret=jax.default_backend() != "tpu")
+
+            def _pallas_gather(agg, slots, cols):
+                T = slots.shape[0]
+                rows = agg[slots][:, :wa]
+                return kernel(rows.reshape(T, cfg.sw, N_FEATS),
+                              jnp.arange(T, dtype=jnp.int32), cols)
+
+            self._pallas_gather = jax.jit(_pallas_gather)
         cap = max(int(capacity), 1)
         # +1: row 0 is the dead slot
-        shape_a = (cap + 1, cfg.sw, N_FEATS)
-        shape_h = (cap + 1, cfg.sw, cfg.n_hist_buckets)
+        shape_a = (cap + 1, pool_row_width(wa))
+        shape_h = (cap + 1, pool_row_width(wh))
         if engine == "numpy":
             self.agg = np.zeros(shape_a, np.float32)
             self.hist = np.zeros(shape_h, np.float32)
@@ -530,7 +588,6 @@ class TenantStatePool:
             self.hist = jnp.zeros(shape_h, jnp.float32)
         self._free: list = []
         self._next = 1
-        S, W = cfg.n_services, cfg.n_windows
         if engine == "numpy":
             return
 
@@ -539,15 +596,27 @@ class TenantStatePool:
         # compile-cache entries — warm() precompiles the serve grid).
         # The mutating ops DONATE the planes: the pool is the sole
         # owner of its buffers (every read goes through gather /
-        # gather_window), so XLA updates the [slots, SW, *] planes in
-        # place instead of copying megabytes per fold — the rebind
-        # below always installs the op's output before anything can
-        # read again.
+        # gather_window) and the rebind below always installs the op's
+        # output before anything can read again.  Donation only lets
+        # the output alias the input buffer; what keeps the update IN
+        # PLACE is the planes' row-major layout (class docstring): each
+        # op below reads and writes whole flat rows of it and reshapes
+        # only the rows it touches, so none compiles to an op over a
+        # whole plane.  The [lanes, SW, *] deltas are flattened inside
+        # the fold: a relayout of the lanes, not of the pool.
+        def _flat(delta, width, plane):
+            rows = delta.reshape(delta.shape[0], width)
+            return jnp.pad(rows, ((0, 0), (0, plane.shape[1] - width)))
+
         def _scatter(agg, hist, slots, dagg, dhist):
-            return agg.at[slots].add(dagg), hist.at[slots].add(dhist)
+            return (agg.at[slots].add(_flat(dagg, wa, agg)),
+                    hist.at[slots].add(_flat(dhist, wh, hist)))
 
         def _put(agg, hist, slot, ragg, rhist):
-            return agg.at[slot].set(ragg), hist.at[slot].set(rhist)
+            return (jax.lax.dynamic_update_slice(
+                        agg, ragg.reshape(1, wa), (slot, 0)),
+                    jax.lax.dynamic_update_slice(
+                        hist, rhist.reshape(1, wh), (slot, 0)))
 
         def _roll(agg, hist, slot, shift):
             # device twin of anomod.stream.roll_ring_state on one row:
@@ -559,19 +628,36 @@ class TenantStatePool:
             live = (idx < W)[None, :, None]
 
             def roll2(plane, width):
-                x = plane[slot].reshape(S, W, width)
+                x = jax.lax.dynamic_slice(plane, (slot, 0), (1, width))
+                x = x.reshape(S, W, -1)
                 out = jnp.where(live, jnp.take(x, take, axis=1), 0.0)
-                return plane.at[slot].set(out.reshape(S * W, width))
+                return jax.lax.dynamic_update_slice(
+                    plane, out.reshape(1, width), (slot, 0))
 
-            return (roll2(agg, N_FEATS), roll2(hist, cfg.n_hist_buckets))
+            return roll2(agg, wa), roll2(hist, wh)
+
+        chunk = self._GATHER_CHUNK     # the closures below hold no self
+
+        def _gather_chunk(agg, slots, cols):
+            rows = agg[slots][:, :wa].reshape(slots.shape[0], S, W,
+                                              N_FEATS)
+            return jnp.take_along_axis(
+                rows, cols[:, None, None, None], axis=2)[:, :, 0]
 
         def _gather_window(agg, slots, cols):
             # [T, S, F]: ONE dispatch materializing only the scored
             # window column of each requested tenant — the batched
-            # scorer's gather (the full [SW, F] rows stay on device)
-            rows = agg[slots].reshape(slots.shape[0], S, W, N_FEATS)
-            return jnp.take_along_axis(
-                rows, cols[:, None, None, None], axis=2)[:, :, 0]
+            # scorer's gather.  The touched rows are gathered whole and
+            # the column taken from them, _GATHER_CHUNK rows at a time
+            # (requests are powers of two): the temporaries stay those
+            # of one chunk whatever the request
+            T = slots.shape[0]
+            if T <= chunk:
+                return _gather_chunk(agg, slots, cols)
+            out = jax.lax.map(lambda sc: _gather_chunk(agg, *sc),
+                              (slots.reshape(-1, chunk),
+                               cols.reshape(-1, chunk)))
+            return out.reshape(T, S, N_FEATS)
 
         self._scatter_fn = named_jit("anomod_pool_scatter", _scatter,
                                      donate_argnums=(0, 1))
@@ -627,19 +713,22 @@ class TenantStatePool:
         parity, checkpoint, calibration, migration).  Always a COPY —
         the returned pytree must not alias rows later folds mutate."""
         slot = int(slot)   # a None slot must raise, not np.newaxis
+        sw = self.cfg.sw
+        agg, hist = self.agg[slot, :self._wa], self.hist[slot, :self._wh]
         if self.engine == "numpy":
-            return ReplayState(agg=self.agg[slot].copy(),
-                               hist=self.hist[slot].copy())
-        return ReplayState(agg=np.asarray(self.agg[slot]),
-                           hist=np.asarray(self.hist[slot]))
+            agg, hist = agg.copy(), hist.copy()
+        return ReplayState(agg=np.asarray(agg).reshape(sw, -1),
+                           hist=np.asarray(hist).reshape(sw, -1))
 
     def put(self, slot: int, state: ReplayState) -> None:
         """Install an externally-built state into a slot (set_state
         seam); a put(gather()) round-trip is byte-identical."""
         slot = int(slot)   # a None slot must raise, not broadcast
         if self.engine == "numpy":
-            self.agg[slot] = np.asarray(state.agg, np.float32)
-            self.hist[slot] = np.asarray(state.hist, np.float32)
+            self.agg[slot, :self._wa] = np.asarray(
+                state.agg, np.float32).reshape(-1)
+            self.hist[slot, :self._wh] = np.asarray(
+                state.hist, np.float32).reshape(-1)
             return
         self.agg, self.hist = self._put_fn(
             self.agg, self.hist, np.int32(slot),
@@ -655,9 +744,10 @@ class TenantStatePool:
         if self.engine == "numpy":
             cfg = self.cfg
             S, W = cfg.n_services, cfg.n_windows
-            for plane, width in ((self.agg, N_FEATS),
-                                 (self.hist, cfg.n_hist_buckets)):
-                x = plane[slot].reshape(S, W, width)   # in-place view
+            for plane, width in ((self.agg, self._wa),
+                                 (self.hist, self._wh)):
+                # in-place view of the row's floats
+                x = plane[slot, :width].reshape(S, W, -1)
                 if shift < W:
                     x[:, :W - shift] = x[:, shift:].copy()
                     x[:, W - shift:] = 0.0
@@ -697,17 +787,18 @@ class TenantStatePool:
             # in-place adds (measured in bench_fold_sweep.py — a
             # fancy-index += triggers numpy's gather/add/scatter
             # temporaries and loses to both)
-            da = np.asarray(dagg)
-            dh = np.asarray(dhist)
+            wa, wh = self._wa, self._wh
+            da = np.asarray(dagg).reshape(L, wa)
+            dh = np.asarray(dhist).reshape(L, wh)
             lo = ls[0]
             if ls == list(range(lo, lo + n)):
-                self.agg[lo:lo + n] += da[:n]
-                self.hist[lo:lo + n] += dh[:n]
+                self.agg[lo:lo + n, :wa] += da[:n]
+                self.hist[lo:lo + n, :wh] += dh[:n]
             else:
                 for i, s in enumerate(ls):
-                    a = self.agg[s]
+                    a = self.agg[s, :wa]
                     np.add(a, da[i], out=a)
-                    h = self.hist[s]
+                    h = self.hist[s, :wh]
                     np.add(h, dh[i], out=h)
             return
         live = np.asarray(slots, np.int32)
@@ -744,9 +835,12 @@ class TenantStatePool:
         T = slots.shape[0]
         if self._pallas_gather is None and self.engine == "numpy":
             cfg = self.cfg
-            r = self.agg.reshape(self.agg.shape[0], cfg.n_services,
-                                 cfg.n_windows, N_FEATS)
-            return r[slots[:, None], :, cols[:, None]][:, 0]
+            # float (s, cols[t], f) of a flat row sits at
+            # (s * W + cols[t]) * F + f
+            at = ((np.arange(cfg.n_services)[None, :, None] * cfg.n_windows
+                   + cols[:, None, None]) * N_FEATS
+                  + np.arange(N_FEATS)[None, None, :])
+            return np.asarray(self.agg[slots[:, None, None], at])
         pad = 1
         while pad < T:
             pad *= 2
@@ -760,7 +854,9 @@ class TenantStatePool:
     def gather_rows(self, slots) -> np.ndarray:
         """[T, SW, F] host copy of whole agg rows (calibration-time
         bulk gather; scoring uses :meth:`gather_window`)."""
-        return np.asarray(self.agg[np.asarray(slots, np.int32)])
+        slots = np.asarray(slots, np.int32)
+        rows = np.asarray(self.agg[slots])[:, :self._wa]
+        return rows.reshape(len(slots), self.cfg.sw, N_FEATS)
 
     def warm(self, lane_buckets: Tuple[int, ...] = ()) -> float:
         """Compile the pool's hot ops OUTSIDE the measured serve wall:

@@ -203,8 +203,8 @@ def phase_serve(serve_kw, expect_engines=("matmul", "jax"),
     # one invariant a wrong device result would break: every served span
     # was folded into exactly one live slot of the pool (slot 0 is the
     # dead slot; the 32-window ring never rolls inside the run)
-    folded = float(np.asarray(pool.agg)[1:, :, F_COUNT]
-                   .astype(np.float64).sum())
+    folded = float(pool.gather_rows(np.arange(1, pool.capacity + 1))
+                   [:, :, F_COUNT].astype(np.float64).sum())
     assert folded == rep.served_spans, \
         f"pool count plane sums to {folded}, served {rep.served_spans}"
     # bit parity on THIS backend at THIS size — reported, not asserted

@@ -12,7 +12,7 @@ import pytest
 from anomod.obs.census import (CENSUS_PLANES, collect_resident_bytes,
                                diff_census, fit_slope, fit_zipf,
                                fleet_probe, plane_nbytes,
-                               pool_slot_nbytes,
+                               pool_row_nbytes, pool_slot_nbytes,
                                process_resident_bytes,
                                span_batch_nbytes)
 from anomod.serve.engine import run_power_law
@@ -215,8 +215,10 @@ def test_span_batch_nbytes_exact():
 
 
 def test_pool_reconciliation_survives_growth():
-    """The (capacity + 1) × per-slot pin holds through pool doubling
-    (growth concatenates zero rows — the shape algebra must follow)."""
+    """The (capacity + 1) × per-row pin holds through pool doubling
+    (growth concatenates zero rows — the shape algebra must follow).
+    This shape's agg row (192 floats) is held at 256: the pool's row is
+    the host pytree's bytes plus that padding."""
     from anomod.replay import TenantStatePool
     from anomod.serve.engine import serve_plane_cfg
     cfg = serve_plane_cfg(4, 5.0, 8)
@@ -224,7 +226,8 @@ def test_pool_reconciliation_survives_growth():
     for _ in range(6):
         pool.acquire()                     # forces two doublings
     got = plane_nbytes(pool.agg) + plane_nbytes(pool.hist)
-    assert got == (pool.capacity + 1) * pool_slot_nbytes(cfg)
+    assert got == (pool.capacity + 1) * pool_row_nbytes(cfg)
+    assert pool_row_nbytes(cfg) == pool_slot_nbytes(cfg) + 4 * (256 - 192)
     assert pool.capacity >= 6
 
 

@@ -1,0 +1,122 @@
+"""The device pool's row ops compiled for a described v5e, with no chip.
+
+What PR 27 found on the chip (PERF.md section 6): a ``[slots, SW, F]``
+plane is resident slot-MINOR on the TPU, so the donated scatter fold
+transposed the whole pool and back on every dispatch.  The planes are
+flat rows held at a multiple of the lane tile now, and the programs
+below must stay free of any op over a whole plane except the in-place
+update itself.  The TPU's compiler is installed here and compiles for a
+chip that is described and not attached; ``tpu_tests/test_pool_layout.py``
+is the same guard on the chip, with times.
+
+All of it in this one file, the topology described inside a fixture:
+only one process at a time may load the TPU's library.
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax import ShapeDtypeStruct as SDS
+
+from anomod.replay import N_FEATS, ReplayConfig, TenantStatePool
+
+#: the benchmark's fleet cell: 34,500 tenants and the dead row, TT shape
+FLEET_ROWS, LANES = 34501, 32
+SHAPES = {"tt": (45, 32), "sn": (12, 32)}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def optimizing():
+    """tests/conftest.py turns most XLA optimizations off for the CPU
+    suite; the programs read here are the chip's, so they are compiled
+    as the chip compiles them, and never through the persistent cache
+    (an entry compiled for a described chip cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = (jax.config.read("jax_disable_most_optimizations"),
+           jax.config.jax_enable_compilation_cache)
+    jax.config.update("jax_disable_most_optimizations", False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_disable_most_optimizations", was[0])
+    jax.config.update("jax_enable_compilation_cache", was[1])
+    compilation_cache.reset_cache()
+
+
+def plane_ops(text: str, rows: int):
+    """(name, opcode) of every instruction of the entry computation whose
+    result has ``rows`` leading rows, parameters and tuples aside."""
+    entry = text[text.index("ENTRY"):]
+    found = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) ([a-z\-]+)\(", line)
+        if m and m.group(3) not in ("parameter", "tuple", "bitcast",
+                                    "get-tuple-element") \
+                and f"[{rows}," in m.group(2):
+            found.append((m.group(1), m.group(3)))
+    return found
+
+
+def _programs(shape, sharding):
+    cfg = ReplayConfig(n_services=shape[0], n_windows=shape[1],
+                       window_us=5_000_000, chunk_size=4096)
+    pool = TenantStatePool(cfg, capacity=1, engine="jax")
+
+    def sds(dims, dtype=jnp.float32):
+        return SDS(dims, dtype, sharding=sharding)
+
+    agg = sds((FLEET_ROWS, pool.agg.shape[1]))
+    hist = sds((FLEET_ROWS, pool.hist.shape[1]))
+    row_a = sds((cfg.sw, N_FEATS))
+    row_h = sds((cfg.sw, cfg.n_hist_buckets))
+    i32 = jnp.int32
+    return {
+        "scatter": (pool._scatter_fn,
+                    (agg, hist, sds((LANES,), i32),
+                     sds((LANES,) + row_a.shape), sds((LANES,) + row_h.shape))),
+        "put": (pool._put_fn, (agg, hist, sds((), i32), row_a, row_h)),
+        "roll": (pool._roll_fn, (agg, hist, sds((), i32), sds((), i32))),
+        "gather_window": (pool._gather_window_fn,
+                          (agg, sds((1024,), i32), sds((1024,), i32))),
+    }, (agg, hist)
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+@pytest.mark.parametrize("op", ["scatter", "put", "roll", "gather_window"])
+def test_pool_op_compiles_with_no_whole_plane_op(one_chip, optimizing,
+                                                 op, shape):
+    programs, (agg, hist) = _programs(SHAPES[shape], one_chip)
+    fn, args = programs[op]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    # the planes are resident row-major: a tenant's row is contiguous
+    for plane in (agg, hist) if op != "gather_window" else (agg,):
+        dims = ",".join(map(str, plane.shape))
+        assert f"f32[{dims}]{{1,0:T(8,128)}}" in text.split("ENTRY")[0]
+    found = plane_ops(text, FLEET_ROWS)
+    mem = compiled.memory_analysis()
+    if op == "gather_window":
+        assert found == []
+        return
+    # one in-place update per plane and nothing else plane-sized: no
+    # copy, no transpose, no temporary the size of a plane
+    assert sorted(code for _, code in found) == (
+        ["fusion", "fusion"] if op == "scatter"
+        else ["dynamic-update-slice"] * 2), found
+    assert mem.temp_size_in_bytes < 4 * agg.shape[1] * 1024
+    assert mem.alias_size_in_bytes >= 4 * FLEET_ROWS * (
+        agg.shape[1] + hist.shape[1])

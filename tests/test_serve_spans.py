@@ -254,17 +254,18 @@ def jitted():
     pool = TenantStatePool(cfg, capacity=3, engine="jax")
     runner = BucketRunner(cfg, (64,), lane_buckets=(2,), state="host")
     H = cfg.n_hist_buckets
-    agg = np.zeros((4, cfg.sw, N_FEATS), np.float32)
-    hist = np.zeros((4, cfg.sw, H), np.float32)
+    agg, hist = np.asarray(pool.agg), np.asarray(pool.hist)
+    dagg = np.zeros((2, cfg.sw, N_FEATS), np.float32)
+    dhist = np.zeros((2, cfg.sw, H), np.float32)
     slots = np.asarray([1, 2], np.int32)
     chunk = dead_chunk(cfg, 64, xp=np)
     lanes = {k: np.broadcast_to(v, (2, 64)) for k, v in chunk.items()}
-    zero = ReplayState(agg=agg[0], hist=hist[0])
+    zero = ReplayState(agg=dagg[0], hist=dhist[0])
     return {
         "anomod_pool_scatter": (pool._scatter_fn,
-                                (agg, hist, slots, agg[:2], hist[:2])),
+                                (agg, hist, slots, dagg, dhist)),
         "anomod_pool_put": (pool._put_fn,
-                            (agg, hist, np.int32(1), agg[0], hist[0])),
+                            (agg, hist, np.int32(1), dagg[0], dhist[0])),
         "anomod_pool_roll": (pool._roll_fn,
                              (agg, hist, np.int32(1), np.int32(2))),
         "anomod_pool_gather_window": (pool._gather_window_fn,

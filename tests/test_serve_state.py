@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from anomod.replay import (N_FEATS, ReplayConfig, ReplayState,
-                           TenantStatePool, fold_delta)
+                           TenantStatePool, fold_delta, pool_row_width)
 from anomod.schemas import SpanBatch
 from anomod.stream import (OnlineDetector, StreamReplay,
                            roll_ring_state, score_closed_windows_batched)
@@ -50,13 +50,33 @@ def _assert_state_bytes(a: ReplayState, b: ReplayState):
 
 ENGINES = ("numpy", "jax")
 
+#: the pool holds a tenant's state as one flat row padded to a multiple
+#: of 128 floats (TenantStatePool docstring), so the pins run at row
+#: widths that are and are not such a multiple: tiny 192 (held at 256)
+#: and 512; the SN shape 2,304 and 6,144 (both are); the TT shape 8,640
+#: (67.5 tiles: held at 8,704) and 23,040 (is)
+SHAPES = {"tiny": (4, 8), "sn": (12, 32), "tt": (45, 32)}
+BOTH = pytest.mark.parametrize("engine", ENGINES)
+ALL_SHAPES = pytest.mark.parametrize("shape", list(SHAPES))
 
-@pytest.mark.parametrize("engine", ENGINES)
-def test_pool_round_trip_bit_exact_under_interleavings(engine):
+
+def test_pool_rows_are_flat_and_held_at_a_lane_multiple():
+    assert [pool_row_width(n) for n in (1, 128, 192, 2304, 8640, 23040)] \
+        == [128, 128, 256, 2304, 8704, 23040]
+    for engine in ENGINES:
+        pool = TenantStatePool(_cfg(*SHAPES["tt"]), capacity=3,
+                               engine=engine)
+        assert pool.agg.shape == (4, 8704) and pool.hist.shape == (4, 23040)
+        assert pool.capacity == 3
+
+
+@BOTH
+@ALL_SHAPES
+def test_pool_round_trip_bit_exact_under_interleavings(engine, shape):
     """get_state/set_state seam via the pool: arbitrary cross-tenant
     interleavings of put/gather/roll/scatter_fold stay byte-identical
     to a host-side mirror applying fold_delta/roll_ring_state."""
-    cfg = _cfg()
+    cfg = _cfg(*SHAPES[shape])
     rng = np.random.default_rng(42)
     pool = TenantStatePool(cfg, capacity=4, engine=engine)
     slots = [pool.acquire() for _ in range(4)]
@@ -86,19 +106,22 @@ def test_pool_round_trip_bit_exact_under_interleavings(engine):
         _assert_state_bytes(pool.gather(s), mirror[s])
 
 
-@pytest.mark.parametrize("engine", ENGINES)
-def test_pool_scatter_duplicate_slots_fold_in_lane_order(engine):
+@BOTH
+@ALL_SHAPES
+def test_pool_scatter_duplicate_slots_fold_in_lane_order(engine, shape):
     """A slot repeated within one dispatch folds in LANE order via wave
     splitting: ((state + d0) + d1), bit-for-bit — never a pre-combined
     d0 + d1 handed to one scatter (XLA's duplicate-index add order is
     unspecified, and a numpy fancy-index += drops duplicates; the waves
-    make both deterministic)."""
-    cfg = _cfg()
+    make both deterministic).  The dead pad lane's delta goes to row 0
+    and touches no live row."""
+    cfg = _cfg(*SHAPES[shape])
     rng = np.random.default_rng(7)
     pool = TenantStatePool(cfg, capacity=2, engine=engine)
-    s = pool.acquire()
-    st = _rand_state(cfg, rng)
+    s, other = pool.acquire(), pool.acquire()
+    st, st_other = _rand_state(cfg, rng), _rand_state(cfg, rng)
     pool.put(s, st)
+    pool.put(other, st_other)
     dagg = rng.random((4, cfg.sw, N_FEATS)).astype(np.float32)
     dhist = rng.random((4, cfg.sw, cfg.n_hist_buckets)).astype(np.float32)
     pool.scatter_fold([s, s, s], dagg, dhist)  # lane 3 = dead pad
@@ -106,14 +129,16 @@ def test_pool_scatter_duplicate_slots_fold_in_lane_order(engine):
     for i in range(3):
         want = fold_delta(want, dagg[i], dhist[i])
     _assert_state_bytes(pool.gather(s), want)
+    _assert_state_bytes(pool.gather(other), st_other)
 
 
-@pytest.mark.parametrize("engine", ENGINES)
-def test_pool_roll_bit_identical_to_host_roll(engine):
+@BOTH
+@ALL_SHAPES
+def test_pool_roll_bit_identical_to_host_roll(engine, shape):
     """The pool roll (shift plane columns, zero the tail) vs
     roll_ring_state on the same bits, every shift regime: partial,
     full-plane, and past-the-grid."""
-    cfg = _cfg()
+    cfg = _cfg(*SHAPES[shape])
     rng = np.random.default_rng(3)
     for k in (1, 3, cfg.n_windows - 1, cfg.n_windows, 2 * cfg.n_windows):
         pool = TenantStatePool(cfg, capacity=2, engine=engine)
@@ -124,12 +149,14 @@ def test_pool_roll_bit_identical_to_host_roll(engine):
         _assert_state_bytes(pool.gather(s), roll_ring_state(st, cfg, k))
 
 
-@pytest.mark.parametrize("engine", ENGINES)
-def test_pool_slot_exhaustion_growth_and_churn_reuse(engine):
+@BOTH
+@ALL_SHAPES
+def test_pool_slot_exhaustion_growth_and_churn_reuse(engine, shape):
     """Exhaustion grows the pool by doubling WITHOUT disturbing live
     states; release() returns a zeroed slot that the next acquire
-    reuses (tenant churn must never leak a predecessor's bits)."""
-    cfg = _cfg()
+    reuses (tenant churn must never leak a predecessor's bits); a fold
+    after the growth (a new plane shape) is still the host seam's."""
+    cfg = _cfg(*SHAPES[shape])
     rng = np.random.default_rng(0)
     pool = TenantStatePool(cfg, capacity=2, engine=engine)
     s1, s2 = pool.acquire(), pool.acquire()
@@ -149,14 +176,26 @@ def test_pool_slot_exhaustion_growth_and_churn_reuse(engine):
     z = pool.gather(s2b)                       # ...zeroed
     assert not np.asarray(z.agg).any() and not np.asarray(z.hist).any()
     _assert_state_bytes(pool.gather(s1), st1)
+    # a fold over the grown planes: an old row twice (waves), a row the
+    # growth added, a dead pad lane
+    st3 = pool.gather(s3)
+    dagg = rng.random((4, cfg.sw, N_FEATS)).astype(np.float32)
+    dhist = rng.random((4, cfg.sw, cfg.n_hist_buckets)).astype(np.float32)
+    pool.scatter_fold([s1, s3, s1], dagg, dhist)
+    _assert_state_bytes(pool.gather(s3), fold_delta(st3, dagg[1], dhist[1]))
+    _assert_state_bytes(pool.gather(s1), fold_delta(
+        fold_delta(st1, dagg[0], dhist[0]), dagg[2], dhist[2]))
+    assert not any(np.asarray(x).any() for x in pool.gather(s2b))
 
 
-def test_pool_gather_window_matches_plane_column_and_pallas_twin():
+@ALL_SHAPES
+def test_pool_gather_window_matches_plane_column_and_pallas_twin(shape):
     """The batched scorer's fused gather: [T, S, F] columns byte-equal
     to slicing the gathered rows, under the pow2 request padding — and
     the pallas gather kernel (interpret mode on CPU) returns the same
-    bytes as the XLA formulation."""
-    cfg = _cfg()
+    bytes as the XLA formulation.  gather_rows is the same rows' agg
+    half, whole."""
+    cfg = _cfg(*SHAPES[shape])
     pool = TenantStatePool(cfg, capacity=4, engine="numpy")
     jx = TenantStatePool(cfg, capacity=4, engine="jax")
     pal = TenantStatePool(cfg, capacity=4, gather_engine="pallas")
@@ -168,15 +207,39 @@ def test_pool_gather_window_matches_plane_column_and_pallas_twin():
     got = pool.gather_window(slots, cols)
     assert got.shape == (3, cfg.n_services, N_FEATS)
     for j, (s, c) in enumerate(zip(slots, cols)):
-        want = np.asarray(pool.agg[s]).reshape(
+        want = pool.gather(s).agg.reshape(
             cfg.n_services, cfg.n_windows, N_FEATS)[:, c]
         assert got[j].tobytes() == want.tobytes()
     assert jx.gather_window(slots, cols).tobytes() == got.tobytes()
     assert pal.gather_window(slots, cols).tobytes() == got.tobytes()
+    for p in (pool, jx, pal):
+        rows = p.gather_rows(slots)
+        assert rows.shape == (3, cfg.sw, N_FEATS)
+        for j, s in enumerate(slots):
+            assert rows[j].tobytes() == pool.gather(s).agg.tobytes()
     with pytest.raises(ValueError):
         TenantStatePool(cfg, gather_engine="mosaic")
     with pytest.raises(ValueError):
         TenantStatePool(cfg, engine="cuda")
+
+
+def test_pool_gather_window_above_one_chunk_is_the_same_bytes():
+    """A request above the jitted gather's chunk (512 rows a step) is
+    served in steps inside the one dispatch: same bytes as the numpy
+    engine's fancy index, slot 0 and repeated slots included."""
+    cfg = _cfg()
+    rng = np.random.default_rng(11)
+    pools = [TenantStatePool(cfg, capacity=40, engine=e) for e in ENGINES]
+    for s in range(1, 41, 3):
+        st = _rand_state(cfg, rng)
+        for p in pools:
+            p.put(s, st)
+    n = 2 * TenantStatePool._GATHER_CHUNK + 77      # pads to 2,048
+    slots = rng.integers(0, 41, n)
+    cols = rng.integers(0, cfg.n_windows, n)
+    got = [p.gather_window(slots, cols) for p in pools]
+    assert got[0].shape == (n, cfg.n_services, N_FEATS) and got[0].any()
+    assert got[0].tobytes() == got[1].tobytes()
 
 
 # -- the runner's device fold ---------------------------------------------
