@@ -371,6 +371,12 @@ def main(argv=None) -> int:
                               "inference over the tenant's live service "
                               "graph (anomod.serve.rca; default: "
                               "ANOMOD_SERVE_RCA)")
+    p_serve.add_argument("--seq-model", default=None, metavar="CONFIG.json",
+                         help="score every served span as an event token "
+                              "of its tenant's session with the latent-"
+                              "attention routed-expert decoder this "
+                              "configuration file describes "
+                              "(anomod.serve.seqplane; default: off)")
     p_serve.add_argument("--state", choices=["auto", "host", "device"],
                          default=None,
                          help="tenant replay state residency: device = "
@@ -1247,7 +1253,7 @@ def main(argv=None) -> int:
         # on: pure registry reads, decisions byte-identical either way
         from anomod.obs.http import maybe_serve
         _endpoint = maybe_serve()
-        _, report = run_power_law(
+        engine, report = run_power_law(
             n_tenants=args.tenants, n_services=args.services,
             capacity_spans_per_s=args.capacity, overload=args.overload,
             duration_s=args.duration, tick_s=args.tick, seed=args.seed,
@@ -1270,7 +1276,7 @@ def main(argv=None) -> int:
                           else (False if args.no_async_commit
                                 else None)),
             worker=args.worker, fold=args.fold,
-            native_drain=args.native_drain,
+            native_drain=args.native_drain, seq_model=args.seq_model,
             # --no-score forces RCA off even when ANOMOD_SERVE_RCA=1
             # (the explicit CLI ask wins over the env default; the
             # --rca + --no-score combination already parser.error'd)
@@ -1280,7 +1286,11 @@ def main(argv=None) -> int:
         if tracer is not None:
             from pathlib import Path as _P
             tracer.dump(_P(args.trace_out))
-        print(json.dumps(report.to_dict(), indent=2))
+        out = report.to_dict()
+        if engine.seq_counters is not None:
+            out["seq_model"] = dict(engine.seq_counters,
+                                    windows_scored=len(engine.seq_scores))
+        print(json.dumps(out, indent=2))
         return 0
 
     if args.cmd == "perf":

@@ -95,9 +95,13 @@ PLANES: Tuple[str, ...] = ("admission", "dispatch", "fold", "score", "rca")
 #: deferral legitimately moves WHICH tick a tenant's fold/score deltas
 #: land in vs a never-evicted run of the same seed — content conserved,
 #: placement shifted — so the key cannot sit on the canonical surface.
+#: ``seq`` (anomod.serve.seqplane): the sequence-model plane's tick
+#: (tokens, session-policy counts, a digest of the surprisals).  A pure
+#: consumer of the served batches, so the canonical planes are equal with
+#: the plane on or off; the key is variant because the plane is.
 FLIGHT_VARIANT_KEYS: Tuple[str, ...] = ("walls", "topology", "recovery",
                                         "scaling", "perf", "census",
-                                        "tiering")
+                                        "tiering", "seq")
 
 
 def crc_text(text: str, prev: int = 0) -> int:

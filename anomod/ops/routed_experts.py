@@ -1,0 +1,75 @@
+"""Routed experts for a layer that is TOLD which experts it holds.
+
+The router scores every token over all ``n_routed`` experts and picks its
+top-k; the layer computes the part of the result that its own experts
+``[lo, lo + held)`` give and leaves the absent experts' part out (they
+live on other chips; nothing here stands in for them).
+
+Dispatch drops no token whatever the skew: the token-expert pairs are
+sorted by expert, the held ones first, and taken through a grouped matmul
+(``jax.lax.ragged_dot`` over the held experts) in rounds of ``capacity``
+rows; the number of rounds is data (one where routing is even, up to
+``top_k * T / capacity`` where every token lands here).
+"""
+
+from __future__ import annotations
+
+
+def route(x, w_router, bias, top_k: int, scaling: float, norm_topk: bool):
+    """Sigmoid scores in float32, top-k of ``score + bias`` (the bias only
+    chooses), weights from the unbiased scores, normalised and scaled.
+    Returns ``(experts [T, k] int32, weights [T, k] float32)``."""
+    import jax
+    import jax.numpy as jnp
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), w_router,
+                               precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(s + bias, top_k)
+    w = jnp.take_along_axis(s, experts, axis=1)
+    if norm_topk:
+        w = w / (w.sum(axis=1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), w * scaling
+
+
+def held_expert_sum(x, experts, weights, valid, w_gate, w_up, w_down,
+                    lo: int, capacity: int):
+    """``sum_e w_e * expert_e(x)`` over the held experts ``[lo, lo + E)``
+    (``w_gate`` / ``w_up`` ``[E, D, F]``, ``w_down`` ``[E, F, D]``), for
+    the ``valid`` rows of ``x`` ``[T, D]``.  Returns ``(out [T, D]
+    float32, tokens per held expert [E] int32)``."""
+    import jax
+    import jax.numpy as jnp
+    T, k = experts.shape
+    E = w_gate.shape[0]
+    local = experts - lo
+    held = (local >= 0) & (local < E) & valid[:, None]
+    local = jnp.where(held, local, E).reshape(-1)         # E sorts last
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    counts = jnp.zeros((E + 1,), jnp.int32).at[local].add(1)[:E]
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    n_held = ends[-1]
+    order = jnp.concatenate([order, jnp.zeros((capacity,), jnp.int32)])
+    tok_of = jnp.arange(T * k, dtype=jnp.int32) // k
+    w_flat = weights.reshape(-1)
+    lane = jnp.arange(capacity, dtype=jnp.int32)
+
+    def round_body(r, out):
+        base = r * capacity
+        pair = jax.lax.dynamic_slice_in_dim(order, base, capacity)
+        live = base + lane < n_held
+        tok = tok_of[pair]
+        sizes = (jnp.clip(ends - base, 0, capacity)
+                 - jnp.clip(starts - base, 0, capacity))
+        xs = x[tok]
+        dot = lambda a, w: jax.lax.ragged_dot(
+            a, w, sizes, preferred_element_type=jnp.float32)
+        mid = (jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)).astype(x.dtype)
+        # rows past the held pairs are the kernel's to leave undefined
+        y = jnp.where(live[:, None],
+                      dot(mid, w_down) * w_flat[pair][:, None], 0.0)
+        return out.at[jnp.where(live, tok, T)].add(y, mode="drop")
+
+    rounds = (n_held + capacity - 1) // capacity
+    out = jax.lax.fori_loop(0, rounds, round_body,
+                            jnp.zeros((T, x.shape[1]), jnp.float32))
+    return out, counts

@@ -476,7 +476,8 @@ def run_power_law(n_tenants: int = 200, n_services: int = 8,
                   tier_prefetch: Optional[int] = None,
                   worker: Optional[str] = None,
                   fold: Optional[str] = None,
-                  served_log: Optional[list] = None
+                  served_log: Optional[list] = None,
+                  seq_model=None
                   ) -> Tuple["ServeEngine", ServeReport]:
     """The canonical seeded serve run shared by ``anomod serve`` and
     ``bench.py --mode serve``: a power-law tenant fleet offering
@@ -526,7 +527,7 @@ def run_power_law(n_tenants: int = 200, n_services: int = 8,
                          tier_warm_bytes=tier_warm_bytes,
                          tier_cold_dir=tier_cold_dir,
                          tier_prefetch=tier_prefetch,
-                         worker=worker, fold=fold)
+                         worker=worker, fold=fold, seq_model=seq_model)
     if engine.flight_recorder is not None:
         # the header's replay contract: `anomod audit replay` re-executes
         # this exact invocation from the journal alone.  Every
@@ -674,7 +675,8 @@ class ServeEngine:
                  tier_cold_dir=None,
                  tier_prefetch: Optional[int] = None,
                  worker: Optional[str] = None,
-                 fold: Optional[str] = None):
+                 fold: Optional[str] = None,
+                 seq_model=None):
         from anomod.config import get_config
         if capacity_spans_per_s <= 0:
             raise ValueError("capacity must be positive")
@@ -1204,6 +1206,26 @@ class ServeEngine:
         # metric handles only when the plane is live: an RCA-off run
         # must not register permanently-zero RCA series in the scrape
         # journal / exports
+        #: the sequence-model plane (anomod.serve.seqplane): every served
+        #: span an event token of its tenant's session, scored by a
+        #: latent-attention routed-expert decoder; a pure consumer of the
+        #: served batches beside the RCA step.  ``seq_model`` is the
+        #: configuration file's object or its path (None: no plane).
+        self._seq = None
+        if seq_model is not None:
+            for on, what in ((self._tier is not None, "state tiering"),
+                             (_worker == "process", "process workers"),
+                             (mesh is not None, "a mesh"),
+                             (self._async, "the deferred-commit tick")):
+                if on:
+                    raise ValueError("the sequence-model plane holds its "
+                                     "sessions on this process's one chip "
+                                     f"and does not run with {what}")
+            from anomod.serve.seqplane import SeqPlane
+            self._seq = SeqPlane(
+                seq_model, [sp.tenant_id for sp in self.specs],
+                self.cfg.n_services, self.cfg.n_hist_buckets,
+                self.cfg.window_us, self.t0_us, tracer=tracer)
         self._rca_slo = None
         if self.rca:
             self._rca_slo = _TenantSLO("anomod_serve_rca_seconds")
@@ -1734,6 +1756,8 @@ class ServeEngine:
             self._slo_record(now, served)
         if self.rca:
             self._rca_step(now, served)
+        if self._seq is not None:
+            self._seq.step(served)
         with self._span("serve.recorders"):
             # the perf-timeline drain rides INSIDE the measured wall (the
             # bench perf block prices the recorder, never hides it); it
@@ -2589,6 +2613,14 @@ class ServeEngine:
         # every-record-carries-every-tier contract.
         rec["tiering"] = (self._tier.drain_events()
                           if self._tier is not None else [])
+        # the sequence-model plane's tick rides the VARIANT tier (the
+        # "seq" key): tokens, session-policy counts and a digest of the
+        # surprisals.  ALWAYS present (empty with the plane off or on a
+        # record that follows no step of its own)
+        doc = None
+        if self._seq is not None:
+            doc, self._seq.tick_doc = self._seq.tick_doc, None
+        rec["seq"] = doc if doc is not None else {"tokens": 0}
         if final:
             rec["final"] = True
         fr.record(rec)
@@ -2703,6 +2735,8 @@ class ServeEngine:
                 r.abort_lanes()
         if self._tier is not None:
             self._tier.close()         # join/park the prefetch lane
+        if self._seq is not None:
+            self._seq.close()          # the latent pool and the weights
         if self._workers is not None:
             errs = []
             for w in self._workers:
@@ -3495,6 +3529,8 @@ class ServeEngine:
                     self.runner.warm_lanes()
                 if self.rca:
                     self._rca_planes[0].runner.warm()
+            if self._seq is not None:
+                self._seq.warm()
         n_ticks = max(int(round(duration_s / self.clock.tick_s)), 1)
         mod_src = getattr(traffic, "modality_arrivals", None) \
             if self.multimodal else None
@@ -3659,6 +3695,19 @@ class ServeEngine:
             self._rca_planes[shard_id].runner.warm()
 
     # -- reporting --------------------------------------------------------
+
+    @property
+    def seq_scores(self):
+        """The sequence-model plane's closed windows, the newest last:
+        ``(tenant, window, spans, mean surprisal, max surprisal)``; None
+        where the engine has no such plane."""
+        return None if self._seq is None else self._seq.scores
+
+    @property
+    def seq_counters(self):
+        """The sequence-model plane's counters
+        (``anomod.serve.seqplane.COUNTERS``), or None."""
+        return None if self._seq is None else self._seq.counters
 
     def alerts_for(self, tenant_id: int,
                    onset_window: Optional[int] = None):

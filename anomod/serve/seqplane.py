@@ -1,0 +1,437 @@
+"""The sequence-model plane of the serve tick: every served span is one
+event token of its tenant's session, scored by a latent-attention,
+routed-expert decoder (:mod:`anomod.models.latent_moe`) as its surprisal
+``-log p(token | the tenant's session so far)``.
+
+A plane beside ``_rca_step``: it reads the tick's served batches and
+writes nothing the sketch planes read, so states, alerts and shed
+decisions are byte-identical with the plane on or off.
+
+- **Tokeniser**: ``id = ((service * H + latency bucket) * 4 + status
+  class) * 3 + kind``, the latency bucket that of the sketch's
+  ``H``-bucket histogram (``int(log1p(duration_us))``, clipped).
+- **State**: a block-paged latent pool on the device (``[layers, blocks,
+  block, latent + rope]`` with the row held at a multiple of 128 columns,
+  allocated once; block 0 is never allocated and takes the pads'
+  writes), each tenant's last hidden state, and the per-tenant session
+  table on the host (:class:`SessionTable`).  A session
+  that reaches ``context_tokens`` restarts empty; when a step's blocks
+  are not free the least recently appended sessions are ended before it
+  is placed (ties by tenant id).  The policy is a pure function of the
+  served log.
+- **Step**: one forward a tick over the packed appended chunks of every
+  tenant served in it, padded to a fixed grid of token counts compiled
+  in :meth:`SeqPlane.warm`; pool and hidden states are donated and
+  updated in place; the tick does not go on until the scores are on the
+  host.  More tokens than the grid's largest size take further steps.
+
+Spans: ``serve.seq_stage`` (tokenise, session and block tables),
+``serve.seq_model`` (issue to scores on the host), ``serve.seq_score``
+(window roll-up).  Counters: :data:`COUNTERS`.
+"""
+
+from __future__ import annotations
+
+import collections
+import json
+import zlib
+
+import numpy as np
+
+from anomod.models import latent_moe as lm
+from anomod.ops import latent_attention as la
+from anomod.utils.tracing import span_of
+
+COUNTERS = ("seq_tokens", "seq_pairs", "seq_absorbed_tokens",
+            "seq_absorbed_pairs", "seq_expanded_keys", "seq_keys",
+            "seq_pad_tokens", "seq_steps", "expert_tokens_max",
+            "expert_tokens_mean", "sessions_rolled", "sessions_evicted",
+            "pool_blocks_held")
+N_STATUS, N_KIND = 4, 3
+#: logits rows kept for each audit tenant, the newest
+AUDIT_KEEP = 32
+
+
+def vocab_needed(n_services: int, n_hist: int) -> int:
+    return n_services * n_hist * N_STATUS * N_KIND
+
+
+def tokenise(service, duration_us, status, kind, n_hist: int) -> np.ndarray:
+    """Event-token ids of spans (columns of a ``SpanBatch``)."""
+    bucket = np.clip(np.log1p(np.asarray(duration_us, np.float32))
+                     .astype(np.int32), 0, n_hist - 1)
+    status = np.asarray(status, np.int32)
+    cls = np.where(status >= 500, 2, np.where(
+        status >= 400, 1, np.where(status >= 200, 0, 3)))
+    k = np.clip(np.asarray(kind, np.int32), 0, N_KIND - 1)
+    return (((np.asarray(service, np.int32) * n_hist + bucket) * N_STATUS
+             + cls) * N_KIND + k).astype(np.int32)
+
+
+class Session:
+    __slots__ = ("blocks", "length", "number")
+
+    def __init__(self, number: int):
+        self.blocks, self.length, self.number = [], 0, number
+
+
+class SessionTable:
+    """Sessions, their blocks and the bounded-memory policy.  ``append``
+    takes one step's ``(tenant, n_tokens)`` chunks in ascending tenant
+    order and returns its segments ``(tenant, session number, start
+    position, n, blocks)``; blocks of a session that rolled inside the
+    step are freed when the step has been planned (the step still reads
+    them)."""
+
+    def __init__(self, n_blocks: int, context_tokens: int,
+                 block_tokens: int):
+        self.context, self.block = int(context_tokens), int(block_tokens)
+        self.free = collections.deque(range(1, int(n_blocks)))
+        self.usable = len(self.free)
+        self.sessions = collections.OrderedDict()   # tenant -> Session, LRU
+        self.started = {}                           # tenant -> sessions begun
+        self.rolled = self.evicted = 0
+
+    @property
+    def blocks_held(self) -> int:
+        return self.usable - len(self.free)
+
+    def _begin(self, tenant: int) -> Session:
+        n = self.started.get(tenant, 0)
+        self.started[tenant] = n + 1
+        s = self.sessions[tenant] = Session(n)
+        return s
+
+    def _needed(self, tenant: int, n: int) -> int:
+        """Blocks ``n`` more tokens of ``tenant`` take now (a session
+        that rolls inside the step keeps its blocks until its end)."""
+        s = self.sessions.get(tenant)
+        length, held = (s.length, len(s.blocks)) if s else (0, 0)
+        need = 0
+        while n > 0:
+            take = min(n, self.context - length)
+            need += -(-(length + take) // self.block) - held
+            n -= take
+            length, held = (length + take, held + need) \
+                if length + take < self.context else (0, 0)
+        return need
+
+    def append(self, chunks: list) -> list:
+        for t, _ in chunks:
+            if t in self.sessions:
+                self.sessions.move_to_end(t)
+        # room first: end the least recently appended sessions (this
+        # step's own count as appended now, in tenant order) until the
+        # step's blocks are free; nothing ends while it is placed
+        need = {t: self._needed(t, n) for t, n in chunks}
+        short = sum(need.values()) - len(self.free)
+        sizes = dict(chunks)
+        while short > 0:
+            if not self.sessions:
+                raise RuntimeError(
+                    "the latent pool cannot hold one step's tokens")
+            victim, s = next(iter(self.sessions.items()))
+            del self.sessions[victim]
+            self.free.extend(s.blocks)
+            self.evicted += 1
+            short -= len(s.blocks)
+            if victim in need:
+                fresh = self._needed(victim, sizes[victim])
+                short += fresh - need[victim]
+                need[victim] = fresh
+        segments, ended = [], []
+        for tenant, n in chunks:
+            while n > 0:
+                s = self.sessions.get(tenant) or self._begin(tenant)
+                take = min(n, self.context - s.length)
+                for _ in range(-(-(s.length + take) // self.block)
+                               - len(s.blocks)):
+                    s.blocks.append(self.free.popleft())
+                segments.append((tenant, s.number, s.length, take, s.blocks))
+                s.length += take
+                n -= take
+                if s.length == self.context:
+                    ended.append(self.sessions.pop(tenant).blocks)
+                    self.rolled += 1
+        for blocks in ended:
+            self.free.extend(blocks)
+        for t, _ in chunks:             # same-step sessions: by tenant id
+            if t in self.sessions:
+                self.sessions.move_to_end(t)
+        return segments
+
+
+def build_plan(cfg, caps: dict, segments: list, tokens: np.ndarray,
+               tenant_ids: np.ndarray, audit: frozenset) -> tuple:
+    """A step's plan (``latent_moe.empty_plan`` filled) for ``segments``
+    whose tokens are packed in order in ``tokens`` (``tenant_ids``: the
+    sorted ids whose ranks are the rows of ``h_last``).  Returns ``(plan,
+    stats, audit_rows)``: ``stats`` holds the step's share of the work
+    counters (tokens, visible (new, cached) pairs, those and the tokens of
+    absorbed chunks, the keys expanded chunks materialise, the keys read);
+    ``audit_rows`` names the segment behind each filled row of
+    ``plan["audit"]``."""
+    plan = lm.empty_plan(cfg, caps, len(tenant_ids))
+    S, n_tok = len(segments), len(tokens)
+    tenant, number, start, n, blocks = zip(*segments)
+    tenant, start, n = (np.asarray(a, np.int64) for a in (tenant, start, n))
+    off = np.concatenate([[0], np.cumsum(n)[:-1]])
+    row = np.searchsorted(tenant_ids, tenant)
+    for s, b in enumerate(blocks):
+        plan["seg_blocks"][s, :len(b)] = b
+    seg = np.repeat(np.arange(S), n)
+    pos = start[seg] + np.arange(n_tok) - off[seg]
+    first = np.arange(n_tok) == off[seg]
+    plan["tok_id"][:n_tok] = tokens
+    plan["tok_pos"][:n_tok] = pos
+    plan["tok_seg"][:n_tok] = seg
+    plan["tok_slot"][:n_tok] = plan["seg_blocks"][
+        seg, pos // cfg.block_tokens] * cfg.block_tokens \
+        + pos % cfg.block_tokens
+    plan["tok_ctx"][:n_tok] = np.where(
+        first, np.where(pos > 0, caps["tokens"] + row[seg], -1),
+        np.arange(n_tok) - 1)
+    # the last segment of a tenant in this step leaves its hidden state
+    last = np.ones(S, bool)
+    last[:-1] = tenant[1:] != tenant[:-1]
+    plan["last_src"][:S] = np.where(last, off + n - 1, 0)
+    plan["last_row"][:S] = np.where(last, row, len(tenant_ids))
+    # the form of each segment, from sizes alone; the longest first into
+    # the pair list, whatever it cannot hold stays absorbed
+    total = start + n
+    dims = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+            cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank)
+    expanded = np.zeros(S, bool)
+    pairs = []
+    wants = np.nonzero(~la.absorbed_is_cheaper(n, total, *dims,
+                                               block=cfg.block_tokens))[0]
+    for s in wants[np.argsort(-n[wants], kind="stable")]:
+        runs = range(0, -(-int(total[s]) // cfg.block_tokens), la.KV_BLOCKS)
+        if len(pairs) + len(runs) > caps["pairs"]:
+            break
+        expanded[s] = True
+        tiles = -(-int(n[s]) // la.Q_TILE)
+        pairs += [(s, off[s], tiles, blk0) for blk0 in runs]
+    plan["tok_expanded"][:n_tok] = expanded[seg]
+    if pairs:
+        p = plan["pairs"]
+        p["seg"][:len(pairs)], p["q0"][:len(pairs)], \
+            p["n_tiles"][:len(pairs)], p["blk0"][:len(pairs)] = zip(*pairs)
+        p["n_pairs"] = np.int32(len(pairs))
+    a_seg = np.nonzero(~expanded)[0]
+    if len(a_seg):
+        per = -(-n[a_seg] // la.GROUP)
+        g_seg = np.repeat(a_seg, per)
+        g_i = np.arange(per.sum()) - np.repeat(
+            np.concatenate([[0], np.cumsum(per)[:-1]]), per)
+        g_tok0 = off[g_seg] + g_i * la.GROUP
+        g_ntok = np.minimum(la.GROUP, n[g_seg] - g_i * la.GROUP)
+        g_nblk = -(-(start[g_seg] + g_i * la.GROUP + g_ntok)
+                   // cfg.block_tokens)
+        order = np.argsort(-g_nblk, kind="stable")
+        g = plan["groups"]
+        G = len(order)
+        g["tok0"][:G], g["ntok"][:G] = g_tok0[order], g_ntok[order]
+        g["seg"][:G], g["nblk"][:G] = g_seg[order], g_nblk[order]
+        g["n_batches"] = np.int32(-(-G // la.BATCH))
+    rows = [s for s in range(S) if last[s] and int(tenant[s]) in audit][
+        :caps["audit"]]
+    for i, s in enumerate(rows):
+        plan["audit"][i] = off[s] + n[s] - 1
+    audit_rows = [(int(tenant[s]), number[s], int(total[s]) - 1)
+                  for s in rows]
+    pairs_of = n * start + n * (n + 1) // 2
+    return plan, {"seq_tokens": n_tok, "seq_pairs": int(pairs_of.sum()),
+                  "seq_absorbed_tokens": int(n[~expanded].sum()),
+                  "seq_absorbed_pairs": int(pairs_of[~expanded].sum()),
+                  "seq_expanded_keys": int(total[expanded].sum()),
+                  "seq_keys": int(total.sum())}, audit_rows
+
+
+class SeqPlane:
+    """The plane.  ``spec``: the configuration file's object (or its
+    path); beside the public keys and ``assumed`` (which names the
+    ``token_grid``) it may carry ``weights_seed`` and ``audit_tenants``."""
+
+    def __init__(self, spec, tenant_ids, n_services: int, n_hist: int,
+                 window_us: int, t0_us: int = 0, tracer=None):
+        import jax.numpy as jnp
+        from anomod.replay import named_jit
+        if isinstance(spec, str):
+            with open(spec) as f:
+                spec = json.load(f)
+        self.cfg = cfg = lm.DecoderConfig.from_dict(spec)
+        if vocab_needed(n_services, n_hist) > cfg.vocab_held:
+            raise ValueError(
+                f"{n_services} services x {n_hist} buckets need "
+                f"{vocab_needed(n_services, n_hist)} event ids, the "
+                f"vocabulary slice holds {cfg.vocab_held}")
+        grid = spec.get("assumed", {}).get("token_grid")
+        if not grid:
+            raise ValueError("the configuration's `assumed` names no "
+                             "`token_grid` (the packed-token sizes to "
+                             "compile ahead)")
+        self.grid = tuple(sorted(int(t) for t in grid))
+        self.audit = frozenset(int(t) for t in spec.get("audit_tenants", ()))
+        self.tenant_ids = np.asarray(sorted(int(t) for t in tenant_ids))
+        self.n_tenants, self.n_hist = len(self.tenant_ids), int(n_hist)
+        self.window_us, self.t0_us = int(window_us), int(t0_us)
+        self.tracer = tracer
+        self.params = lm.init_params(cfg, int(spec.get("weights_seed", 0)))
+        self.pool = jnp.zeros((cfg.num_hidden_layers, cfg.pool_blocks,
+                               cfg.block_tokens, cfg.pool_row_width),
+                              jnp.bfloat16)
+        self.h_last = jnp.zeros((self.n_tenants + 1, cfg.hidden_size),
+                                jnp.bfloat16)
+        self.table = SessionTable(cfg.pool_blocks, cfg.context_tokens,
+                                  cfg.block_tokens)
+        self._step = named_jit(
+            "anomod_seq_step",
+            lambda params, pool, h_last, plan: lm.append_step(
+                cfg, params, pool, h_last, plan),
+            donate_argnums=(1, 2))
+        self.counters = dict.fromkeys(COUNTERS, 0)
+        #: (tenant, window, spans, mean surprisal, max surprisal) of
+        #: closed windows, the newest last (bounded)
+        self.scores = collections.deque(maxlen=1 << 16)
+        self._open = {}                  # tenant -> [window, n, sum, max]
+        #: audit tenants only, the newest last (bounded): per segment
+        #: (tenant, session, start, tokens, surprisals), and logits rows
+        #: (tenant, session, position, row), at most 64 rows a step
+        self.audit_segments = collections.deque(maxlen=1 << 16)
+        self.audit_logits = collections.deque(
+            maxlen=AUDIT_KEEP * max(len(self.audit), 1))
+        self.tick_doc = None
+
+    def caps(self, tokens: int) -> dict:
+        return lm.plan_caps(self.cfg, tokens, 2 * self.n_tenants + 64)
+
+    def warm(self) -> None:
+        """Compile every grid size (a step of pads each)."""
+        for t in self.grid:
+            self._run(lm.empty_plan(self.cfg, self.caps(t), self.n_tenants))
+
+    def _run(self, plan: dict):
+        import jax
+        self.pool, self.h_last, surprisal, audit, counts = self._step(
+            self.params, self.pool, self.h_last, jax.device_put(plan))
+        return np.asarray(surprisal), audit, np.asarray(counts)
+
+    def close(self) -> None:
+        """Free the device state (the pool first)."""
+        self.pool = self.h_last = self.params = None
+
+    # -- one tick ---------------------------------------------------------
+
+    def step(self, served: list) -> None:
+        """Score the tick's served batches."""
+        if not sum(qb.n_spans for qb in served):
+            self.tick_doc = {"tokens": 0}
+            return
+        c = self.counters
+        with span_of(self.tracer, "serve.seq_stage", batches=len(served)):
+            order = sorted(range(len(served)),
+                           key=lambda i: served[i].tenant_id)
+            cols = [served[i].spans for i in order]
+            cat = lambda k: np.concatenate([getattr(b, k) for b in cols])
+            tokens = tokenise(cat("service"), cat("duration_us"),
+                              cat("status"), cat("kind"), self.n_hist)
+            starts = cat("start_us")
+            tenants = np.repeat([served[i].tenant_id for i in order],
+                                [served[i].n_spans for i in order])
+            ids, counts = np.unique(tenants, return_counts=True)
+            segments = self.table.append(list(zip(ids.tolist(),
+                                                  counts.tolist())))
+            steps = self._plan_steps(segments, tokens)
+        with span_of(self.tracer, "serve.seq_model", steps=len(steps)):
+            surprisal = []
+            for plan, stats, audit_rows, pad in steps:
+                got, audit, ecounts = self._run(plan)
+                surprisal.append(got[:stats["seq_tokens"]])
+                if audit_rows:
+                    rows = np.asarray(audit)
+                    self.audit_logits.extend(
+                        (t, s, p, rows[i])
+                        for i, (t, s, p) in enumerate(audit_rows))
+                for k, v in stats.items():
+                    c[k] += v
+                c["seq_pad_tokens"] += pad
+                c["seq_steps"] += 1
+                if ecounts.size:
+                    c["expert_tokens_max"] += int(ecounts.max(axis=1).sum())
+                    c["expert_tokens_mean"] += float(
+                        ecounts.mean(axis=1).sum())
+            surprisal = np.concatenate(surprisal)
+        with span_of(self.tracer, "serve.seq_score"):
+            closed = self._roll_up(tenants, starts, surprisal)
+            if self.audit:
+                at = 0
+                for tenant, number, start, n, _ in segments:
+                    if tenant in self.audit:
+                        self.audit_segments.append(
+                            (tenant, number, start, tokens[at:at + n],
+                             surprisal[at:at + n]))
+                    at += n
+            c["sessions_rolled"] = self.table.rolled
+            c["sessions_evicted"] = self.table.evicted
+            c["pool_blocks_held"] = self.table.blocks_held
+            self.tick_doc = {
+                "tokens": int(len(tokens)), "steps": len(steps),
+                "windows_closed": closed,
+                "sessions_rolled": self.table.rolled,
+                "sessions_evicted": self.table.evicted,
+                "blocks_held": self.table.blocks_held,
+                "digest": zlib.crc32(surprisal.tobytes())}
+
+    def _plan_steps(self, segments: list, tokens: np.ndarray) -> list:
+        """The tick's segments cut into steps of at most the grid's
+        largest size (a cut segment goes on in the next step) and at most
+        the plan's segment rows."""
+        biggest = self.grid[-1]
+        seg_cap = self.caps(biggest)["segments"]
+        steps, cur, room, at = [], [], biggest, 0
+        queue = collections.deque(segments)
+        while queue:
+            tenant, number, start, n, blocks = queue.popleft()
+            take = min(n, room)
+            cur.append((tenant, number, start, take, blocks))
+            room -= take
+            if take < n:
+                queue.appendleft((tenant, number, start + take, n - take,
+                                  blocks))
+            if not room or len(cur) == seg_cap or not queue:
+                used = biggest - room
+                size = next(t for t in self.grid if t >= used)
+                steps.append(build_plan(
+                    self.cfg, self.caps(size), cur, tokens[at:at + used],
+                    self.tenant_ids, self.audit) + (size - used,))
+                at += used
+                cur, room = [], biggest
+        return steps
+
+    def _roll_up(self, tenants, starts, surprisal) -> int:
+        """Per tenant and sketch window: span count, mean and max
+        surprisal; a window closes when a later one's span is scored."""
+        window = (starts - self.t0_us) // self.window_us
+        key = tenants.astype(np.int64) << 32 | window.astype(np.int64)
+        uniq, inv = np.unique(key, return_inverse=True)
+        n = np.bincount(inv)
+        total = np.bincount(inv, weights=surprisal)
+        most = np.full(len(uniq), -np.inf)
+        np.maximum.at(most, inv, surprisal)
+        closed = 0
+        for k, cnt, tot, mx in zip(uniq.tolist(), n.tolist(),
+                                   total.tolist(), most.tolist()):
+            t, w = k >> 32, k & 0xFFFFFFFF
+            cur = self._open.get(t)
+            if cur is not None and cur[0] == w:
+                cur[1] += cnt
+                cur[2] += tot
+                cur[3] = max(cur[3], mx)
+                continue
+            if cur is not None:
+                self.scores.append((t, cur[0], cur[1], cur[2] / cur[1],
+                                    cur[3]))
+                closed += 1
+            self._open[t] = [w, cnt, tot, mx]
+        return closed

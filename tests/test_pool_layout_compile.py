@@ -120,3 +120,58 @@ def test_pool_op_compiles_with_no_whole_plane_op(one_chip, optimizing,
     assert mem.temp_size_in_bytes < 4 * agg.shape[1] * 1024
     assert mem.alias_size_in_bytes >= 4 * FLEET_ROWS * (
         agg.shape[1] + hist.shape[1])
+
+
+# -- the sequence model's latent pool (PR 28) ---------------------------------
+
+def test_seq_step_writes_the_latent_pool_in_place(one_chip, optimizing):
+    """The serving step of ``k2-fleet-overload`` at its published widths
+    and its real pool, compiled for the chip: the donated pool comes back
+    in its own buffer, the program's temporaries stay far under the
+    pool's size (no pool-shaped copy: a ``[.., 128, 576]`` row would be
+    laid out token-minor and copied whole around every write; the row is
+    held at 640 columns), and everything fits the chip beside the
+    weights."""
+    import json
+    import os
+
+    import numpy as np
+
+    from anomod.models import latent_moe as lm
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-k2-ep32-share.json")) as f:
+        spec = json.load(f)
+    cfg = lm.DecoderConfig.from_dict(spec)
+    assert cfg.pool_row_width == 640 and cfg.pool_blocks == 3484
+
+    def sds(shape, dtype):
+        return SDS(tuple(shape), dtype, sharding=one_chip)
+
+    flat = {}
+    for name, leaf in lm.param_shapes(cfg).items():
+        flat[name] = (
+            {k: sds(s, jnp.float32 if k in lm.F32_LEAVES else jnp.bfloat16)
+             for k, (s, _) in leaf.items()} if isinstance(leaf, dict)
+            else sds(leaf[0], jnp.float32 if name in lm.F32_LEAVES
+                     else jnp.bfloat16))
+    tokens = min(spec["assumed"]["token_grid"])
+    caps = lm.plan_caps(cfg, tokens, 2 * spec["fleet"]["n_tenants"] + 64)
+    plan = jax.tree_util.tree_map(
+        lambda a: sds(np.shape(a), jnp.int32), lm.empty_plan(cfg, caps, 0))
+    pool = sds((cfg.num_hidden_layers, cfg.pool_blocks, cfg.block_tokens,
+                cfg.pool_row_width), jnp.bfloat16)
+    h_last = sds((spec["fleet"]["n_tenants"] + 1, cfg.hidden_size),
+                 jnp.bfloat16)
+    step = jax.jit(lambda p, pool, h, plan: lm.append_step(
+        cfg, p, pool, h, plan), donate_argnums=(1, 2))
+    compiled = step.lower(flat, pool, h_last, plan).compile()
+    mem = compiled.memory_analysis()
+    # the attention kernels' device ops carry their call's name in the
+    # metadata a trace keeps (what `mla_append_roofline` finds them by)
+    assert f'/{lm.ATTENTION_SCOPE}/while/body/' in compiled.as_text()
+    pool_bytes = 2 * int(np.prod(pool.shape))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 2
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
