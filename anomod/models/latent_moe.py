@@ -325,7 +325,7 @@ def empty_plan(cfg: DecoderConfig, caps: dict, trash_row: int) -> dict:
         "seg_blocks": np.zeros((S + 1, cfg.session_blocks), np.int32),
         "last_src": z(S), "last_row": np.full((S,), trash_row, np.int32),
         "groups": {"tok0": z(G), "ntok": z(G), "seg": z(G), "nblk": z(G),
-                   "n_batches": np.int32(0)},
+                   "n_groups": np.int32(0)},
         "pairs": {"seg": z(P), "q0": z(P), "n_tiles": z(P), "blk0": z(P),
                   "n_pairs": np.int32(0)},
         "audit": z(caps["audit"]),
@@ -370,14 +370,16 @@ def attention_block(cfg: DecoderConfig, lp: dict, h, plan: dict, pool,
     scale = softmax_scale(cfg)
     def forms(q_nope, q_pe, pos, seg, expanded, pool, blocks, groups, pairs,
               w_kvb):
-        q_lat = dot(q_nope, w_kvb[..., :nope], "thn,chn->thc").astype(
-            h.dtype)
+        # the absorbed form's query at the pool's row width, in its two
+        # parts: through W_kvb's key half, and the rope part zero-padded
+        q_lat = dot(_pad_rows(q_nope, la.GROUP), w_kvb[..., :nope],
+                    "thn,chn->thc").astype(h.dtype)
+        q_rope = jnp.pad(q_pe, ((0, la.GROUP), (0, 0),
+                                (0, cfg.pool_row_width - cfg.latent_width)))
         o_abs = la.absorbed_attention(
-            _pad_rows(jnp.concatenate([q_lat, q_pe, jnp.zeros(
-                q_pe.shape[:2] + (cfg.pool_row_width - cfg.latent_width,),
-                h.dtype)], axis=-1), la.GROUP),
-            _pad_rows(pos, la.GROUP), pool, blocks, groups,
-            w_kvb[..., nope:], scale, R, cfg.block_tokens)[:T]
+            q_lat, q_rope, _pad_rows(pos, la.GROUP),
+            _pad_rows((seg >= 0) & (expanded == 0), la.GROUP), pool, blocks,
+            groups, w_kvb[..., nope:], scale, cfg.block_tokens)[:T]
         o_exp = la.expanded_attention(
             _pad_rows(q_nope, la.Q_TILE), _pad_rows(q_pe, la.Q_TILE),
             _pad_rows(pos, la.Q_TILE),

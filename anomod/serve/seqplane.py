@@ -20,10 +20,12 @@ decisions are byte-identical with the plane on or off.
   is placed (ties by tenant id).  The policy is a pure function of the
   served log.
 - **Step**: one forward a tick over the packed appended chunks of every
-  tenant served in it, padded to a fixed grid of token counts compiled
-  in :meth:`SeqPlane.warm`; pool and hidden states are donated and
-  updated in place; the tick does not go on until the scores are on the
-  host.  More tokens than the grid's largest size take further steps.
+  tenant served in it (short chunks through the absorbed attention
+  kernel, a grid step a group of ``GROUP`` tokens), padded to a fixed
+  grid of token counts compiled in :meth:`SeqPlane.warm`; pool and
+  hidden states are donated and updated in place; the tick does not go
+  on until the scores are on the host.  More tokens than the grid's
+  largest size take further steps.
 
 Spans: ``serve.seq_stage`` (tokenise, session and block tables),
 ``serve.seq_model`` (issue to scores on the host), ``serve.seq_score``
@@ -43,10 +45,10 @@ from anomod.ops import latent_attention as la
 from anomod.utils.tracing import span_of
 
 COUNTERS = ("seq_tokens", "seq_pairs", "seq_absorbed_tokens",
-            "seq_absorbed_pairs", "seq_expanded_keys", "seq_keys",
-            "seq_pad_tokens", "seq_steps", "expert_tokens_max",
-            "expert_tokens_mean", "sessions_rolled", "sessions_evicted",
-            "pool_blocks_held")
+            "seq_absorbed_pairs", "seq_absorbed_group_blocks",
+            "seq_expanded_keys", "seq_keys", "seq_pad_tokens", "seq_steps",
+            "expert_tokens_max", "expert_tokens_mean", "sessions_rolled",
+            "sessions_evicted", "pool_blocks_held")
 N_STATUS, N_KIND = 4, 3
 #: logits rows kept for each audit tenant, the newest
 AUDIT_KEEP = 32
@@ -168,7 +170,8 @@ def build_plan(cfg, caps: dict, segments: list, tokens: np.ndarray,
     sorted ids whose ranks are the rows of ``h_last``).  Returns ``(plan,
     stats, audit_rows)``: ``stats`` holds the step's share of the work
     counters (tokens, visible (new, cached) pairs, those and the tokens of
-    absorbed chunks, the keys expanded chunks materialise, the keys read);
+    absorbed chunks, the cached blocks their groups walk, the keys expanded
+    chunks materialise, the keys read);
     ``audit_rows`` names the segment behind each filled row of
     ``plan["audit"]``."""
     plan = lm.empty_plan(cfg, caps, len(tenant_ids))
@@ -233,7 +236,7 @@ def build_plan(cfg, caps: dict, segments: list, tokens: np.ndarray,
         G = len(order)
         g["tok0"][:G], g["ntok"][:G] = g_tok0[order], g_ntok[order]
         g["seg"][:G], g["nblk"][:G] = g_seg[order], g_nblk[order]
-        g["n_batches"] = np.int32(-(-G // la.BATCH))
+        g["n_groups"] = np.int32(G)
     rows = [s for s in range(S) if last[s] and int(tenant[s]) in audit][
         :caps["audit"]]
     for i, s in enumerate(rows):
@@ -244,6 +247,8 @@ def build_plan(cfg, caps: dict, segments: list, tokens: np.ndarray,
     return plan, {"seq_tokens": n_tok, "seq_pairs": int(pairs_of.sum()),
                   "seq_absorbed_tokens": int(n[~expanded].sum()),
                   "seq_absorbed_pairs": int(pairs_of[~expanded].sum()),
+                  "seq_absorbed_group_blocks":
+                      int(plan["groups"]["nblk"].sum()),
                   "seq_expanded_keys": int(total[expanded].sum()),
                   "seq_keys": int(total.sum())}, audit_rows
 
@@ -377,6 +382,8 @@ class SeqPlane:
             c["pool_blocks_held"] = self.table.blocks_held
             self.tick_doc = {
                 "tokens": int(len(tokens)), "steps": len(steps),
+                "absorbed_group_blocks": sum(
+                    step[1]["seq_absorbed_group_blocks"] for step in steps),
                 "windows_closed": closed,
                 "sessions_rolled": self.table.rolled,
                 "sessions_evicted": self.table.evicted,

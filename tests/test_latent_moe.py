@@ -111,13 +111,20 @@ def force_form(monkeypatch):
     return force
 
 
-@pytest.mark.parametrize("form", ["absorbed", "expanded"])
-def test_each_attention_form_equals_the_reference(model, force_form, form):
+@pytest.mark.parametrize("form, steps", [
+    ("absorbed", (5, 1, 20, 14)),
+    ("expanded", (5, 1, 20, 14)),
+    # the kernel's groups: one live row of GROUP (9 = 8 + 1), a last block
+    # partly filled (block 8: 9 + 17 + 3 = 29), one token against it
+    ("absorbed", (9, 17, 3, 1)),
+])
+def test_each_attention_form_equals_the_reference(model, force_form, form,
+                                                  steps):
     _, cfg, params = model
     force_form(form)
     run = Stepper(cfg, params)
     rng = np.random.default_rng(0)
-    for n in (5, 1, 20, 14):
+    for n in steps:
         run.append([(1, n)], rng)
     assert (run.expanded_tokens == run.tokens) == (form == "expanded")
     assert run.worst_gap() < 2e-5
@@ -128,14 +135,24 @@ def test_each_attention_form_equals_the_reference(model, force_form, form):
     ("session_roll", [[(0, 40)], [(0, 30), (1, 3)], [(0, 10)]], None),
     ("eviction", [[(0, 60)], [(1, 60)], [(2, 60), (3, 9)], [(0, 5)],
                   [(1, 4), (3, 2)]], 24),
+    # the absorbed kernel's walks, all in the last call: 64 blocks (a
+    # session of 512 tokens, full), 1 block, and 13 with the last block
+    # holding one token; a group of one live row (11 = 8 + 3, 3 = 3)
+    ("ragged_walks", [[(0, 120)], [(0, 120)], [(0, 120)], [(0, 120)],
+                      [(0, 21), (2, 96)],
+                      [(0, 11), (1, 1), (2, 1), (3, 3)]], None),
 ])
 def test_appended_chunks_through_the_paged_cache_equal_one_full_forward(
-        model, case, chunks, blocks):
+        model, force_form, case, chunks, blocks):
     _, cfg, params = model
+    if case == "ragged_walks":
+        _, cfg = tiny(assumed=dict(context_tokens=512, block_tokens=8,
+                                   pool_tokens=1024))
+        force_form("absorbed")
     run = Stepper(cfg, params, blocks=blocks)
     rng = np.random.default_rng(1)
     for step in chunks:
-        run.append(step, rng)
+        stats, _ = run.append(step, rng)
     assert run.worst_gap() < 2e-5
     if case == "session_roll":
         assert run.table.rolled == 1 and (0, 1) in run.sessions
@@ -143,6 +160,71 @@ def test_appended_chunks_through_the_paged_cache_equal_one_full_forward(
         # 23 usable blocks of 8 tokens: the third step ends tenant 0's
         # session (least recently appended), which then starts anew
         assert run.table.evicted >= 1 and (0, 1) in run.sessions
+    if case == "ragged_walks":
+        assert run.table.rolled == 1
+        assert stats["seq_absorbed_group_blocks"] == 64 + 64 + 13 + 1 + 1
+
+
+def _dense_absorbed(q_cat, q_pos, pool, seg_blocks, tok_seg, w_v, scale,
+                    latent):
+    """Every absorbed token against its session's whole block table, a
+    plain softmax over the keys at or before its position."""
+    out = np.zeros(q_cat.shape[:2] + (w_v.shape[-1],), np.float32)
+    for t in np.nonzero(tok_seg >= 0)[0]:
+        keys = pool[seg_blocks[tok_seg[t]]].reshape(-1, pool.shape[-1])
+        s = (q_cat[t] @ keys.T) * scale
+        s[:, np.arange(len(keys)) > q_pos[t]] = -np.inf
+        p = np.exp(s - s.max(axis=1, keepdims=True))
+        o_lat = (p / p.sum(axis=1, keepdims=True)) @ keys[:, :latent]
+        out[t] = np.einsum("hc,chv->hv", o_lat, w_v)
+    return out
+
+
+@pytest.mark.parametrize("case, held, new", [
+    ("no_groups", [], []),                  # the warm-up's empty plan
+    ("one_live_row", [30, 0], [9, 1]),
+    ("one_and_64_blocks", [505, 0, 100], [7, 2, 3]),
+    ("last_block_partly_filled", [11, 61, 7], [8, 16, 1]),
+])
+def test_the_absorbed_kernel_on_ragged_work(case, held, new):
+    """``absorbed_attention`` alone under the interpreter, the pool and
+    the queries random: rows of no group and the pad rows come back
+    zero, every other row is the dense softmax's."""
+    H, latent, W, block = 4, 64, 128, 8
+    _, cfg = tiny(assumed=dict(context_tokens=512, block_tokens=block,
+                               pool_tokens=2048))
+    caps = lm.plan_caps(cfg, 32, 8)
+    plan = lm.empty_plan(cfg, caps, 0)
+    n_tok = sum(new)
+    if new:
+        table = sp.SessionTable(cfg.pool_blocks, cfg.context_tokens, block)
+        table.append([(t, n) for t, n in enumerate(held) if n])
+        plan, stats, _ = sp.build_plan(
+            cfg, caps, table.append(list(enumerate(new))),
+            np.zeros(n_tok, np.int32), np.arange(len(new)), frozenset())
+        assert stats["seq_absorbed_tokens"] == n_tok
+    rng = np.random.default_rng(4)
+    T1 = caps["tokens"] + la.GROUP
+    q_cat = rng.standard_normal((T1, H, W)).astype(np.float32)
+    pool = rng.standard_normal((cfg.pool_blocks, block, W)).astype(
+        np.float32)
+    w_v = rng.standard_normal((latent, H, 16)).astype(np.float32)
+    pad = lambda a: np.concatenate([a, np.zeros(la.GROUP, a.dtype)])
+    tok_seg = np.concatenate([plan["tok_seg"], np.full(la.GROUP, -1)])
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(la.absorbed_attention(
+            jnp.asarray(q_cat[..., :latent]), jnp.asarray(q_cat[..., latent:]),
+            pad(plan["tok_pos"]), tok_seg >= 0, jnp.asarray(pool),
+            plan["seg_blocks"], plan["groups"], jnp.asarray(w_v), 0.25,
+            block))
+    want = _dense_absorbed(q_cat, pad(plan["tok_pos"]), pool,
+                           plan["seg_blocks"], tok_seg, w_v, 0.25, latent)
+    assert not got[n_tok:].any()
+    np.testing.assert_allclose(got, want, atol=2e-4)
+    if case == "one_and_64_blocks":
+        g = plan["groups"]
+        assert sorted(g["nblk"][:int(g["n_groups"])]) == [1, 13, 64]
+        assert stats["seq_absorbed_group_blocks"] == 78
 
 
 def test_both_forms_in_one_step_chosen_by_size(model, monkeypatch):

@@ -124,14 +124,10 @@ def test_pool_op_compiles_with_no_whole_plane_op(one_chip, optimizing,
 
 # -- the sequence model's latent pool (PR 28) ---------------------------------
 
-def test_seq_step_writes_the_latent_pool_in_place(one_chip, optimizing):
-    """The serving step of ``k2-fleet-overload`` at its published widths
-    and its real pool, compiled for the chip: the donated pool comes back
-    in its own buffer, the program's temporaries stay far under the
-    pool's size (no pool-shaped copy: a ``[.., 128, 576]`` row would be
-    laid out token-minor and copied whole around every write; the row is
-    held at 640 columns), and everything fits the chip beside the
-    weights."""
+def _compiled_seq_step(one_chip, grid_size):
+    """``(compiled step, the pool's shape)`` of ``k2-fleet-overload`` at
+    its published widths and its real pool, at the token grid's least or
+    largest size (``grid_size``: ``min`` or ``max``)."""
     import json
     import os
 
@@ -156,7 +152,7 @@ def test_seq_step_writes_the_latent_pool_in_place(one_chip, optimizing):
              for k, (s, _) in leaf.items()} if isinstance(leaf, dict)
             else sds(leaf[0], jnp.float32 if name in lm.F32_LEAVES
                      else jnp.bfloat16))
-    tokens = min(spec["assumed"]["token_grid"])
+    tokens = grid_size(spec["assumed"]["token_grid"])
     caps = lm.plan_caps(cfg, tokens, 2 * spec["fleet"]["n_tenants"] + 64)
     plan = jax.tree_util.tree_map(
         lambda a: sds(np.shape(a), jnp.int32), lm.empty_plan(cfg, caps, 0))
@@ -166,12 +162,63 @@ def test_seq_step_writes_the_latent_pool_in_place(one_chip, optimizing):
                  jnp.bfloat16)
     step = jax.jit(lambda p, pool, h, plan: lm.append_step(
         cfg, p, pool, h, plan), donate_argnums=(1, 2))
-    compiled = step.lower(flat, pool, h_last, plan).compile()
+    return step.lower(flat, pool, h_last, plan).compile(), pool.shape
+
+
+def test_seq_step_writes_the_latent_pool_in_place(one_chip, optimizing):
+    """The serving step of ``k2-fleet-overload`` at its published widths
+    and its real pool, compiled for the chip: the donated pool comes back
+    in its own buffer, the program's temporaries stay far under the
+    pool's size (no pool-shaped copy: a ``[.., 128, 576]`` row would be
+    laid out token-minor and copied whole around every write; the row is
+    held at 640 columns), and everything fits the chip beside the
+    weights."""
+    import numpy as np
+
+    from anomod.models import latent_moe as lm
+
+    compiled, pool_shape = _compiled_seq_step(one_chip, min)
     mem = compiled.memory_analysis()
     # the attention kernels' device ops carry their call's name in the
     # metadata a trace keeps (what `mla_append_roofline` finds them by)
     assert f'/{lm.ATTENTION_SCOPE}/while/body/' in compiled.as_text()
-    pool_bytes = 2 * int(np.prod(pool.shape))
+    pool_bytes = 2 * int(np.prod(pool_shape))
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 2
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+
+
+def test_seq_step_absorbed_form_is_one_mosaic_kernel(one_chip, optimizing):
+    """The same step at the grid's LARGEST size (PR 30): the absorbed form
+    lowers through Mosaic as one custom call a layer stack that carries
+    the attention's call name (what ``mla_append_roofline`` finds it by)
+    and no copy of the pool is made for it; the only loops left under
+    that name are the expanded form's two; the pool is still aliased and
+    the program fits the chip."""
+    import numpy as np
+
+    from anomod.models import latent_moe as lm
+
+    compiled, pool_shape = _compiled_seq_step(one_chip, max)
+    text = compiled.as_text()
+    flat_pool = f"bf16[{pool_shape[0] * pool_shape[1]},"
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and f"/{lm.ATTENTION_SCOPE}/" in line]
+    # one in the dense layers' scan, one in the routed layers'
+    assert len(kernels) == 2 and all("/pallas_call" in k for k in kernels)
+    assert flat_pool in text and not re.search(
+        rf"= {re.escape(flat_pool)}[^=\n]* copy\(", text)
+    loops = re.findall(rf'while\([^\n]*op_name="[^"]*/{lm.ATTENTION_SCOPE}/'
+                       r'([^"]*)"', text)
+    assert sorted(loops) == ["while", "while", "while/body/while",
+                             "while/body/while"], loops
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * int(np.prod(pool_shape))
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # at twice the other test's tokens: 2.07e9 of temporaries (1.78e9
+    # with the loop: the kernel's [tokens, heads, latent] result beside
+    # its head-major copy for the value half), and the program fits the
+    # chip's 16.9e9 with 0.5e9 left for the sketch planes' state
+    assert mem.temp_size_in_bytes < 0.55 * pool_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9

@@ -5,6 +5,7 @@ sketch planes untouched by the plane, its spans, counters and refusals."""
 import numpy as np
 import pytest
 
+from anomod.ops import latent_attention as la
 from anomod.serve import seqplane as sp
 from anomod.serve.engine import run_power_law
 from anomod.utils.tracing import Tracer
@@ -97,6 +98,11 @@ def test_plane_scores_every_served_span_with_spans_and_counters(runs):
     ticks = on.flight_recorder.records()
     assert all("seq" in rec for rec in ticks)
     assert sum(rec["seq"]["tokens"] for rec in ticks) == c["seq_tokens"]
+    # the absorbed kernel's working steps: every absorbed token is in a
+    # group of at most GROUP that walks at least one block
+    assert sum(rec["seq"].get("absorbed_group_blocks", 0) for rec in ticks) \
+        == c["seq_absorbed_group_blocks"] \
+        >= c["seq_absorbed_tokens"] / la.GROUP > 0
     assert all(rec["seq"] == {"tokens": 0}
                for rec in off.flight_recorder.records())
 
