@@ -246,14 +246,10 @@ def repo_root() -> Path:
 
 
 def scan_files(root: Path) -> List[Path]:
-    """The lint scan set: the package, the bench driver and the CI
-    scripts.  tests/ is deliberately excluded — tests/lint_fixtures/
-    holds must-trip corpora."""
-    files = []
-    bench = root / "bench.py"
-    if bench.is_file():
-        files.append(bench)
-    files += sorted((root / "anomod").rglob("*.py"))
+    """The lint scan set: the package and the CI scripts.  tests/ is
+    deliberately excluded — tests/lint_fixtures/ holds must-trip
+    corpora."""
+    files = sorted((root / "anomod").rglob("*.py"))
     files += sorted((root / "scripts").glob("*.py"))
     return [p for p in files if p.is_file()]
 
@@ -483,7 +479,7 @@ RULES: Dict[str, Rule] = {r.id: r for r in [
          "contract the variant-key tests pin)"),
     Rule("C601", "commit-barrier",
          "read of deferred-commit state (tenant detectors/replays, "
-         "RCA queue, report/flight/perf/census/policy publishers) "
+         "RCA queue, report/flight/census/policy publishers) "
          "between a deferred dispatch and _commit_deferred()",
          "the async serve tick (ANOMOD_SERVE_ASYNC_COMMIT) keeps byte "
          "parity only because nothing reads scored state while folds "

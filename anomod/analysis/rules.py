@@ -386,9 +386,8 @@ _DEFER_STATE_ATTRS = {"_tenant_det", "_tenant_replay", "_rca_queue",
 
 #: engine methods that read or publish committed scoring state (the
 #: barrier tail itself runs them AFTER the drain)
-_DEFER_READ_CALLS = {"alerts_for", "report", "_perf_drain",
-                     "_census_drain", "_flight_tick", "_policy_step",
-                     "_rca_step"}
+_DEFER_READ_CALLS = {"alerts_for", "report", "_census_drain",
+                     "_flight_tick", "_policy_step", "_rca_step"}
 
 #: the one sanctioned barrier
 _BARRIER_CALL = "_commit_deferred"

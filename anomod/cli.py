@@ -13,7 +13,9 @@ import json
 import sys
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
+    """Every sub-command and flag of ``anomod`` (tests/test_docs.py parses
+    the documents' command lines with it)."""
     parser = argparse.ArgumentParser(
         prog="anomod",
         description="TPU-native anomaly-detection & RCA framework (AnoMod capabilities)")
@@ -116,19 +118,19 @@ def main(argv=None) -> int:
 
     p_ing = sub.add_parser(
         "ingest", help="ingest-cache management (anomod.io.cache): warm the "
-        "content-addressed corpus cache before driver benches, report its "
+        "content-addressed corpus cache ahead of a run, report its "
         "state, or clear it")
     p_ing.add_argument("--warm-cache", action="store_true",
-                       help="load the full corpus (and the bench.py span "
-                            "corpus) through the cache so later runs are "
-                            "warm")
+                       help="load the full corpus (and the chip_smoke.py "
+                            "span corpus) through the cache so later runs "
+                            "are warm")
     p_ing.add_argument("--testbed", choices=["SN", "TT", "both"],
                        default="TT")
     p_ing.add_argument("--traces", type=int, default=200,
                        help="n_synth_traces for the corpus loaders")
     p_ing.add_argument("--bench-traces", type=int, default=2_000,
-                       help="n_traces of the bench.py replay corpus to warm "
-                            "(0 skips it; 2000 is bench.py's default)")
+                       help="n_traces of the chip_smoke.py replay corpus "
+                            "to warm (0 skips it; 2000 is its size)")
     p_ing.add_argument("--workers", type=int, default=None,
                        help="process-pool size for the corpus load "
                             "(default: ANOMOD_INGEST_WORKERS)")
@@ -330,7 +332,7 @@ def main(argv=None) -> int:
                          help="serving capacity in spans/sec")
     p_serve.add_argument("--overload", type=float, default=1.0,
                          help="offered load as a multiple of capacity "
-                              "(2.0 = the bench's shed regime)")
+                              "(2.0 = the shed regime)")
     p_serve.add_argument("--alpha", type=float, default=1.2,
                          help="power-law exponent of the tenant rate "
                               "distribution (0 = equal rates)")
@@ -388,11 +390,6 @@ def main(argv=None) -> int:
                          help="disable the GIL-free C++ lane staging for "
                               "this run: the interpreter fill, as before "
                               "ANOMOD_NATIVE (byte-identical output)")
-    p_serve.add_argument("--perf", action="store_true",
-                         help="dispatch-lifecycle timeline + overlap-"
-                              "bubble accounting (anomod.obs.perf; "
-                              "default: ANOMOD_PERF — pure read-side, "
-                              "decisions byte-identical either way)")
     p_serve.add_argument("--async-commit", action="store_true",
                          help="deferred-commit tick: issue the fold/"
                               "score dispatches without waiting, run "
@@ -595,50 +592,6 @@ def main(argv=None) -> int:
                          help="record: tenant-state residency; replay: "
                               "override the recorded residency")
 
-    p_perf = sub.add_parser(
-        "perf", help="performance observatory (anomod.obs.perf): "
-        "`record` runs seeded traffic with the dispatch-lifecycle "
-        "timeline on and dumps the event timeline + overlap-bubble "
-        "analysis (--chrome adds a Chrome/Perfetto trace, one lane "
-        "per shard/scratch-slot), `diff` compares two bench captures "
-        "— decision metrics byte-exact, wall metrics by bootstrap "
-        "confidence intervals over their raw_wall_s samples against "
-        "the explicit box noise model (ANOMOD_PERF_NOISE_FLOOR) — "
-        "exiting nonzero naming the first statistically significant "
-        "wall regression or decision drift, and `history` indexes a "
-        "bench_runs/ directory into a trajectory table")
-    p_perf.add_argument("action", choices=["record", "diff", "history"])
-    p_perf.add_argument("paths", nargs="*",
-                        help="diff: the two capture JSONs (A then B); "
-                             "history: the runs directory "
-                             "(default bench_runs/)")
-    p_perf.add_argument("--out", default=None,
-                        help="record: timeline JSON output path "
-                             "(required)")
-    p_perf.add_argument("--chrome", default=None,
-                        help="record: also dump the timeline as a "
-                             "Chrome trace-event array (loads in "
-                             "chrome://tracing / Perfetto; lanes group "
-                             "by shard, shard/slot tags in args)")
-    p_perf.add_argument("--tenants", type=int, default=24,
-                        help="record only (default 24)")
-    p_perf.add_argument("--duration", type=float, default=30.0,
-                        help="record: virtual seconds to serve")
-    p_perf.add_argument("--tick", type=float, default=0.5)
-    p_perf.add_argument("--capacity", type=float, default=4000.0)
-    p_perf.add_argument("--overload", type=float, default=1.5)
-    p_perf.add_argument("--seed", type=int, default=0)
-    p_perf.add_argument("--shards", type=int, default=None,
-                        help="record: engine shard count (default: "
-                             "ANOMOD_SERVE_SHARDS)")
-    p_perf.add_argument("--pipeline", type=int, default=None,
-                        help="record: dispatch pipeline depth (default: "
-                             "ANOMOD_SERVE_PIPELINE)")
-    p_perf.add_argument("--noise-floor", type=float, default=None,
-                        help="diff: box noise fraction the wall-ratio "
-                             "CIs must clear (default: "
-                             "ANOMOD_PERF_NOISE_FLOOR, 0.35)")
-
     p_cen = sub.add_parser(
         "census", help="fleet census observatory (anomod.obs.census): "
         "`record` runs seeded traffic with the deterministic resident-"
@@ -646,7 +599,7 @@ def main(argv=None) -> int:
         "`probe` sweeps registered-fleet sizes at fixed hot traffic and "
         "fits the O(registered) per-tick wall and resident-bytes "
         "slopes (the baseline the million-tenant tiering refactor must "
-        "flatten), and `diff` compares two bench captures' census "
+        "flatten), and `diff` compares two captures' census "
         "blocks — byte counts exact (deterministic, so every delta is "
         "real), wall slopes within the explicit box noise tolerance — "
         "exiting nonzero on a regression: the tiering PR's "
@@ -692,8 +645,7 @@ def main(argv=None) -> int:
                             "(default 8)")
     p_cen.add_argument("--tolerance", type=float, default=None,
                        help="diff: wall-slope noise tolerance the B/A "
-                            "ratio must clear (default: "
-                            "ANOMOD_PERF_NOISE_FLOOR)")
+                            "ratio must clear (default 0.35)")
 
     p_q = sub.add_parser(
         "quality", help="de-saturated quality sweep: degradation curves over "
@@ -725,13 +677,17 @@ def main(argv=None) -> int:
                           "node-locus training)")
     p_q.add_argument("--json", action="store_true",
                      help="emit one JSON object per sweep point")
+    return parser
 
+
+def main(argv=None) -> int:
+    parser = build_parser()
     args = parser.parse_args(argv)
 
     # the subcommands that compile: place the persistent compilation
     # cache before any of them touches the backend (the rest stay
     # jax-free and start in milliseconds)
-    if args.cmd in ("detect", "stream", "obs", "serve", "perf", "census",
+    if args.cmd in ("detect", "stream", "obs", "serve", "census",
                     "audit", "quality", "rca", "replay"):
         from anomod.utils.platform import enable_compile_cache
         enable_compile_cache()
@@ -1187,8 +1143,7 @@ def main(argv=None) -> int:
                               ("--fold", args.fold),
                               ("--state", args.state),
                               ("--ckpt-every", args.ckpt_every),
-                              ("--trace-out", args.trace_out),
-                              ("--perf", args.perf)):
+                              ("--trace-out", args.trace_out)):
                 if bad:
                     parser.error(f"{flag} is not supported on the "
                                  "live-feed path")
@@ -1268,7 +1223,6 @@ def main(argv=None) -> int:
             pipeline=args.pipeline,
             native=False if args.no_native else None,
             state=args.state, chaos=args.chaos,
-            perf=True if args.perf else None,
             ckpt_every=args.ckpt_every,
             policy=args.policy, policy_script=args.policy_script,
             min_shards=args.min_shards, max_shards=args.max_shards,
@@ -1293,120 +1247,10 @@ def main(argv=None) -> int:
         print(json.dumps(out, indent=2))
         return 0
 
-    if args.cmd == "perf":
-        from pathlib import Path as _P
-        if args.action == "history":
-            if len(args.paths) > 1:
-                parser.error("perf history takes at most one runs "
-                             "directory")
-            # mode-mismatched flags fail loud, never silently ignored
-            # (the audit-branch discipline)
-            for flag, val in (("--out", args.out),
-                              ("--chrome", args.chrome),
-                              ("--noise-floor", args.noise_floor)):
-                if val is not None:
-                    parser.error(f"{flag} applies to perf "
-                                 + ("diff" if flag == "--noise-floor"
-                                    else "record")
-                                 + ", not history")
-            from anomod.obs.perf import capture_history
-            rows = capture_history(args.paths[0] if args.paths
-                                   else "bench_runs")
-            print(json.dumps({"check": "anomod_perf_history",
-                              "n_captures": len(rows), "runs": rows},
-                             indent=2))
-            return 0
-        if args.action == "diff":
-            if len(args.paths) != 2:
-                parser.error("perf diff takes exactly two capture "
-                             "paths (A then B)")
-            if args.out or args.chrome:
-                parser.error("--out/--chrome apply to perf record")
-            from anomod.obs.perf import diff_captures
-            try:
-                a = json.loads(_P(args.paths[0]).read_text())
-                b = json.loads(_P(args.paths[1]).read_text())
-            except (OSError, ValueError) as e:
-                parser.error(f"cannot load capture: {e}")
-            doc = diff_captures(a, b, noise_floor=args.noise_floor)
-            print(json.dumps(doc, indent=2))
-            if doc["decision_mismatches"]:
-                m = doc["decision_mismatches"][0]
-                print(f"perf diff: decision drift at {m['path']} "
-                      f"(a={m['a']!r}, b={m['b']!r}) — decision "
-                      "metrics are byte-exact across same-seed "
-                      "captures; this is not noise", file=sys.stderr)
-                return 2
-            if doc["status"] == "decision-coverage-gap":
-                print("perf diff: the two captures share NO decision "
-                      "metrics (truncated or foreign capture?) — "
-                      "nothing was actually compared byte-exact, so "
-                      "this verdict must not pass a gate",
-                      file=sys.stderr)
-                return 2
-            if doc["regressions"]:
-                r = doc["regressions"][0]
-                print(f"perf diff: statistically significant wall "
-                      f"regression at {r['path']}: B/A mean ratio "
-                      f"{r['ratio']} (95% CI {r['ci95']}) clears the "
-                      f"1+{doc['noise_model']['floor_fraction']} "
-                      "noise floor", file=sys.stderr)
-                return 1
-            return 0
-        # record
-        if not args.out:
-            parser.error("perf record needs --out")
-        if args.paths:
-            parser.error("perf record takes no positional paths")
-        if args.noise_floor is not None:
-            parser.error("--noise-floor applies to perf diff")
-        from anomod.obs.perf import (PERF_FORMAT, analyze_events,
-                                     perf_tracer, round_events)
-        from anomod.serve.engine import run_power_law
-        eng, rep = run_power_law(
-            n_tenants=args.tenants, n_services=8,
-            capacity_spans_per_s=args.capacity, overload=args.overload,
-            duration_s=args.duration, tick_s=args.tick, seed=args.seed,
-            shards=args.shards, pipeline=args.pipeline, perf=True)
-        stats = analyze_events(eng.perf_events, eng.pipeline)
-        from anomod.obs.flight import _atomic_write_json
-        _atomic_write_json(args.out, {
-            "perf_format": PERF_FORMAT,
-            "engine": {"shards": rep.shards, "pipeline": rep.pipeline,
-                       "seed": args.seed, "tick_s": args.tick},
-            "report": {
-                "perf_events_recorded": rep.perf_events_recorded,
-                "events_dropped": eng.perf_events_dropped,
-                "overlap_headroom_s": rep.overlap_headroom_s,
-                "fold_wait_s": rep.fold_wait_s,
-                "bubble_fractions": rep.bubble_fractions,
-                "stage_wall_s": rep.stage_wall_s,
-                "dispatch_wall_s": rep.dispatch_wall_s,
-                "fold_wall_s": rep.fold_wall_s,
-                "score_wall_s": rep.score_wall_s,
-                "serve_wall_s": rep.serve_wall_s},
-            "raw_wall_s": [round(t, 6) for t in eng.tick_walls],
-            "events": round_events(eng.perf_events)})
-        out = {"action": "record", "out": args.out,
-               "events": rep.perf_events_recorded,
-               "overlap_headroom_s": rep.overlap_headroom_s,
-               "fold_wait_s": rep.fold_wait_s,
-               "fold_wall_s": rep.fold_wall_s,
-               "headroom_of_fold":
-                   rep.bubble_fractions.get("headroom_of_fold"),
-               "analysis": {k: round(v, 6) if isinstance(v, float)
-                            else v for k, v in stats.items()}}
-        if args.chrome:
-            tr = perf_tracer(eng.perf_events)
-            tr.dump_chrome(_P(args.chrome))
-            out["chrome"] = {"out": args.chrome, "spans": tr.n_spans}
-        print(json.dumps(out, indent=2))
-        return 0
-
     if args.cmd == "census":
         from pathlib import Path as _P
         # mode-mismatched flags fail loud, never silently ignored
-        # (the audit/perf-branch discipline): record-only and
+        # (the audit-branch discipline): record-only and
         # probe-only flags are refused by the other actions
         _record_only = (("--tenants", args.tenants),
                         ("--duration", args.duration),
@@ -1682,6 +1526,9 @@ def main(argv=None) -> int:
             # fold, never under the replaying process's env knobs
             kw.setdefault("worker", "thread")
             kw.setdefault("fold", "dense")
+            # journals recorded before the perf observatory went carry
+            # its enable bit; it never moved a canonical plane
+            kw.pop("perf", None)
             eng, rep = run_power_law(**kw)
         doc = eng.flight_recorder.dump(args.out)
         print(json.dumps({
@@ -1725,7 +1572,7 @@ def main(argv=None) -> int:
         else:
             pts = severity_sweep(severities=args.severities, **common)
             render = render_markdown
-        # committed provenance trail (same contract as bench.py): every
+        # committed provenance trail: every
         # sweep leaves a bench_runs/ record with the full table + device
         # string + git SHA, so docs tables cite re-checkable artifacts
         try:

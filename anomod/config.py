@@ -34,7 +34,7 @@ def _cache_dir_env() -> Optional[Path]:
     """ANOMOD_CACHE_DIR: ingest-cache root; "0"/"off"/"none" disables it.
 
     Unset means the default user cache location — the cache is on by
-    default so repeat bench captures measure the kernel, not host parsing.
+    default so repeat runs measure the kernel, not host parsing.
     """
     raw = _env("ANOMOD_CACHE_DIR", "")
     if raw.lower() in _CACHE_OFF:
@@ -285,7 +285,7 @@ def _serve_async_commit_env() -> bool:
     SLO, shed and the canonical flight journal are pinned byte-identical
     to the synchronous engine (``anomod audit replay`` crosses the two
     freely); only the wall-time attribution moves — the hidden wait is
-    reported on the ``commit_defer`` perf leg (anomod.obs.perf).
+    reported as ``ServeReport.commit_defer_wall_s``.
 
     Validated against the explicit token sets (not the legacy
     anything-truthy bool idiom): the knob silently flips the engine's
@@ -499,8 +499,7 @@ def _flight_env() -> bool:
 
     Default ON — the recorder is the always-on tick journal every
     determinism contract replays against (bounded ring, bounded
-    per-tick cost; the serve bench gates its overhead at <= 5% like
-    telemetry) — "0"/"false"/"off" disables it end to end.
+    per-tick cost) — "0"/"false"/"off" disables it end to end.
     """
     return _env("ANOMOD_FLIGHT", "1").strip().lower() \
         not in ("0", "false", "off", "no")
@@ -935,77 +934,18 @@ def _serve_max_respawns_env() -> int:
     return n
 
 
-def _perf_env() -> bool:
-    """ANOMOD_PERF: the performance observatory's dispatch-lifecycle
-    timeline (anomod.obs.perf).
-
-    Default OFF — it is a deep-dive instrument (the flight recorder is
-    the always-on journal); when on, every fused lane dispatch records
-    staged/submitted/materialized/folded/slot-refilled event
-    timestamps, the per-tick overlap-headroom bound is computed, and
-    the events ride the flight journal's ``perf`` VARIANT key.  A pure
-    read-side consumer: decisions are byte-identical on or off
-    (pinned), overhead priced in the bench ``perf`` block (≤5% bar).
-    """
-    return _env("ANOMOD_PERF", "0").strip().lower() \
-        not in ("0", "false", "off", "no", "")
-
-
-def _perf_max_events_env() -> int:
-    """ANOMOD_PERF_MAX_EVENTS: retained dispatch-timeline event bound.
-
-    The engine keeps the drained lifecycle events for report/export;
-    past this bound the OLDEST drop and every eviction is counted
-    (``anomod_perf_dropped_events_total`` — loss visible, never
-    silent, the flight-ring discipline).
-    """
-    raw = _env("ANOMOD_PERF_MAX_EVENTS", "262144")
-    try:
-        n = int(raw)
-    except ValueError:
-        raise ValueError(
-            f"ANOMOD_PERF_MAX_EVENTS must be a positive integer, "
-            f"got {raw!r}")
-    if not 1 <= n <= 100_000_000:
-        raise ValueError(
-            f"ANOMOD_PERF_MAX_EVENTS must be in [1, 100000000], got {n}")
-    return n
-
-
-def _perf_noise_floor_env() -> float:
-    """ANOMOD_PERF_NOISE_FLOOR: the box noise model `anomod perf diff`
-    tests wall ratios against (fraction; 0.35 = this box's measured
-    ±35% run-to-run floor, docs/BENCHMARKS.md).
-
-    A wall regression is flagged only when the whole 95% bootstrap CI
-    of the B/A mean-wall ratio clears ``1 + floor`` — the floor is the
-    EXPLICIT noise hedge every capture comparison used to carry as
-    prose.
-    """
-    raw = _env("ANOMOD_PERF_NOISE_FLOOR", "0.35")
-    try:
-        v = float(raw)
-    except ValueError:
-        raise ValueError(
-            f"ANOMOD_PERF_NOISE_FLOOR must be a number, got {raw!r}")
-    if not 0 <= v <= 10:
-        raise ValueError(
-            f"ANOMOD_PERF_NOISE_FLOOR must be in [0, 10], got {v}")
-    return v
-
-
 def _census_env() -> bool:
     """ANOMOD_CENSUS: the fleet census observatory (anomod.obs.census).
 
-    Default OFF — like the perf timeline it is a deep-dive instrument
+    Default OFF — it is a deep-dive instrument
     (the flight recorder stays the always-on journal); when on, every
     ``ANOMOD_CENSUS_EVERY``-th tick takes a deterministic resident-
     bytes census (per-(shard, plane) byte counts from array shapes and
     container lengths — never an RSS wall) plus the hot-set/Zipf
     census, exported as registry gauges and the flight journal's
     ``census`` VARIANT key.  A pure read-side consumer: decisions are
-    byte-identical on or off (pinned), overhead priced in the bench
-    ``census`` block (≤5% bar).
+    byte-identical on or off (pinned); its in-run cost is
+    ``ServeReport.census_wall_s``.
     """
     return _env("ANOMOD_CENSUS", "0").strip().lower() \
         not in ("0", "false", "off", "no", "")
@@ -1206,9 +1146,8 @@ def _native_env() -> str:
     on first use if a toolchain is present) and degrades to the pure-
     Python paths otherwise; ``on`` (``1``) REQUIRES it — the first native
     consumer raises with the recorded build-failure reason instead of
-    silently serving the slow path, and ``anomod validate`` /
-    ``scripts/pre_bench_check.py --mode serve`` surface the same reason
-    (exit 5 on a requested-but-unusable runtime); ``off`` (``0``) forces
+    silently serving the slow path, and ``anomod validate`` surfaces
+    the same reason; ``off`` (``0``) forces
     the pure-Python paths even when the .so is fine.  Validated here so a
     typo fails loudly at config construction.
     """
@@ -1324,9 +1263,8 @@ def _obs_enabled_env() -> bool:
     """ANOMOD_OBS_ENABLED: process-wide metrics registry switch.
 
     Default ON — the hot-path cost of a disabled-check-free counter bump
-    is nanoseconds, and the serve bench pins the enabled-vs-off overhead
-    at <= 5% — "0"/"false"/"off" turns every metric handle into a shared
-    no-op object (anomod.obs.registry)."""
+    is nanoseconds — "0"/"false"/"off" turns every metric handle into a
+    shared no-op object (anomod.obs.registry)."""
     return _env("ANOMOD_OBS_ENABLED", "1").strip().lower() \
         not in ("0", "false", "off", "no")
 
@@ -1507,17 +1445,6 @@ class Config:
     # (anomod.obs.flight.forensic_bundle; None = dumps off).
     flight_dump_dir: Optional[Path] = dataclasses.field(
         default_factory=_flight_dump_dir_env)
-    # ANOMOD_PERF — dispatch-lifecycle timeline + overlap-bubble
-    # accounting (anomod.obs.perf; off by default, pure read-side).
-    perf: bool = dataclasses.field(default_factory=_perf_env)
-    # ANOMOD_PERF_MAX_EVENTS — retained timeline-event bound (oldest
-    # drop past it, counted in the registry).
-    perf_max_events: int = dataclasses.field(
-        default_factory=_perf_max_events_env)
-    # ANOMOD_PERF_NOISE_FLOOR — the explicit box noise model `anomod
-    # perf diff` tests bootstrap wall-ratio CIs against.
-    perf_noise_floor: float = dataclasses.field(
-        default_factory=_perf_noise_floor_env)
     # ANOMOD_CENSUS — fleet census observatory: deterministic
     # resident-bytes + hot-set/Zipf census per cadence tick
     # (anomod.obs.census; off by default, pure read-side).

@@ -102,8 +102,7 @@ def cache_key(parts: Dict[str, Any]) -> str:
 
 def full_key(kind: str, key_parts: Dict[str, Any]) -> str:
     """The ONE composition of caller key parts + kind + format version —
-    shared by :func:`cached` and presence probes (the pre-bench gate), so
-    the two can never desync on the key recipe."""
+    the key recipe of :func:`cached`."""
     return cache_key({**key_parts, "kind": kind,
                       "cache_format_version": CACHE_FORMAT_VERSION})
 
@@ -328,8 +327,8 @@ def store(root: Path, key: str, kind: str, value,
                     cache_format_version=CACHE_FORMAT_VERSION)
         # payload first, sidecar last (checkpoint.py publish-order idiom);
         # both atomic, so a reader never sees a torn file — the sidecar is
-        # the human-readable provenance view (key parts, parse wall) and
-        # the pre-bench gate's presence marker, never the hot read path
+        # the human-readable provenance view (key parts, parse wall),
+        # never the hot read path
         _atomic_publish(payload_path,
                         lambda f: _write_payload(f, arrays, meta))
         _atomic_publish(json_path,
@@ -377,7 +376,7 @@ def cached(kind: str, key_parts: Dict[str, Any],
     On a miss, ``compute()`` runs and — when ``cacheable(value)`` — the
     result is published together with the measured cold parse wall
     (``meta["parse_s"]``), which warm hits then report back for honest
-    cold-number accounting (bench.py's ``parse_s`` field).
+    cold-number accounting (``load_bench_corpus``'s ``parse_s``).
     """
     root = cache_root(cfg)
     key = full_key(kind, key_parts)

@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 from pathlib import Path
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 from anomod import labels as labels_mod
 from anomod import synth
@@ -321,8 +321,8 @@ def load_corpus(testbed: str, cfg: Optional[Config] = None,
 
 
 # ---------------------------------------------------------------------------
-# Bench ingest helpers — the corpus bench.py replays, read through the cache
-# at the CONCATENATED level: one entry per (testbed, n_traces), so the warm
+# Bench ingest helpers — the corpus chip_smoke.py replays, read through the
+# cache at the CONCATENATED level: one entry per (testbed, n_traces), so the warm
 # path is a single bulk columnar read with no per-label re-intern concat.
 # ---------------------------------------------------------------------------
 
@@ -366,16 +366,3 @@ def load_bench_corpus(testbed: str, n_traces: int,
             "load_s": _time.perf_counter() - t0,
             "n_experiments": len(labels_mod.labels_for_testbed(testbed))}
     return batch, info
-
-
-def bench_cache_status(testbed: str, n_traces: int,
-                       cfg: Optional[Config] = None) -> Tuple[int, int]:
-    """(present, total) bench-corpus cache entries — the pre-bench gate's
-    cold/warm check, without loading anything."""
-    cfg = cfg or get_config()
-    root = cache.cache_root(cfg)
-    if root is None:
-        return 0, 1
-    key = cache.full_key("spans",
-                         bench_corpus_key_parts(testbed, n_traces, cfg))
-    return (1 if cache.entry_paths(root, key)[0].is_file() else 0), 1

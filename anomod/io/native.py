@@ -21,7 +21,7 @@ _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 #: why the native runtime is unusable (build/load/symbol failure detail),
 #: None while it is fine — surfaced by :func:`status` into
-#: ``anomod validate`` and the serve pre-bench gate, and quoted by the
+#: ``anomod validate`` and ``chip_smoke.py``, and quoted by the
 #: ANOMOD_NATIVE=on refusal so the operator sees the root cause instead
 #: of a silent slow path
 _BUILD_ERROR: Optional[str] = None
@@ -168,7 +168,7 @@ def enabled() -> bool:
 
 def staging_enabled(override: Optional[bool] = None) -> bool:
     """Resolve the serve staging path's native switch: an explicit
-    ``override`` (the bench's python-staging reference leg passes False;
+    ``override`` (a python-staging reference run passes False;
     True demands the runtime like ``ANOMOD_NATIVE=on``) beats the env
     knob; ``None`` defers to :func:`enabled`."""
     if override is None:
@@ -209,7 +209,7 @@ def sfq_kernels(require: bool = False):
 def status() -> dict:
     """The native runtime's health document (JSON-able): knob value,
     availability, .so path and the build-failure reason when unusable —
-    surfaced by ``anomod validate`` and the serve pre-bench gate."""
+    surfaced by ``anomod validate`` and ``chip_smoke.py``."""
     ok = available()
     m = mode()
     out = {

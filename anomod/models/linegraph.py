@@ -6,7 +6,7 @@ side of the culprit's outgoing calls degrades, the culprit's own spans
 stay healthy) is architecturally outside their evidence — post-leak-fix,
 all node-feature models score ≤0.06 edge-locus top-1, and even with the
 out-edge feature BLOCK the best attention model reaches 0.39
-(docs/BENCHMARKS.md).  This model makes edges first-class: each observed
+(docs/QUALITY.md).  This model makes edges first-class: each observed
 (caller, callee) edge is a token carrying its own windowed aggregates and
 explicit CONTRAST features (its deviation from the callee's other
 in-edges and the caller's other out-edges — the discriminative pattern
@@ -19,7 +19,7 @@ logit with direction-aware peak/mean readouts of the incident-edge
 logits — the caller's out-edge plane is exactly where a link fault lands.
 
 Round-5 redesign notes (committed records in bench_runs/, table in
-docs/BENCHMARKS.md):
+docs/QUALITY.md):
   - windowed inputs enter POOLED over windows (mean/max/mean-positive):
     the earlier flatten readout memorized window positions (train 1.00 /
     eval 0.42); pooling alone moved in-dist 0.42 -> 0.81.
@@ -29,7 +29,7 @@ docs/BENCHMARKS.md):
     training protocol: 0.39 top-1 there (bench_runs/20260731T184051Z)
     vs 0.50 with 24 training seeds (bench_runs/20260731T210351Z, the
     committed data-scaling record; in-dist 0.97 at both protocols —
-    see docs/BENCHMARKS.md for the same-protocol comparison against
+    see docs/QUALITY.md for the same-protocol comparison against
     the out-edge-block models).
 
 TPU-first shape discipline: the edge list is padded to a static E_max

@@ -2,13 +2,11 @@
 hot-set/Zipf telemetry, and O(registered)-vs-O(active) tick-cost
 attribution.
 
-The fourth observability plane, beside the metrics registry
-(anomod.obs.registry), the flight recorder (anomod.obs.flight) and the
-performance observatory (anomod.obs.perf).  The registry says how fast
-the serve plane ran, the flight recorder what it DECIDED, the perf
-observatory where the time went — this module says what the plane
-HOLDS, per tenant and per byte, and which of its costs scale with the
-REGISTERED fleet rather than the ACTIVE one.  It is the instrument the
+An observability plane beside the metrics registry
+(anomod.obs.registry) and the flight recorder (anomod.obs.flight).  The
+registry says how fast the serve plane ran, the flight recorder what it
+DECIDED — this module says what the plane HOLDS, per tenant and per
+byte, and which of its costs scale with the REGISTERED fleet rather than the ACTIVE one.  It is the instrument the
 ROADMAP's million-tenant tiering item ("O(hot-set) ticks",
 resident-bytes and demotion/promotion counters) lands against: the
 tiering refactor must flatten the baseline curves this module commits.
@@ -27,15 +25,15 @@ journal — byte-identical; pinned in tests/test_census.py):
   admission registries/queues (anomod.serve.queues — queued span
   arrays exact, per-registered-tenant bookkeeping at documented
   nominal entry sizes), the per-tenant SLO t-digests, the online-RCA
-  evidence buffers (anomod.serve.rca), and the flight/perf recorder
-  retentions (container length × schema-derived record size).  The
+  evidence buffers (anomod.serve.rca), and the flight recorder's
+  retention (container length × schema-derived record size).  The
   pool total is PINNED to reconcile exactly with
   ``(capacity + 1) × per-slot nbytes`` (row 0 is the dead slot) — a
   census whose pool arithmetic drifts from the arrays it describes is
   lying, and the ``pool_reconciled`` bit says so.  Records drain at
   the tick barrier in (shard, plane) order onto the flight journal's
   ``census`` VARIANT key (wall-free, so the variant stream is
-  byte-equal across same-seed reruns — unlike ``walls``/``perf``).
+  byte-equal across same-seed reruns — unlike ``walls``).
 
 - **Hot-set census** (:class:`CensusTracker`): per-tenant last-served
   tick and a served-span EWMA (decay :data:`CENSUS_EWMA_DECAY` per
@@ -65,17 +63,11 @@ journal — byte-identical; pinned in tests/test_census.py):
   before/after judge — byte counts compared exactly (they are
   deterministic, so any delta is real), slope fits within the explicit
   box noise tolerance.
-
-The bench ``census`` block (bench.py --mode serve) commits one capture
-of all three, plus ONE informational ``process_resident_memory_bytes``
-sample read from /proc (a cross-check that the deterministic total is
-the right order of magnitude — never a pin, never compared).
 """
 
 from __future__ import annotations
 
 import math
-import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -85,8 +77,13 @@ CENSUS_FORMAT = 1
 
 #: the census plane names, in the (shard, plane) drain order's plane
 #: axis — one row per (shard, plane) per census tick
-CENSUS_PLANES = ("admission", "flight", "perf", "pool", "rca",
-                 "scratch", "slo", "tier")
+CENSUS_PLANES = ("admission", "flight", "pool", "rca", "scratch",
+                 "slo", "tier")
+
+#: the fraction by which B's wall slope may exceed A's before
+#: :func:`diff_census` calls it a regression: a hedge against host
+#: wall-clock noise (byte counts compare exactly)
+WALL_SLOPE_TOLERANCE = 0.35
 
 #: per-tick decay of the served-span EWMA (applied lazily per idle
 #: tick, so updates stay O(served) and reads O(reported))
@@ -100,9 +97,8 @@ CENSUS_EWMA_DECAY = 0.9
 # the nominal per-entry sizes below — deterministic functions of
 # container LENGTH, which is what the census is for: it prices GROWTH
 # (does this structure scale with registered or with active tenants?),
-# not CPython malloc details.  The /proc RSS sample in the bench block
-# is the order-of-magnitude cross-check; these constants are the
-# comparable, replayable surface.
+# not CPython malloc details.  These constants are the comparable,
+# replayable surface.
 # ---------------------------------------------------------------------------
 
 #: one queued micro-batch's bookkeeping beyond its span arrays: the
@@ -135,10 +131,6 @@ RCA_TENANT_BYTES = 112
 #: the census prices the RING LENGTH at this schema-derived nominal so
 #: the byte stream stays deterministic)
 FLIGHT_RECORD_BYTES = 2048
-
-#: one retained perf-timeline event: len(EVENT_FIELDS)=14 slots of
-#: 8 bytes plus dict overhead (anomod.obs.perf.EVENT_FIELDS)
-PERF_EVENT_BYTES = 256
 
 #: one warm-tier entry's bookkeeping beyond its exact state arrays:
 #: the dict entry, the record row and the detector-snapshot scaffolding
@@ -198,20 +190,6 @@ def tdigest_nbytes(digest) -> int:
     if digest is None:
         return 0
     return plane_nbytes(digest.mean) + plane_nbytes(digest.weight)
-
-
-def process_resident_bytes() -> Optional[int]:
-    """ONE informational RSS sample from /proc/self/statm — the
-    order-of-magnitude cross-check the bench block records beside the
-    deterministic census total.  Never a pin, never compared (it moves
-    with allocator behavior, jax runtime buffers and import history);
-    None where /proc is unavailable."""
-    try:
-        with open("/proc/self/statm") as f:
-            pages = int(f.read().split()[1])
-        return pages * os.sysconf("SC_PAGE_SIZE")
-    except (OSError, ValueError, IndexError):
-        return None
 
 
 # ---------------------------------------------------------------------------
@@ -338,9 +316,6 @@ def collect_resident_bytes(engine) -> Tuple[List[dict], Dict[str, int],
     planes.append({"shard": -1, "plane": "flight",
                    "bytes": n_rec * FLIGHT_RECORD_BYTES,
                    "records": n_rec})
-    n_ev = len(engine.perf_events)
-    planes.append({"shard": -1, "plane": "perf",
-                   "bytes": n_ev * PERF_EVENT_BYTES, "events": n_ev})
 
     planes.sort(key=lambda r: (r["shard"], r["plane"]))
     by_plane: Dict[str, int] = {}
@@ -558,29 +533,20 @@ def fleet_probe(sizes: Optional[Sequence[int]] = None, hot: int = 1000,
 # `anomod census diff` — the tiering PR's before/after judge
 # ---------------------------------------------------------------------------
 
-def default_slope_tolerance() -> float:
-    """Wall-slope comparisons reuse the box noise model the perf
-    observatory validated (ANOMOD_PERF_NOISE_FLOOR) — one explicit
-    noise hedge for the whole repo, not two."""
-    from anomod.config import get_config
-    return get_config().perf_noise_floor
-
-
 def diff_census(a: dict, b: dict,
                 tolerance: Optional[float] = None) -> dict:
-    """Compare two bench captures' ``census`` blocks.
+    """Compare two captures' ``census`` blocks (``{"census":
+    {"resident_bytes": ..., "sweep": <``fleet_probe``'s document>}}``).
 
     BYTE counts are deterministic, so they compare EXACTLY: every
     per-plane delta is real (never noise) and any growth in B is a
     regression.  The bytes SLOPE is a fit over those deterministic
     points, so it compares exactly too.  The WALL slope is wall clock:
     B regresses only when it exceeds A's slope by more than
-    ``tolerance`` (default: the ANOMOD_PERF_NOISE_FLOOR box noise
-    model).  Returns the verdict document ``anomod census diff``
-    prints; ``status`` is ``ok`` / ``bytes-regression`` /
-    ``slope-regression`` / ``census-missing``."""
-    tol = default_slope_tolerance() if tolerance is None \
-        else float(tolerance)
+    ``tolerance`` (default: :data:`WALL_SLOPE_TOLERANCE`).  Returns the
+    verdict document ``anomod census diff`` prints; ``status`` is ``ok`` /
+    ``bytes-regression`` / ``slope-regression`` / ``census-missing``."""
+    tol = WALL_SLOPE_TOLERANCE if tolerance is None else float(tolerance)
     ca = a.get("census") if isinstance(a.get("census"), dict) else None
     cb = b.get("census") if isinstance(b.get("census"), dict) else None
     if ca is None or cb is None:
@@ -642,8 +608,7 @@ def diff_census(a: dict, b: dict,
         "check": "anomod_census_diff",
         "tolerance": tol,
         "note": "byte counts are deterministic — every delta is real; "
-                "wall slopes regress only past 1 + tolerance "
-                "(ANOMOD_PERF_NOISE_FLOOR, docs/BENCHMARKS.md)",
+                "wall slopes regress only past 1 + tolerance",
         "planes": plane_rows,
         "bytes_regressions": bytes_regressions,
         "total_a": (ca.get("resident_bytes") or {}).get("total"),

@@ -177,8 +177,7 @@ def export_tt_csv(registry: Registry, path) -> int:
 
     The file round-trips through ``anomod.io.metrics.load_tt_metric_csv``
     — the framework's own loader — which is the self-scrape contract the
-    scorer (anomod.obs.selfscrape) and the committed bench capture rely
-    on."""
+    scorer (anomod.obs.selfscrape) relies on."""
     from anomod.io.metrics import write_metric_batch_tt_csv
     batch = to_metric_batch(registry)
     path = Path(path)

@@ -76,18 +76,16 @@ PLANES: Tuple[str, ...] = ("admission", "dispatch", "fold", "score", "rca")
 #: marks must never touch them), and the elastic policy's scaling
 #: events (what scaled up/down/rebalanced is likewise execution
 #: topology: an elastic run's canonical planes stay equal to a static
-#: run's), and the performance observatory's per-tick dispatch-
-#: lifecycle timeline (anomod.obs.perf — pure wall-clock event
-#: timestamps plus the overlap-headroom bound computed from them), and
+#: run's), and
 #: the fleet census observatory's resident-bytes/hot-set records
 #: (anomod.obs.census — deterministic and wall-free, but per-shard
 #: pool/scratch bytes follow the execution TOPOLOGY, so the key is
-#: variant like ``topology``; unlike ``walls``/``perf`` the census
+#: variant like ``topology``; unlike ``walls`` the census
 #: stream is byte-equal across same-seed reruns of one topology,
 #: pinned in tests/test_census.py) —
 #: the flight twin of the serving plane's
 #: SHARD_VARIANT_REPORT_FIELDS (one definition, shared by
-#: canonical_ticks, the parity tests and the pre-bench flight smoke).
+#: canonical_ticks and the parity tests).
 #: ``tiering`` (anomod.serve.tiering) joins the variant tier for one
 #: precise reason: demote/promote/miss events are wall-free functions
 #: of seed+config (byte-equal across same-config reruns, pinned in
@@ -100,7 +98,7 @@ PLANES: Tuple[str, ...] = ("admission", "dispatch", "fold", "score", "rca")
 #: consumer of the served batches, so the canonical planes are equal with
 #: the plane on or off; the key is variant because the plane is.
 FLIGHT_VARIANT_KEYS: Tuple[str, ...] = ("walls", "topology", "recovery",
-                                        "scaling", "perf", "census",
+                                        "scaling", "census",
                                         "tiering", "seq")
 
 

@@ -83,7 +83,7 @@ class ObsHttpServer:
 
     def registry(self) -> Registry:
         # resolved per request when constructed registry-less, so a
-        # set_registry() swap (the bench's per-leg idiom) is visible
+        # set_registry() swap is visible
         return self._registry if self._registry is not None \
             else get_registry()
 

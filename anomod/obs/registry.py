@@ -613,7 +613,7 @@ def get_registry() -> Registry:
 
 
 def set_registry(registry: Registry) -> Registry:
-    """Swap the process-wide registry (tests, the bench's off/on pair);
+    """Swap the process-wide registry (tests);
     returns the previous one so callers can restore it."""
     global _DEFAULT
     with _DEFAULT_LOCK:

@@ -5,10 +5,9 @@ hi/lo moment split, one-hot construction, histogram bucketing, and the MXU
 matmul — into one kernel whose [F+H, SW+1] accumulator stays VMEM-resident
 across the entire grid (state never round-trips to HBM between blocks).
 
-Builder-side capture of 2026-07-30 (before PR 1; 30.4M-span replicated TT
-corpus, block sweep 1024-8192 all within 3%): 3.0e8 spans/sec/chip vs
-2.5e8 for the XLA scan path.  PERF.md carries what the chip has said since
-PR 21 (``ANOMOD_BENCH_KERNEL`` selects the kernel).
+PERF.md carries what the chip has said about these kernels
+(``measure_throughput(kernel=)`` selects one; the benchmark's replay
+cell runs the sorted-window variant).
 
 Three structural fixes over the round-1 kernel (which measured 6.0e7
 spans/sec vs 1.1e8 for the XLA scan path):

@@ -1,12 +1,12 @@
-"""Machine-readable benchmark capture provenance.
+"""Machine-readable capture provenance.
 
-Every successful benchmark capture (driver `bench.py` run, quality sweep,
-kernel micro-bench) is written as one JSON file under ``bench_runs/`` so the
-headline numbers in ``docs/BENCHMARKS.md`` cite committed, re-checkable
+Every quality sweep (``anomod stream --all``, ``anomod quality``) and
+``tpu_tests`` session is written as one JSON file under ``bench_runs/`` so
+the accuracy tables in ``docs/QUALITY.md`` cite committed, re-checkable
 artifacts instead of prose: each record carries the measured value, the
-kernel, the *device string* (so an on-chip claim is distinguishable from a
-CPU run), jax/jaxlib versions, a UTC timestamp, and the git SHA of the
-tree that produced it.
+kernel, the *device string*, jax/jaxlib versions, a UTC timestamp, and the
+git SHA of the tree that produced it.  Speed is not recorded here: PERF.md
+and ``PERF_LEDGER.jsonl`` hold every statement about speed.
 
 Writes are best-effort: a benchmark must never fail because the repo is
 read-only or git is absent, so all failures degrade to returning ``None``.

@@ -36,7 +36,7 @@ from anomod.rca import (_apply_model, _stack, build_dataset,
 SEVERITIES = (1.0, 0.4, 0.2, 0.1, 0.05)
 
 #: The de-saturated operating point used by the regression floor test and
-#: docs/BENCHMARKS.md "hard regime" table: mild effects + decoys + noise.
+#: docs/QUALITY.md "hard regime" table: mild effects + decoys + noise.
 HARD_POINT = dict(severity=0.12, noise=0.5, n_confounders=2)
 
 

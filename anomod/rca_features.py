@@ -57,7 +57,7 @@ def windowed_features(batch, services, cfg: ReplayConfig,
     out-edge plane: a link fault (synth fault_locus="edge") is invisible
     in every node aggregate but lands exactly in the culprit's out-edge
     block — without it the models have no evidence channel for edge
-    faults at all (see docs/BENCHMARKS.md, generator-leak retraction)."""
+    faults at all (see docs/QUALITY.md, generator-leak retraction)."""
     svc_index = {s: i for i, s in enumerate(services)}
     remap = np.array([svc_index.get(s, 0) for s in batch.services] or [0],
                      np.int32)

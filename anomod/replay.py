@@ -63,8 +63,8 @@ def segment_ids(batch: SpanBatch, cfg: ReplayConfig,
                 t0_us: Optional[int] = None) -> np.ndarray:
     """[n] int32 (service, window) segment id per span — the ONE definition
     of the replay's segment binning, shared by :func:`stage_columns` and
-    lightweight consumers (e.g. bench.py's f32-exactness replicate clamp)
-    that need segment occupancy without paying the full staging pass."""
+    lightweight consumers that need segment occupancy without paying the
+    full staging pass."""
     n = batch.n_spans
     t0 = int(batch.start_us.min()) if t0_us is None and n else (t0_us or 0)
     window = np.minimum((batch.start_us - t0) // cfg.window_us,
@@ -511,8 +511,7 @@ class TenantStatePool:
       op is an in-place vectorized numpy update, with the lane deltas
       read through the CPU backend's zero-copy ``np.asarray`` view (no
       readback copy, no XLA dispatch).  Same pool architecture, same
-      adds; the engine choice is measured in
-      ``scripts/bench_fold_sweep.py``.
+      adds.
 
     Bit-parity contract (pinned in tests/test_serve_state.py, both
     engines): every pool operation performs the SAME IEEE f32
@@ -784,9 +783,8 @@ class TenantStatePool:
             # are ready) — the fold reads the deltas in place, with no
             # readback copy and no fresh state allocations: one slice
             # += when the slots are a contiguous run, else per-row
-            # in-place adds (measured in bench_fold_sweep.py — a
-            # fancy-index += triggers numpy's gather/add/scatter
-            # temporaries and loses to both)
+            # in-place adds (a fancy-index += triggers numpy's
+            # gather/add/scatter temporaries and loses to both)
             wa, wh = self._wa, self._wh
             da = np.asarray(dagg).reshape(L, wa)
             dh = np.asarray(dhist).reshape(L, wh)

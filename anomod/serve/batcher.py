@@ -54,9 +54,10 @@ through the C++ ``stage_lanes`` entry (anomod.io.native) with the GIL
 RELEASED — byte-identical to the interpreter fill (pinned), but staging
 for scratch slot k+1 overlaps the in-flight dispatch on slot k, and
 shard workers stage concurrently instead of convoying on the GIL.  The
-per-dispatch stage/dispatch/fold walls are accounted separately (the
-bench ``staging`` block / ``anomod_serve_{stage,dispatch,fold}_seconds_
-total``), so the serving-overhead decomposition is measured, not prose.
+per-dispatch stage/dispatch/fold walls are accounted separately
+(``anomod_serve_{stage,dispatch,fold}_seconds_total``; the benchmark's
+``dispatch_ms`` and ``fold_wait_ms`` read them), so the serving-overhead
+decomposition is measured, not prose.
 
 :class:`BucketedStreamReplay` duck-types :class:`anomod.stream.StreamReplay`
 (it subclasses it and overrides only the dispatch), so
@@ -131,7 +132,7 @@ class BucketRunner:
                  lane_engine: Optional[str] = None,
                  state: Optional[str] = None,
                  pool_slots: int = 32,
-                 perf=None, tracer=None):
+                 tracer=None):
         from anomod.config import get_config
         if buckets is None:
             buckets = get_config().serve_buckets
@@ -165,23 +166,14 @@ class BucketRunner:
             if self.state_mode == "device" else None)
         #: GIL-free native scratch packing (anomod.io.native.stage_lanes):
         #: resolved from the validated ANOMOD_NATIVE knob (auto/on/off)
-        #: unless the caller overrides — the bench's python-staging
-        #: reference leg passes False; byte-identical either way
+        #: unless the caller overrides (a python-staging reference run
+        #: passes False); byte-identical either way
         self.native_stage = native_io.staging_enabled(native_stage)
         #: metric sink: the sharded engine hands each shard's runner its
         #: OWN registry (thread-isolated hot path; merged into the
         #: process registry at the tick barrier) — default is the
         #: process registry, exactly as before
         self._reg = registry if registry is not None else obs.get_registry()
-        #: dispatch-lifecycle event sink (anomod.obs.perf.PerfRecorder,
-        #: the performance observatory's read-side seam) — None (the
-        #: default) records nothing; when set, the fused submit/retire
-        #: path stamps staged/submitted/materialized/folded/refill
-        #: events REUSING the wall-leg clock reads below, so the
-        #: timeline reconciles with the five-leg walls to float
-        #: rounding and recording costs no extra perf_counter call on
-        #: the already-timed points
-        self.perf = perf
         #: the engine's tracer (``span(name, **tags)``), or None: one
         #: span per lane fill, lane dispatch and fold retire — per
         #: dispatch, never per batch or per tenant
@@ -226,9 +218,8 @@ class BucketRunner:
         self.dispatches_by_width: Dict[int, int] = {}
         self.n_dispatches = 0
         self.fused_dispatches = 0
-        #: the serve tick's wall decomposition (the numbers behind the
-        #: bench ``staging`` block): host packing (stage_plan + scratch
-        #: fill), dispatch issue (the executable call — an ENQUEUE wall
+        #: the serve tick's wall decomposition: host packing (stage_plan
+        #: + scratch fill), dispatch issue (the executable call — an ENQUEUE wall
         #: on async backends), and fold (output materialization — the
         #: execute barrier — plus the per-lane state adds).  What the
         #: serve wall spends OUTSIDE these three is admission/detector/
@@ -347,8 +338,7 @@ class BucketRunner:
     def warm_lanes(self) -> float:
         """Compile the full (width x lane-bucket) fused-dispatch grid on
         all-dead lane stacks, so a fused serve never pays a compile wall
-        mid-stream.  Returns the total compile wall; idempotent.  The
-        serve pre-bench gate drives this and fails on any shape miss."""
+        mid-stream.  Returns the total compile wall; idempotent."""
         total = 0.0
         for width in self.widths:
             dead = self._dead_cols_for(width)
@@ -555,10 +545,6 @@ class BucketRunner:
                 if self.native_stage:
                     self._stage_plans[key] = native_io.make_stage_plan(
                         scratch, self._pad_fill, mat_keys=STAGE_KEYS)
-            elif self.perf is not None:
-                # an existing scratch slot is being REUSED: stamp the
-                # slot-refilled event on the dispatch that last held it
-                self.perf.note_refill(key, t0)
             plan = self._stage_plans.get(key)
             if plan is not None and plan.stage(group_cols):
                 self.native_staged += 1
@@ -568,8 +554,6 @@ class BucketRunner:
             dt = time.perf_counter() - t0
             self.stage_wall_s += dt
             self._obs_stage_s.inc(dt)
-            if self.perf is not None:
-                self.perf.note_staged(key, t0, t0 + dt)
         return scratch, key
 
     def _fill_slot_py(self, scratch: dict, group_cols: List[dict],
@@ -621,18 +605,14 @@ class BucketRunner:
         for n_live, lanes in self.lane_plan(len(work)):
             group = work[pos:pos + n_live]
             pos += n_live
-            scratch, key = self._fill_slot(width, lanes,
-                                           [cols for _, cols in group])
+            scratch, _ = self._fill_slot(width, lanes,
+                                         [cols for _, cols in group])
             exe = self._lane_exec_for((width, lanes), scratch)
-            prf = self.perf
             with span_of(self.tracer, "serve.lane_dispatch", width=width,
                          lanes=lanes):
                 t0 = time.perf_counter()
                 dagg, dhist = exe(scratch)
                 t1 = time.perf_counter()
-            if prf is not None:
-                prf.note_submitted(key, t0, t1)
-                prf.note_retire(key, t1)
             with span_of(self.tracer, "serve.fold_retire", lanes=lanes,
                          device=False):
                 # materialize before the scratch is reused: the host
@@ -640,14 +620,9 @@ class BucketRunner:
                 # below reads it
                 dagg = np.asarray(dagg)
                 dhist = np.asarray(dhist)
-                if prf is not None:
-                    t_mat = time.perf_counter()
-                    prf.note_materialized(key, t_mat)
                 for i, (st, _) in enumerate(group):
                     out.append(fold_delta(st, dagg[i], dhist[i]))
                 t2 = time.perf_counter()
-            if prf is not None:
-                prf.note_folded(key, t2)
             self.dispatch_wall_s += t1 - t0
             self._obs_dispatch_s.inc(t1 - t0)
             self.fold_wall_s += t2 - t1
@@ -685,8 +660,6 @@ class BucketRunner:
                 dt = time.perf_counter() - t0
             self.dispatch_wall_s += dt
             self._obs_dispatch_s.inc(dt)
-            if self.perf is not None:
-                self.perf.note_submitted(key, t0, t0 + dt)
             self._inflight.append(
                 ([replay for replay, _ in group], dagg, dhist, key))
             self._account_group(n_live, lanes)
@@ -713,10 +686,7 @@ class BucketRunner:
         lane through the get_state/set_state seam — the same
         elementwise f32 add the in-step update performs."""
         replays, dagg, dhist, key = self._inflight.popleft()
-        prf = self.perf
         t0 = time.perf_counter()
-        if prf is not None:
-            prf.note_retire(key, t0)
         pool = self.pool
         on_device = bool(pool is not None and replays and all(
             getattr(r, "_slot", None) is not None
@@ -727,40 +697,20 @@ class BucketRunner:
             if on_device:
                 pool.scatter_fold([r._slot for r in replays], dagg, dhist)
                 dagg.block_until_ready()       # scratch-reuse barrier
-                if prf is not None:
-                    t_wait = time.perf_counter() - t0
-                    prf.note_materialized(key, t0 + t_wait)
             else:
                 dagg = np.asarray(dagg)
                 dhist = np.asarray(dhist)
-                if prf is not None:
-                    t_wait = time.perf_counter() - t0
-                    prf.note_materialized(key, t0 + t_wait)
                 for i, replay in enumerate(replays):
                     replay.set_state(fold_delta(replay.get_state(),
                                                 dagg[i], dhist[i]))
         dt = time.perf_counter() - t0
         self.fold_wall_s += dt
         self._obs_fold_s.inc(dt)
-        if prf is not None:
-            prf.note_folded(key, t0 + dt)
 
     def drain_lanes(self) -> None:
         """Retire every in-flight dispatch (tick-end barrier)."""
         while self._inflight:
             self._retire_one()
-
-    def mark_deferred(self, t0: float, t1: float) -> None:
-        """Stamp every in-flight dispatch's ``deferred`` lifecycle leg
-        (anomod.obs.perf): issued at ``t0``, left executing under the
-        coordinator's next-tick work until the commit barrier read it
-        at ``t1`` — the deferred-commit engine calls this at the
-        barrier, before :meth:`drain_lanes`, so `anomod perf diff`
-        can attribute the hidden wait to the ``commit_defer`` leg."""
-        if self.perf is None:
-            return
-        for _, _, _, key in self._inflight:
-            self.perf.note_deferred(key, t0, t1)
 
     def abort_lanes(self) -> None:
         """Failed-tick cleanup: discard every in-flight dispatch WITHOUT
@@ -770,13 +720,9 @@ class BucketRunner:
         planes keep their last-folded states instead of silently
         absorbing an aborted tick's work on some later drain."""
         while self._inflight:
-            _, dagg, dhist, key = self._inflight.popleft()
+            _, dagg, dhist, _ = self._inflight.popleft()
             np.asarray(dagg)
             np.asarray(dhist)
-            if self.perf is not None:
-                # dropped, counted — an aborted dispatch must not
-                # complete its timeline as if it folded
-                self.perf.note_aborted(key)
 
     @property
     def inflight_dispatches(self) -> int:

@@ -7,7 +7,7 @@ source, and every admission/shedding/serving decision is pure
 bookkeeping — a seeded overload replay is bit-reproducible, and the
 whole engine unit-tests without a single wall sleep.  Wall time is
 measured (never waited on) around the serving path only, for the
-sustained spans/sec number the bench reports.
+sustained spans/sec number the report carries.
 
 Each tenant runs the UNCHANGED detector stack: an
 ``anomod.stream.OnlineDetector`` whose replay plane is a
@@ -46,7 +46,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from anomod import obs
-from anomod.obs.perf import bubble_fractions as _perf_bubbles
 from anomod.ops.tdigest import (TDigest, tdigest_build, tdigest_merge_many,
                                 tdigest_quantile)
 from anomod.replay import N_FEATS, ReplayConfig
@@ -151,9 +150,8 @@ def _merged_quantiles(slos: Sequence[_TenantSLO],
 #: pipeline depths on the same seed: wall-clock measurements and lane
 #: GROUPING topology (which lanes share a fused stack depends on shard
 #: membership; the resulting per-lane bits do not).  The ONE definition
-#: of the shard-determinism contract's exclusion list — shared by the
-#: parity tests (tests/test_serve.py) and the pre-bench fan-out smoke
-#: (scripts/pre_bench_check.py), so the two pins cannot drift apart.
+#: of the shard-determinism contract's exclusion list, read by the
+#: parity tests (tests/test_serve.py).
 #: ``rca_latency``/``rca_wall_s`` are wall measurements of the RCA runs;
 #: the verdict STREAM itself (and every other rca_* field) is pinned
 #: identical across shard counts.
@@ -173,13 +171,6 @@ SHARD_VARIANT_REPORT_FIELDS = (
     # execution strategy (a policy-off run's peak IS its shard count),
     # and the policy/migration wall is a wall measurement
     "peak_shards", "policy_wall_s",
-    # the performance observatory (anomod.obs.perf): lifecycle-event
-    # counts follow the fused-dispatch grouping topology, and the
-    # fold-wait / overlap-headroom / bubble numbers are wall-clock
-    # measurements — consciously VARIANT, never the parity surface
-    # (perf_enabled, the config bit, stays canonical)
-    "perf_events_recorded", "overlap_headroom_s", "fold_wait_s",
-    "bubble_fractions",
     # the deferred-commit seam (ANOMOD_SERVE_ASYNC_COMMIT): how long
     # dispatches were left executing under coordinator work is a wall
     # measurement — consciously VARIANT (async_commit, the config bit,
@@ -352,16 +343,6 @@ class ServeReport:
     flight_recorded_ticks: int                   # journal records written
     flight_dropped_ticks: int                    # ring evictions (0 = no
     #                                              loss; never silent)
-    perf_enabled: bool                           # dispatch-lifecycle
-    #                                              timeline on?
-    perf_events_recorded: int                    # lifecycle events taken
-    overlap_headroom_s: float                    # fold WAIT legally
-    #                                              hideable under next-
-    #                                              round staging (upper
-    #                                              bound; anomod.obs.perf)
-    fold_wait_s: float                           # measured execute WAIT
-    #                                              inside the fold leg
-    bubble_fractions: Dict[str, float]           # per-leg dead-time shares
     census_enabled: bool                         # fleet census on?
     census_ticks: int                            # census drains taken
     census_hot_set: Dict[str, object]            # hot-set/Zipf census
@@ -423,12 +404,9 @@ class ServeReport:
 
 def serve_plane_cfg(n_services: int = 12, window_s: float = 5.0,
                     n_windows: int = 32) -> ReplayConfig:
-    """The serve bench's replay-plane shape — ONE definition shared by
-    ``run_power_law`` (and thus ``bench.py --mode serve``, whose
-    serve_main passes these defaults) and the pre-bench serve gate
-    (``scripts/pre_bench_check.py --mode serve``), so the gate's
-    "bucket set compiles" check always covers the plane the capture
-    actually runs."""
+    """The serve plane's replay-plane shape — ONE definition shared by
+    ``run_power_law``, the live feed (``serve/feed.py``) and the
+    benchmark's fleet configuration (``benchmark/configs/tt-fleet.json``)."""
     return ReplayConfig(n_services=n_services, n_windows=n_windows,
                         window_us=int(window_s * 1e6), chunk_size=4096)
 
@@ -453,7 +431,6 @@ def run_power_law(n_tenants: int = 200, n_services: int = 8,
                   flight: Optional[bool] = None,
                   flight_digest_every: Optional[int] = None,
                   flight_max_ticks: Optional[int] = None,
-                  perf: Optional[bool] = None,
                   census: Optional[bool] = None,
                   census_every: Optional[int] = None,
                   chaos: Optional[str] = None,
@@ -480,7 +457,7 @@ def run_power_law(n_tenants: int = 200, n_services: int = 8,
                   seq_model=None
                   ) -> Tuple["ServeEngine", ServeReport]:
     """The canonical seeded serve run shared by ``anomod serve`` and
-    ``bench.py --mode serve``: a power-law tenant fleet offering
+    ``chip_smoke.py``: a power-law tenant fleet offering
     ``overload``× the engine's capacity, with ``fault_tenants`` busiest
     tenants given a scripted latency fault once calibration is past —
     so one invocation measures sustained throughput, shed behavior AND
@@ -510,7 +487,7 @@ def run_power_law(n_tenants: int = 200, n_services: int = 8,
                          state=state, flight=flight,
                          flight_digest_every=flight_digest_every,
                          flight_max_ticks=flight_max_ticks,
-                         perf=perf, census=census,
+                         census=census,
                          census_every=census_every,
                          chaos=chaos, ckpt_every=ckpt_every,
                          retries=retries,
@@ -555,10 +532,6 @@ def run_power_law(n_tenants: int = 200, n_services: int = 8,
             state=engine.serve_state, flight=True,
             flight_digest_every=engine.flight_recorder.digest_every,
             flight_max_ticks=engine.flight_recorder.max_ticks,
-            # the perf plane, RESOLVED: a replay of a perf-on run
-            # re-records its timeline (variant tier — the canonical
-            # journal is identical either way, the read-side pin)
-            perf=engine.perf,
             # the census plane, RESOLVED: a replay of a census-on run
             # re-takes the same deterministic census (the `census`
             # variant stream of a replay is byte-equal to the
@@ -653,7 +626,6 @@ class ServeEngine:
                  flight: Optional[bool] = None,
                  flight_digest_every: Optional[int] = None,
                  flight_max_ticks: Optional[int] = None,
-                 perf: Optional[bool] = None,
                  census: Optional[bool] = None,
                  census_every: Optional[int] = None,
                  chaos: Optional[object] = None,
@@ -764,8 +736,7 @@ class ServeEngine:
         #: ticks whose commit actually deferred past issue
         self.async_ticks = 0
         #: wall spent with dispatches left executing under coordinator
-        #: work before their barrier first read them — the hidden wait
-        #: `anomod perf diff` attributes to the ``commit_defer`` leg
+        #: work before their barrier first read them
         self.commit_defer_wall_s = 0.0
         #: elastic scaling policy (ANOMOD_SERVE_POLICY, anomod.serve.
         #: policy): "off" (the default) is the static engine; "auto"/
@@ -826,7 +797,6 @@ class ServeEngine:
                     "(ANOMOD_SERVE_POLICY_MIN/MAX_SHARDS)")
         self.policy_wall_s = 0.0
         #: spans resident in the replay states policy migrations moved
-        #: (the bench elasticity block's "migration spans" volume)
         self.policy_migrated_spans = 0
         self._peak_shards = self.shards
         self._policy_events: List[dict] = []
@@ -921,41 +891,6 @@ class ServeEngine:
         _buckets = (buckets if buckets is not None
                     else app_cfg.serve_buckets)
         self._proc_registry = obs.get_registry()
-        #: the performance observatory (ANOMOD_PERF, anomod.obs.perf):
-        #: per-shard dispatch-lifecycle recorders ride the runners'
-        #: fused submit/retire path (staged / submitted / materialized
-        #: / folded / slot-refilled event timestamps), drain at the
-        #: tick barrier in shard order (the fold_verdicts idiom), feed
-        #: the overlap-bubble analyzer, and ride the flight journal's
-        #: ``perf`` VARIANT key.  A pure read-side consumer: every
-        #: decision is byte-identical with recording on or off
-        #: (pinned).  The mesh plane manages its own dispatch, so the
-        #: timeline records nothing there (the runner path is idle).
-        self.perf = bool(app_cfg.perf if perf is None else perf)
-        self.perf_max_events = int(app_cfg.perf_max_events)
-        self.perf_events: list = []      # retained timeline (bounded)
-        self.perf_events_recorded = 0
-        self.perf_events_dropped = 0
-        self.perf_headroom_s = 0.0
-        self.perf_wait_s = 0.0
-        self._perf_pending: list = []    # drains of retired runners
-        self._perf_tick_doc: Optional[dict] = None
-        self._perf_recs: list = []
-        if self.perf:
-            from anomod.obs.perf import PerfRecorder
-            self._perf_recs = [PerfRecorder(s)
-                               for s in range(self.shards)]
-            # metric handles only when the plane is live (the RCA
-            # discipline: a perf-off run must not register permanently-
-            # zero series)
-            self._obs_perf_events = obs.counter(
-                "anomod_perf_events_total")
-            self._obs_perf_dropped = obs.counter(
-                "anomod_perf_dropped_events_total")
-            self._obs_fold_wait = obs.counter(
-                "anomod_serve_fold_wait_seconds_total")
-            self._obs_headroom = obs.counter(
-                "anomod_serve_overlap_headroom_seconds_total")
         #: the fleet census observatory (ANOMOD_CENSUS, anomod.obs.
         #: census): every ANOMOD_CENSUS_EVERY-th tick (and always at
         #: run end) the coordinator takes a deterministic resident-
@@ -990,7 +925,7 @@ class ServeEngine:
                 app_cfg.census_decay_ticks,
                 app_cfg.census_coldest_k, self.census_every)
         if self.census:
-            # metric handles only when the plane is live (the RCA/perf
+            # metric handles only when the plane is live (the RCA
             # discipline: a census-off run must not register
             # permanently-zero series)
             self._obs_census = {
@@ -1025,7 +960,7 @@ class ServeEngine:
         #: share coordinator memory with the score plane cannot cross
         #: the process boundary — the mesh plane, the multimodal
         #: sidecar, the deferred-commit seam, state tiering's demotion
-        #: copier and the perf/census observatories — so process mode
+        #: copier and the census observatory — so process mode
         #: auto-degrades to thread under any of them (an explicit
         #: request is refused): the policy/state idiom.
         _worker = (app_cfg.serve_worker if worker is None
@@ -1049,8 +984,6 @@ class ServeEngine:
                 "one interpreter" if self.async_commit else
                 "state tiering's demotion copier reads the pool "
                 "in-process" if self.tier_hot else
-                "the perf observatory rides the runners in-process"
-                if self.perf else
                 "the census walks resident planes in-process"
                 if self.census else None)
             if blocker is not None:
@@ -1076,7 +1009,7 @@ class ServeEngine:
             self._use_workers = True
         #: tick-barrier fold discipline (ANOMOD_SERVE_FOLD): per-tick
         #: cross-shard merges (registry counter/gauge deltas, t-digest
-        #: centroid sets, leg/perf/verdict records) serialize as
+        #: centroid sets, leg/verdict records) serialize as
         #: "sparse" touched-key deltas (the default — barrier cost
         #: follows ACTIVE tenants, not registered fleet size) or
         #: "dense" full walks (the payload oracle the sparse win is
@@ -1155,8 +1088,6 @@ class ServeEngine:
                                                     self.tier_hot)
                                                 if self.tier_hot
                                                 else owned[s], 1),
-                                 perf=(self._perf_recs[s] if self.perf
-                                       else None),
                                  **self._runner_kw)
                     for s, reg in enumerate(self._shard_regs)]
             self._fold_state = [dict() for _ in range(self.shards)]
@@ -1177,8 +1108,6 @@ class ServeEngine:
                                                self.tier_hot)
                                            if self.tier_hot
                                            else len(self.specs), 1),
-                                       perf=(self._perf_recs[0]
-                                             if self.perf else None),
                                        tracer=tracer)
             self._runners = [self.runner]
         self._workers = None
@@ -1278,9 +1207,8 @@ class ServeEngine:
         self._max_served_batch = 0
         self.serve_wall_s = 0.0
         #: per-tick serve-wall samples (one float per tick, bounded by
-        #: the run's tick count) — the ``raw_wall_s`` sample list the
-        #: bench ``perf`` block commits and `anomod perf diff`
-        #: bootstraps over; wall clock, never a decision input
+        #: the run's tick count) — what the census sweep's wall slope
+        #: reads (obs/census.py); wall clock, never a decision input
         self.tick_walls: List[float] = []
         self.n_spans_served = 0
         # self-scrape plumbing (anomod.obs): cached handles for the tick
@@ -1329,7 +1257,6 @@ class ServeEngine:
                     "multimodal": self.multimodal,
                     "policy": (self.policy.mode
                                if self.policy is not None else "off"),
-                    "perf": self.perf,
                     "census": self.census,
                     "async_commit": self.async_commit,
                     "tier_hot": self.tier_hot,
@@ -1690,7 +1617,7 @@ class ServeEngine:
         # gate) — a counted, journaled `tier_miss`, never a blocking
         # read in the hot loop.  Only the SCORING list is re-shaped:
         # `served` keeps feeding every admission-time consumer below
-        # (SLO, RCA evidence, perf, census, flight, policy), and
+        # (SLO, RCA evidence, census, flight, policy), and
         # parked batches score ahead of the next tick's drain in park
         # order, so per-tenant push order — and therefore every final
         # state/alert byte — matches the never-evicted run.
@@ -1701,12 +1628,6 @@ class ServeEngine:
             self.tier_wall_s += time.perf_counter() - t0
         else:
             score_list = served
-        if self._perf_recs:
-            # tick-boundary stamp (the workers are quiescent between
-            # ticks, so this cross-thread write races nothing): events
-            # the dispatch path records below key on this tick index
-            for rec_ in self._perf_recs:
-                rec_.tick = self.clock.ticks
         if score_list:
             sup = self._supervisor
             if sup is not None:
@@ -1759,21 +1680,13 @@ class ServeEngine:
         if self._seq is not None:
             self._seq.step(served)
         with self._span("serve.recorders"):
-            # the perf-timeline drain rides INSIDE the measured wall (the
-            # bench perf block prices the recorder, never hides it); it
-            # runs after the score barrier, so every dispatch of this
-            # tick has folded and its record is complete
-            self._perf_tick_doc = self._perf_drain() if self.perf else None
             if self._census_tracker is not None:
                 # hot-set bookkeeping every tick (O(served)); the full
                 # resident-bytes census drains on its cadence, INSIDE
-                # the measured wall (the bench census block prices it,
-                # never hides it) and after the perf drain so the
-                # recorder retentions it counts are this tick's.  The
-                # census wall accumulates separately so the bench prices
-                # the overhead IN-RUN (census_wall_s / serve_wall_s —
-                # the ckpt_wall idiom: exact, immune to this box's A/B
-                # leg noise).
+                # the measured wall.  The census wall accumulates
+                # separately so the overhead is priced IN-RUN
+                # (census_wall_s / serve_wall_s — the ckpt_wall idiom:
+                # exact, immune to A/B leg noise).
                 t0 = time.perf_counter()
                 self._census_tracker.observe(self.clock.ticks, served)
                 self._census_tick_doc = (
@@ -1790,8 +1703,8 @@ class ServeEngine:
                     self.tier_wall_s += time.perf_counter() - t0
             if self.flight_recorder is not None:
                 # the journal entry rides INSIDE the measured wall (the
-                # serve_wall_s accumulation below) — the bench's flight
-                # overhead leg prices the recorder, never hides it
+                # serve_wall_s accumulation below): the recorder's cost
+                # is priced, never hidden
                 self._flight_tick(now, served,
                                   time.perf_counter() - t_wall)
         if self.policy is not None:
@@ -1799,8 +1712,8 @@ class ServeEngine:
             # record (a scale-down must not remove a runner whose
             # tick-t dispatch deltas have not been journaled yet); its
             # events ride the NEXT record's `scaling` variant key, and
-            # its wall lands inside the measured tick wall — the bench
-            # elasticity block prices scaling, never hides it
+            # its wall lands inside the measured tick wall: scaling is
+            # priced, never hidden
             t0 = time.perf_counter()
             with self._span("serve.policy"):
                 self._policy_step(served)
@@ -1817,9 +1730,8 @@ class ServeEngine:
                 self._tier_demote_step()
             self.tier_wall_s += time.perf_counter() - t0
         self.clock.advance()
-        # telemetry work stays INSIDE the measured wall: the bench's
-        # enabled-vs-off overhead number must price the scrape, not
-        # hide it
+        # telemetry work stays INSIDE the measured wall: the scrape is
+        # priced, not hidden
         with self._span("serve.scrape"):
             self._obs_tick.observe(time.perf_counter() - t_wall)
             self._obs_ticks.inc()
@@ -1851,7 +1763,7 @@ class ServeEngine:
            tick's admission/drain/shed/SLO coordinator phases; its
            results are about to be read (folds feed this tick's
            staging), so it commits now, then runs the deferred tick's
-           tail (RCA, perf/census drains, flight record, policy)
+           tail (RCA, census drain, flight record, policy)
            against snapshotted inputs.
         3. ISSUE: this tick's fused dispatches stage + submit but do
            NOT drain (``defer=True``); the XLA executes stay in
@@ -1875,11 +1787,6 @@ class ServeEngine:
         with self._span("serve.slo"):
             self._slo_record(now, served)
         self._commit_deferred()
-        if self._perf_recs:
-            # tick-boundary stamp, POST-barrier: the workers are
-            # quiescent only after the deferred commit has joined
-            for rec_ in self._perf_recs:
-                rec_.tick = self.clock.ticks
         pending = None
         sup = self._supervisor
         if served:
@@ -1945,11 +1852,11 @@ class ServeEngine:
     def _commit_deferred(self) -> None:
         """The deferred tick's COMMIT BARRIER (no-op when nothing is
         deferred): drain the in-flight fold/score/commit phases, then
-        run the deferred tick's tail — RCA, perf/census drains, flight
+        run the deferred tick's tail — RCA, census drain, flight
         record, elastic policy — against the exact state, and the
         exact snapshotted inputs, the synchronous engine used at that
         tick.  The tail order mirrors the synchronous tick body
-        (RCA → perf → census → flight → policy) line for line.  Chaos
+        (RCA → census → flight → policy) line for line.  Chaos
         hooks key on the ORIGIN tick, so scripted fold/score/commit
         faults fire — and recover, via the supervisor's origin-keyed
         retry ledger — exactly as scripted even though they surface
@@ -1965,12 +1872,9 @@ class ServeEngine:
         if pending is not None and any(pending):
             # the hidden-wait leg: how long the dispatches were left
             # executing under coordinator work before this barrier
-            # first read them (`anomod perf diff`'s commit_defer leg)
+            # first read them
             self.commit_defer_wall_s += max(0.0,
                                             t_barrier - d["t_issue"])
-            if self.perf:
-                for r in self._runners:
-                    r.mark_deferred(d["t_issue"], t_barrier)
             sup = self._supervisor
             self._last_failures = None
             try:
@@ -1989,7 +1893,6 @@ class ServeEngine:
         if self.rca:
             self._rca_step(now, served)
         with self._span("serve.recorders"):
-            self._perf_tick_doc = self._perf_drain() if self.perf else None
             if self._census_tracker is not None:
                 t0 = time.perf_counter()
                 self._census_tracker.observe(d["tick"], served)
@@ -2304,44 +2207,6 @@ class ServeEngine:
             runner.score_wall_s += dt
             runner._obs_score_s.inc(dt)
 
-    # -- the performance observatory (anomod.obs.perf) --------------------
-
-    def _perf_drain(self) -> dict:
-        """Tick-barrier drain of the per-shard dispatch-lifecycle
-        recorders: fold in (shard, seq) order, run the overlap-bubble
-        analyzer, accumulate the run totals, retain the events
-        (bounded — evictions counted, never silent) and return the
-        journal-shaped doc the flight record's ``perf`` variant key
-        carries — or None when no flight recorder will consume it
-        (the rounded event copies would be pure dead allocation inside
-        the measured wall)."""
-        from anomod.obs.perf import (analyze_events, fold_perf_records,
-                                     round_events)
-        parts = [self._perf_pending] \
-            + [r.drain() for r in self._perf_recs]
-        self._perf_pending = []
-        events = fold_perf_records(parts)
-        stats = analyze_events(events, self.pipeline)
-        n = len(events)
-        self.perf_events_recorded += n
-        self.perf_headroom_s += stats["headroom_s"]
-        self.perf_wait_s += stats["wait_s"]
-        if n:
-            self._obs_perf_events.inc(n)
-            self._obs_fold_wait.inc(stats["wait_s"])
-            self._obs_headroom.inc(stats["headroom_s"])
-        self.perf_events.extend(events)
-        over = len(self.perf_events) - self.perf_max_events
-        if over > 0:
-            del self.perf_events[:over]
-            self.perf_events_dropped += over
-            self._obs_perf_dropped.inc(over)
-        if self.flight_recorder is None:
-            return None
-        return {"events": round_events(events),
-                "headroom_s": round(stats["headroom_s"], 6),
-                "wait_s": round(stats["wait_s"], 6)}
-
     # -- the fleet census observatory (anomod.obs.census) -----------------
 
     def _census_drain(self, t_idx: Optional[int] = None) -> dict:
@@ -2375,8 +2240,7 @@ class ServeEngine:
         g["total"].set(total)
         for plane in ("pool", "scratch", "admission", "slo", "rca"):
             g[plane].set(by_plane.get(plane, 0))
-        g["recorder"].set(by_plane.get("flight", 0)
-                          + by_plane.get("perf", 0))
+        g["recorder"].set(by_plane.get("flight", 0))
         g["registered"].set(len(self.specs))
         g["resident"].set(hot["resident"])
         g["hot"].set(hot["hot_by_decay"].get(
@@ -2581,18 +2445,10 @@ class ServeEngine:
         # (usually empty), the recovery-key contract.
         scaling, self._policy_events = self._policy_events, []
         rec["scaling"] = scaling
-        # the performance observatory's tick timeline rides the VARIANT
-        # tier too (the "perf" key in FLIGHT_VARIANT_KEYS): pure
-        # wall-clock event timestamps + the overlap-headroom bound —
-        # never the parity surface.  ALWAYS present (empty when the
-        # plane is off) — the every-record-carries-every-tier contract.
-        perf_doc, self._perf_tick_doc = self._perf_tick_doc, None
-        rec["perf"] = perf_doc if perf_doc is not None else \
-            {"events": [], "headroom_s": 0.0, "wait_s": 0.0}
         # the fleet census rides the VARIANT tier too (the "census"
         # key in FLIGHT_VARIANT_KEYS): per-shard pool/scratch bytes
         # follow the execution topology, so the key is excluded from
-        # the canonical surface — but unlike walls/perf its content is
+        # the canonical surface — but unlike walls its content is
         # wall-free, so the census stream is byte-equal across
         # same-seed reruns of one topology (pinned).  ALWAYS present
         # (empty off-cadence or with the census off) — the
@@ -3220,8 +3076,7 @@ class ServeEngine:
         if self.worker_mode == "process":
             # the new shard's runner lives in its child; the
             # coordinator grows a mirror cloned from shard 0's
-            # resolved static facts (perf/RCA planes never branch:
-            # perf is refused in process mode and RCA keeps its one
+            # resolved static facts (RCA keeps its one
             # coordinator-resident plane)
             from anomod.serve.procshard import RunnerMirror
             m0 = self._runners[0]
@@ -3234,22 +3089,16 @@ class ServeEngine:
                 w = self._make_worker(s)
                 self._workers.append(w)
                 # warm the new child's compile grid inside the measured
-                # tick wall (scaling is real work the bench elasticity
-                # block prices), off the coordinator thread
+                # tick wall (scaling is real work), off the coordinator
+                # thread
                 rep = w.call({"op": "warm"})
                 self._apply_shard_reply(s, rep)
             for tid in moved:
                 self._move_tenant(tid, s)
             return moved
         reg = obs.Registry(enabled=self._proc_registry.enabled)
-        prec = None
-        if self.perf:
-            from anomod.obs.perf import PerfRecorder
-            prec = PerfRecorder(s)
-            prec.tick = self.clock.ticks
-            self._perf_recs.append(prec)
         runner = BucketRunner(self.cfg, self._buckets_arg, registry=reg,
-                              pool_slots=max(len(moved), 1), perf=prec,
+                              pool_slots=max(len(moved), 1),
                               **self._runner_kw)
         self._shard_regs.append(reg)
         self._runners.append(runner)
@@ -3265,8 +3114,8 @@ class ServeEngine:
         if self._workers is not None:
             self._workers.append(self._make_worker(s))
             # warm the new runner's compile grid on its own worker —
-            # inside the measured tick wall (scaling is real work the
-            # bench elasticity block prices), off the serving threads
+            # inside the measured tick wall (scaling is real work), off
+            # the serving threads
             self._workers[s].submit(partial(self._warm_shard, s))
             self._workers[s].join()
         else:
@@ -3319,11 +3168,6 @@ class ServeEngine:
                                           self._fold_state[s],
                                           shard=str(s), final=True)
         self._retired_runners.append(_runner_stats(self._runners[s]))
-        if self.perf and len(self._perf_recs) > s:
-            # the victim's undrained lifecycle events fold into the
-            # next tick's drain (the retained-book discipline: the
-            # timeline covers the whole run, not the final topology)
-            self._perf_pending.extend(self._perf_recs.pop().drain())
         self._runners.pop()
         if self._shard_regs:                  # empty in process mode
             self._shard_regs.pop()
@@ -3603,10 +3447,6 @@ class ServeEngine:
                 self._rca_tick(self.clock.now_s,
                                budget=len(self._rca_queue))
         self.serve_wall_s += time.perf_counter() - t_wall
-        if self.perf:
-            # settle any lifecycle events the final drain window left
-            # (and feed the settlement record's perf key below)
-            self._perf_tick_doc = self._perf_drain()
         if self.census and self._census_tracker is not None:
             # run-end settlement census (the forced-digest idiom):
             # every census-on run ends on a full resident-bytes +
@@ -3957,13 +3797,6 @@ class ServeEngine:
             flight_dropped_ticks=(self.flight_recorder.n_dropped
                                   if self.flight_recorder is not None
                                   else 0),
-            perf_enabled=self.perf,
-            perf_events_recorded=self.perf_events_recorded,
-            overlap_headroom_s=round(self.perf_headroom_s, 6),
-            fold_wait_s=round(self.perf_wait_s, 6),
-            bubble_fractions=(_perf_bubbles(
-                self.perf_wait_s, self.perf_headroom_s, fold_wall,
-                self.serve_wall_s) if self.perf else {}),
             census_enabled=self.census,
             census_ticks=self.census_ticks,
             census_hot_set=dict(self.census_hot_set),

@@ -16,7 +16,7 @@ canonical results come back (new alerts, the runner's cumulative
 wall/dispatch book, sparse registry deltas, chaos fired-counts).  The
 child executes the slice through the SAME ``ServeEngine._score_shard``
 code path as the thread worker — it builds a real 1-shard sub-engine
-over its owned tenants (flight/perf/census/policy/supervision/tiering
+over its owned tenants (flight/census/policy/supervision/tiering
 off; those planes live on the coordinator) — so the score plane is
 byte-identical to the thread engine BY CONSTRUCTION, not by a parallel
 reimplementation.
@@ -377,7 +377,7 @@ class _ShardPlane:
     state changes into barrier replies.
 
     The sub-engine runs with every coordinator plane OFF — flight,
-    perf, census, policy, supervision, tiering, RCA (evidence buffering
+    census, policy, supervision, tiering, RCA (evidence buffering
     is documented coordinator-side: rca.py keeps buffer content
     shard-count-invariant there) — and every knob passed EXPLICITLY
     from the parent's resolved values, so the child can never drift
@@ -418,7 +418,7 @@ class _ShardPlane:
             fuse=init["fuse"], lane_buckets=init["lane_buckets"],
             shards=1, pipeline=init["pipeline"], rca=False,
             native=init["native"], state=init["state"], flight=False,
-            perf=False, census=False,
+            census=False,
             chaos=self.chaos if self.chaos is not None else "",
             ckpt_every=0, policy="off", async_commit=False, tier_hot=0,
             worker="thread", fold="sparse", **det_kw)

@@ -217,8 +217,7 @@ class RcaRunner:
 
     def warm(self) -> float:
         """Compile the whole bucket grid on dead inputs (outside any
-        measured wall); returns the total compile wall; idempotent.  The
-        serve pre-bench gate drives this and fails on any shape miss."""
+        measured wall); returns the total compile wall; idempotent."""
         total = 0.0
         for n, k in self.buckets:
             if (n, k) in self.compile_s_by_bucket:

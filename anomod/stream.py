@@ -49,7 +49,7 @@ with cool callee self-edges alerts the CALLER with evidence="edge"
 working edge-locus detector: the offline models consume per-service
 aggregates, so link faults are architecturally outside their evidence
 (every node-feature model ≤ 0.06 once the generator's coverage/API
-target-identity leak was gated — see docs/BENCHMARKS.md, "Generator-leak
+target-identity leak was gated — see docs/QUALITY.md, "Generator-leak
 retraction").  The residual gap is the de-saturated sparse regime, where
 pooled out-edge windows against an 8-window baseline cap the z below
 threshold at ~1 span/window.
@@ -806,7 +806,7 @@ class OnlineDetector:
         densities an edge row's own baseline holds a handful of spans —
         a raw mean/variance from 1-5 spans is noise, and the old hard
         ``C0 >= min_count`` gate simply zeroed those rows (the
-        sparse-density edge-locus collapse, docs/BENCHMARKS.md).  Instead
+        sparse-density edge-locus collapse, docs/QUALITY.md).  Instead
         every edge row gets an empirical-Bayes baseline: its own stats
         shrunk toward a borrowed population with prior mass
         ``tau = 1.2*min_count`` —

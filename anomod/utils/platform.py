@@ -21,14 +21,14 @@ COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 def enable_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns its directory.
 
-    The ONE placement rule, called by ``chip_smoke.py``, ``bench.py``,
-    the CLI and ``tests/conftest.py``: where ``JAX_COMPILATION_CACHE_DIR``
+    The ONE placement rule, called by ``chip_smoke.py``,
+    ``benchmark/run.py``, the CLI and both conftests: where ``JAX_COMPILATION_CACHE_DIR``
     is set the cache stays there (JAX reads the variable itself) and no
     other directory is set in code; otherwise it is
     :data:`COMPILE_CACHE_DIR`.  The compile-time floor is dropped either
     way: the serve grid is many executables that each compile in well
-    under JAX's default 1 s threshold, and ``serve_main`` builds the same
-    grid for ~20 runners — exactly the entries the default would skip.
+    under JAX's default 1 s threshold — exactly the entries the default
+    would skip.
     """
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
@@ -37,21 +37,6 @@ def enable_compile_cache() -> str:
         return env_dir
     jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
     return str(COMPILE_CACHE_DIR)
-
-
-def env_number(name: str, default, cast=int):
-    """Parse a numeric env var, warning and falling back on garbage:
-    empty/unset → default, non-numeric → stderr warning + default."""
-    raw = os.environ.get(name, "").strip()
-    if not raw:
-        return default
-    try:
-        return cast(raw)
-    except ValueError:
-        import sys
-        print(f"[anomod] ignoring non-numeric {name}={raw!r}",
-              file=sys.stderr)
-        return default
 
 
 def pin_cpu(n_devices: int = 1) -> None:

@@ -130,35 +130,6 @@ class Tracer:
             stack.pop()
             rec["dur"] = time.time() - start
 
-    def add_span(self, name: str, start_s: float, dur_s: float,
-                 parent: Optional[int] = None, tid: int = 0,
-                 **tags) -> int:
-        """Append a PRE-TIMED span record (explicit start/duration/lane)
-        — the injection seam for timelines measured elsewhere, e.g. the
-        performance observatory's dispatch-lifecycle events
-        (anomod.obs.perf.perf_tracer), which export through the one
-        chrome/jaeger pipeline instead of growing a second exporter.
-        Never touches the thread-local span stack.  Returns the span's
-        index (usable as a later ``parent``)."""
-        rec = {"name": name, "start": float(start_s),
-               "dur": float(dur_s), "parent": parent, "tid": int(tid),
-               "tags": {str(k): v for k, v in tags.items()},
-               "events": []}
-        with self._lock:
-            idx = len(self._spans)
-            self._spans.append(rec)
-        return idx
-
-    def event(self, message: str, **fields) -> None:
-        """Attach an event to the CURRENT thread's innermost open span
-        (no-op outside any span — callers never need to guard)."""
-        stack = self._stack()
-        if not stack:
-            return
-        with self._lock:
-            rec = self._spans[stack[-1]]
-        Span(rec).event(message, **fields)
-
     def to_jaeger(self) -> dict:
         """Jaeger API JSON (loadable by anomod.io.sn_traces)."""
         with self._lock:
@@ -212,12 +183,11 @@ class Tracer:
                 "name": s["name"], "ph": "X", "cat": self.service,
                 "ts": int(s["start"] * 1e6),
                 "dur": int(s["dur"] * 1e6),
-                # one lane per recording thread (or per explicit
-                # add_span lane): Perfetto groups worker-thread spans —
-                # shard workers, the dispatch timeline's scratch slots —
-                # instead of collapsing every span onto lane 0; the
-                # shard/slot TAGS ride in args (below) so lanes group
-                # by shard in the UI and survive the round trip
+                # one lane per recording thread: Perfetto groups
+                # worker-thread spans (shard workers) instead of
+                # collapsing every span onto lane 0; the shard TAGS
+                # ride in args (below) so lanes group by shard in the
+                # UI and survive the round trip
                 "pid": 0, "tid": s.get("tid", 0),
                 "args": {**{str(k): str(v)
                             for k, v in sorted(s["tags"].items())},

@@ -4,13 +4,13 @@
 Run as ``python chip_smoke.py`` from the checkout root, in ONE process
 (a chip belongs to one process at a time).  It prints the device JAX
 found, exits non-zero at once unless that device is a TPU, and then
-drives the three main paths once through the entry points ``bench.py``
-and the CLI call, at the sizes those default to:
+drives the three main paths once through the entry points the CLI
+calls, at the sizes those default to:
 
 - **replay** — the TT bench corpus through ``measure_throughput`` with
   each device kernel, the span-count assert on, the aggregate compared
   with the numpy oracle; plus the XLA t-digest plane once.
-- **serve** — ``run_power_law`` at ``bench.py``'s serve configuration
+- **serve** — ``run_power_law`` at :func:`serve_run_kw`'s configuration
   with RCA on, default engines; the device branches (matmul lanes, jax
   pool) must have run and the pool's count plane must sum to the served
   spans.  Whether fused ≡ sequential (that run's own served log,
@@ -36,10 +36,41 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-#: the full-size run (the serve size is bench.serve_run_kw()); the CPU
+#: the full-size run (the serve size is serve_run_kw()); the CPU
 #: rehearsal in tests/test_chip_smoke.py passes tiny twins
 N_TRACES, REPLICATE = 2000, 2
 TRAIN = dict(epochs=36, train_seeds=8, n_traces=80)
+
+
+def serve_run_kw(capacity: float = 25_000, duration: float = 60,
+                 tenants: int = 200) -> dict:
+    """The serve phase's ``run_power_law`` configuration."""
+    return dict(
+        n_tenants=int(tenants), n_services=12,
+        capacity_spans_per_s=float(capacity), overload=2.0,
+        duration_s=float(duration), tick_s=0.5, seed=7,
+        window_s=5.0, baseline_windows=4, fault_tenants=2,
+        # the fixed shed budget: 8 seconds of capacity worth of
+        # backlog — scale-invariant, so a down-sized contract run
+        # sheds in the same regime as the full-size one
+        max_backlog=int(8 * float(capacity)))
+
+
+def engines_identical(eng_a, eng_b):
+    """(alerts_same, states_same) over the union of the two engines'
+    tenants — the one definition every parity bit of the on-chip report
+    reads."""
+    import numpy as np
+    tids = sorted(set(eng_a._tenant_det) | set(eng_b._tenant_det))
+    alerts = all(eng_a.alerts_for(t) == eng_b.alerts_for(t) for t in tids)
+    states = all(
+        t in eng_a._tenant_replay and t in eng_b._tenant_replay
+        and np.array_equal(np.asarray(eng_a._tenant_replay[t].state.agg),
+                           np.asarray(eng_b._tenant_replay[t].state.agg))
+        and np.array_equal(np.asarray(eng_a._tenant_replay[t].state.hist),
+                           np.asarray(eng_b._tenant_replay[t].state.hist))
+        for t in tids)
+    return alerts, states
 
 
 class CompileMeter:
@@ -175,7 +206,6 @@ def phase_serve(serve_kw, expect_engines=("matmul", "jax"),
 
     from anomod.replay import F_COUNT
     from anomod.serve.engine import run_power_law
-    from bench import engines_identical
 
     served_log = []
     eng, rep = run_power_law(shards=1, rca=True, served_log=served_log,
@@ -188,7 +218,7 @@ def phase_serve(serve_kw, expect_engines=("matmul", "jax"),
     assert rep.served_spans > 0
     assert rep.n_alerts > 0
     # WHICH scripted fault tenants alert is fixed by seed and admission,
-    # not by the device: at the bench configuration tenant 0 (priority 0)
+    # not by the device: at the full-size configuration tenant 0 (priority 0)
     # does, and tenant 1 (priority 1) cannot — under the 2x overload it
     # is served ~38 s behind arrival, so by the end of the 60 virtual
     # seconds the detector has seen its spans up to t = 22 s and the
@@ -319,7 +349,6 @@ def main() -> int:
         return 2
 
     from anomod.utils.platform import enable_compile_cache
-    from bench import serve_run_kw
     print(f"compile cache: {enable_compile_cache()}", flush=True)
     meter = CompileMeter()
     phases = (

@@ -9,10 +9,7 @@ neither inline-suppressed (with a reason) nor in the baseline
 (``scripts/lint_baseline.json``, which may only shrink).
 
 The catalog of enforced contracts lives in docs/CONTRACTS.md; the same
-run is available as ``anomod lint``.  ``scripts/pre_bench_check.py``
-runs this gate in BOTH modes before every capture (its own
-``EXIT_LINT`` code): a capture of a tree with a violated determinism
-or parity contract is not reproducible from its record.
+run is available as ``anomod lint``.
 
 Exit codes: 0 = clean (baselined findings ride, shrinkage reported),
 1 = new contract violations (listed on stderr).
@@ -27,7 +24,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 
 def run(root=None) -> dict:
-    """The gate body (importable by pre_bench_check): the ONE shared
+    """The gate body: the ONE shared
     composition ``anomod.analysis.lint.run_gate`` as a summary doc."""
     from anomod.analysis.lint import run_gate
     doc, _ = run_gate(root)

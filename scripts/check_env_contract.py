@@ -7,14 +7,12 @@
   (``anomod/config.py`` — the typed, fail-loud home for knobs that shape
   framework behavior), or
 - it is documented (``README.md`` or any ``docs/*.md`` — the contract
-  for operational/driver knobs that deliberately stay out of Config,
-  e.g. the bench platform overrides).
+  for operational/driver knobs that deliberately stay out of Config).
 
 An env read that is neither is exactly how a knob rots: it works on the
 author's machine, nobody else can discover it, and a typo'd value fails
-silently.  This gate greps the whole package (plus ``bench.py`` and
-``scripts/``) for ``ANOMOD_[A-Z0-9_]+`` tokens and fails listing every
-uncovered name — including any new ``ANOMOD_OBS_*`` knob someone adds
+silently.  This gate greps the whole package (plus ``scripts/``) for
+``ANOMOD_[A-Z0-9_]+`` tokens and fails listing every uncovered name — including any new ``ANOMOD_OBS_*`` knob someone adds
 without teaching the Config/doc contract about it.
 
 Since PR 11 the token grep is backed by the AST scanner in
@@ -28,8 +26,7 @@ the contract at all; route them through anomod.config).
 
 Exit codes: 0 = every referenced var is covered and no dynamic reads,
 1 = violations (listed in the JSON line and on stderr) — the exit
-contract is unchanged from PR 3.  ``scripts/pre_bench_check.py`` runs
-this before every bench gate.
+contract is unchanged from PR 3.
 """
 
 import argparse
@@ -52,8 +49,7 @@ def referenced_vars(root: Path) -> dict:
     Tokens ending in ``_`` are glob-style prefixes in prose (e.g.
     ``ANOMOD_SERVE_BENCH_*`` rendered without the star) — not reads."""
     out: dict = {}
-    files = [root / "bench.py"]
-    files += sorted((root / "anomod").rglob("*.py"))
+    files = sorted((root / "anomod").rglob("*.py"))
     files += sorted((root / "scripts").glob("*.py"))
     for p in files:
         if not p.is_file():

@@ -28,10 +28,6 @@ Verdicts (one JSON line on stdout):
   or ``-fsanitize`` probe failed); the REASON is recorded; exit 0
 - ``fail`` — the sanitizer reported a race/error, or the hammer's
   byte-parity oracle failed; stderr carries the report; exit 1
-
-``scripts/pre_bench_check.py --mode serve`` runs the tsan leg whenever
-the native runtime is in play, mapping ``fail`` to its
-``EXIT_NATIVE_UNUSABLE`` code (a racy staging runtime must not serve).
 """
 
 import argparse

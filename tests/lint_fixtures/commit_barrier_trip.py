@@ -15,6 +15,6 @@ class Engine:
 
     def tick_armed_deferred(self, served, pending):
         self._deferred = {"pending": pending}        # window opens
-        doc = self._perf_drain()                     # C601: pre-commit drain
+        doc = self._census_drain()                   # C601: pre-commit drain
         self._commit_deferred()
         return doc

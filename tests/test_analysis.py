@@ -8,8 +8,8 @@ finding passes, stale entries ratchet out), the parity-surface audit
 criteria name), the CANONICAL ServeReport field inventory (the
 forcing function: a new field must either join the variant list or be
 named by a test — this literal is that naming), the repo-runs-clean
-pin, the env-contract delegation (dynamic-read false negative closed),
-the pre-bench EXIT_LINT wiring and the sanitize-smoke verdict shapes.
+pin, the env-contract delegation (dynamic-read false negative closed) and
+the sanitize-smoke verdict shapes.
 """
 
 import json
@@ -216,11 +216,6 @@ CANONICAL_REPORT_FIELDS = (
     # policy_wall_s are the variant topology/wall halves
     "policy", "n_scale_ups", "n_scale_downs", "n_rebalances",
     "n_policy_migrations", "brownout_ticks",
-    # the performance observatory (ISSUE-14): whether the dispatch-
-    # lifecycle timeline ran is config, identical at every shard
-    # count; its event counts / headroom / wait / bubble numbers are
-    # wall-clock+topology and live on SHARD_VARIANT_REPORT_FIELDS
-    "perf_enabled",
     # the fleet census (ISSUE-15): the enable bit is config, the
     # census tick count is a pure function of cadence × run length,
     # and the hot-set/Zipf census derives from coordinator admission
@@ -394,8 +389,7 @@ def test_check_contracts_gate_green_on_repo():
 def test_sanitize_smoke_verdict_shapes():
     """The probe returns a reasoned verdict either way; the smoke's
     skip path carries its reason (never a silent skip).  The full
-    build+hammer run is exercised by `pre_bench_check --mode serve`
-    and `make -C native tsan` (slow path)."""
+    build+hammer run is `make -C native tsan` (slow path)."""
     sys.path.insert(0, str(SCRIPTS))
     try:
         import native_sanitize_smoke as nss

@@ -13,7 +13,6 @@ from anomod.obs.census import (CENSUS_PLANES, collect_resident_bytes,
                                diff_census, fit_slope, fit_zipf,
                                fleet_probe, plane_nbytes,
                                pool_row_nbytes, pool_slot_nbytes,
-                               process_resident_bytes,
                                span_batch_nbytes)
 from anomod.serve.engine import run_power_law
 
@@ -229,11 +228,6 @@ def test_pool_reconciliation_survives_growth():
     assert got == (pool.capacity + 1) * pool_row_nbytes(cfg)
     assert pool_row_nbytes(cfg) == pool_slot_nbytes(cfg) + 4 * (256 - 192)
     assert pool.capacity >= 6
-
-
-def test_process_resident_bytes_informational():
-    got = process_resident_bytes()
-    assert got is None or got > 0
 
 
 # ---------------------------------------------------------------------------

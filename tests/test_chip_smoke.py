@@ -15,15 +15,11 @@ ROOT = Path(__file__).resolve().parent.parent
 
 @pytest.fixture(scope="module")
 def smoke():
-    sys.path.insert(0, str(ROOT))          # chip_smoke imports bench
-    try:
-        spec = importlib.util.spec_from_file_location(
-            "chip_smoke", ROOT / "chip_smoke.py")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        yield mod
-    finally:
-        sys.path.remove(str(ROOT))
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", ROOT / "chip_smoke.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @pytest.fixture
@@ -65,8 +61,7 @@ def test_replay_parity_check_catches_a_wrong_plane(smoke):
 
 
 def test_serve_phase_rehearsal(smoke):
-    from bench import serve_run_kw
-    kw = dict(serve_run_kw(capacity=1500, duration=45, tenants=12),
+    kw = dict(smoke.serve_run_kw(capacity=1500, duration=45, tenants=12),
               buckets=(64, 256), lane_buckets=(1, 2, 4))
     # at this size the second fault tenant is served in time to alert
     info = smoke.phase_serve(kw, expect_engines=("scatter", "numpy"),
@@ -91,11 +86,10 @@ def test_fused_vs_sequential_reads_the_runs_own_served_log(smoke):
     hands back re-scores to the engine's states, and the same log with
     one tick's batches withheld does not."""
     from anomod.serve.engine import run_power_law
-    from bench import serve_run_kw
     log = []
     eng, rep = run_power_law(
         shards=1, served_log=log, buckets=(64, 256), lane_buckets=(1, 2, 4),
-        **serve_run_kw(capacity=1500, duration=20, tenants=6))
+        **smoke.serve_run_kw(capacity=1500, duration=20, tenants=6))
     assert len(log) == 40                      # one entry per tick
     assert sum(qb.spans.n_spans for served in log for qb in served) \
         == rep.served_spans

@@ -1,12 +1,10 @@
 """Ingest fast-path tests: content-addressed cache correctness (warm ==
 cold bit-identical, invalidation on source change and loader-version bump,
 corrupt-entry fallback), parallel-loader parity, the double-buffered
-prefetcher, the env contract, and the pre-bench cold-cache gate."""
+prefetcher and the env contract."""
 
 import json
 import os
-import subprocess
-import sys
 from pathlib import Path
 
 import numpy as np
@@ -16,8 +14,6 @@ from anomod import labels, synth
 from anomod.config import Config
 from anomod.io import cache, dataset
 from anomod.io import metrics as met_io
-
-SCRIPTS = Path(__file__).parent.parent / "scripts"
 
 
 def _cfg(tmp_path, **kw):
@@ -228,35 +224,6 @@ def test_env_contract(monkeypatch):
         Config()
 
 
-def test_pre_bench_gate_refuses_cold_cache(tmp_path):
-    env = dict(os.environ, ANOMOD_CACHE_DIR=str(tmp_path / "cache"),
-               ANOMOD_DATA_ROOT=str(tmp_path / "data"))
-    script = str(SCRIPTS / "pre_bench_check.py")
-
-    r = subprocess.run([sys.executable, script, "--traces", "40"],
-                       capture_output=True, text=True, timeout=120, env=env)
-    assert r.returncode == 1, r.stdout + r.stderr
-    assert json.loads(r.stdout)["status"] == "cold"
-
-    r = subprocess.run([sys.executable, script, "--traces", "40", "--cold"],
-                       capture_output=True, text=True, timeout=120, env=env)
-    assert r.returncode == 0
-
-    # warm the exact bench key, then the gate passes
-    cfg = _cfg(tmp_path)
-    dataset.load_bench_corpus("TT", 40, cfg)
-    r = subprocess.run([sys.executable, script, "--traces", "40"],
-                       capture_output=True, text=True, timeout=120, env=env)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert json.loads(r.stdout)["status"] == "warm"
-
-    # disabled caching is also a refusal (nothing can ever be warm)
-    env["ANOMOD_CACHE_DIR"] = "off"
-    r = subprocess.run([sys.executable, script, "--traces", "40"],
-                       capture_output=True, text=True, timeout=120, env=env)
-    assert r.returncode == 2
-
-
 def test_ingest_cli_warm_cache(tmp_path, capsys):
     from anomod.cli import main
     rc = main(["ingest", "--warm-cache", "--testbed", "TT",
@@ -285,5 +252,3 @@ def test_bench_corpus_cold_warm_accounting(tmp_path):
     assert warm["cache_hit"]
     assert warm["parse_s"] == pytest.approx(cold["parse_s"])
     _assert_batches_equal(b1, b2, "bench-corpus")
-    assert dataset.bench_cache_status("TT", 60, cfg) == (1, 1)
-    assert dataset.bench_cache_status("TT", 61, cfg) == (0, 1)

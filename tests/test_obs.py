@@ -478,8 +478,10 @@ def test_env_contract_script_passes_on_repo():
     assert out["status"] == "ok"
     # the PINNED inventory size: a new ANOMOD_* knob must land here and
     # in the docs in the same PR (PR 21 took it from 79 to 72: the probe /
-    # platform-pin / jit-cache names went with the machinery they set)
-    assert out["n_vars"] == 72
+    # platform-pin / jit-cache names went with the machinery they set;
+    # PR 31 to 61: the perf observatory's three and the eight that the
+    # deleted pre-chip benchmark and its fold sweep read)
+    assert out["n_vars"] == 61
 
 
 def test_env_contract_script_catches_rogue_var(tmp_path):

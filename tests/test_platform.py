@@ -73,13 +73,3 @@ def test_cache_entries_land_where_the_rule_says(tmp_path, env_set):
     if env_set:
         # the config value is whatever JAX derived from the env itself
         assert r.stdout.splitlines()[1] == str(tmp_path / "cc")
-
-
-def test_env_number(monkeypatch, capsys):
-    monkeypatch.setenv("X_NUM", "12")
-    assert platform.env_number("X_NUM", 3) == 12
-    monkeypatch.setenv("X_NUM", "twelve")
-    assert platform.env_number("X_NUM", 3) == 3
-    assert "ignoring non-numeric" in capsys.readouterr().err
-    monkeypatch.delenv("X_NUM")
-    assert platform.env_number("X_NUM", 2.5, cast=float) == 2.5

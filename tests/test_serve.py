@@ -838,8 +838,7 @@ def test_lane_env_knobs_registered_and_validated(monkeypatch):
 
 def _report_decision_fields(rep):
     """Everything in the report that must be shard-count/pipeline-depth
-    invariant (the exclusion list is engine.py's ONE definition, shared
-    with the pre-bench fan-out smoke)."""
+    invariant (the exclusion list is engine.py's ONE definition)."""
     from anomod.serve.engine import SHARD_VARIANT_REPORT_FIELDS
     return {k: v for k, v in rep.to_dict().items()
             if k not in SHARD_VARIANT_REPORT_FIELDS}

@@ -8,8 +8,8 @@ read — and every decision plane (tenant states, alert streams, SLO,
 shed, the canonical flight journal) is BYTE-identical to the
 synchronous engine of the same seed.  The synchronous engine stays the
 parity oracle (``ANOMOD_SERVE_ASYNC_COMMIT=0``); only wall-time
-attribution moves (the hidden wait lands on the ``commit_defer`` perf
-leg, a consciously variant report field).
+attribution moves (the hidden wait lands in ``commit_defer_wall_s``, a
+consciously variant report field).
 
 Tier-1 covers the parity core, the chaos-hook ordering across the
 deferred commit (pre-mutation issue-side phases and the post-mutation

@@ -5,6 +5,7 @@ carry names a device trace keeps."""
 
 import contextlib
 import sys
+import threading
 
 import numpy as np
 import pytest
@@ -59,7 +60,8 @@ def obs_off():
     set_registry(prev)
 
 
-def _engine(tracer, async_commit=False, state="device"):
+def _engine(tracer, async_commit=False, state="device", pipeline=None,
+            shards=1, **engine_kw):
     traffic = PowerLawTraffic(
         n_tenants=6, total_rate_spans_per_s=1800, alpha=0.6, seed=5,
         n_services=4, batch_cap=64,
@@ -71,8 +73,8 @@ def _engine(tracer, async_commit=False, state="device"):
                       capacity_spans_per_s=1200, tick_s=1.0,
                       buckets=(128, 512), lane_buckets=(1, 2, 4),
                       max_backlog=2400, baseline_windows=4, fuse=True,
-                      shards=1, tracer=tracer, async_commit=async_commit,
-                      state=state)
+                      shards=shards, pipeline=pipeline, tracer=tracer,
+                      async_commit=async_commit, state=state, **engine_kw)
     return eng, traffic
 
 
@@ -182,6 +184,249 @@ def test_no_tracer_opens_no_span_and_no_annotation(obs_off, monkeypatch):
     eng, traffic = _engine(None)
     _run(eng, traffic, 3)
     assert opened == []
+
+
+# -- one dispatch, three spans: order and containment, never a duration -----
+
+class SeqRecorder:
+    """A tracer that numbers every open and every close in ONE sequence
+    across threads (a stack a thread for the parent), so order and
+    containment read off integers: span ``i`` is inside span ``j`` when
+    ``opened[j] < opened[i]`` and ``closed[i] < closed[j]``."""
+
+    def __init__(self):
+        self.spans = []          # {name, tags, thread, parent, open, close}
+        self._lock = threading.Lock()
+        self._seq = 0
+        self._tls = threading.local()
+
+    def _tick(self):
+        with self._lock:
+            self._seq += 1
+            return self._seq
+
+    @contextlib.contextmanager
+    def span(self, name, **tags):
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        rec = {"name": name, "tags": tags,
+               "thread": threading.get_ident(),
+               "parent": stack[-1] if stack else None,
+               "open": self._tick(), "close": None}
+        with self._lock:
+            self.spans.append(rec)
+        stack.append(rec)
+        try:
+            yield None
+        finally:
+            stack.pop()
+            rec["close"] = self._tick()
+
+    def named(self, name):
+        return [s for s in self.spans if s["name"] == name]
+
+    def still_open(self):
+        return [s["name"] for s in self.spans if s["close"] is None]
+
+
+def _inside(inner, outer):
+    return outer["open"] < inner["open"] and inner["close"] < outer["close"]
+
+
+def _dispatches_by_tick(rec):
+    """tick span -> its dispatches as ``(fill, dispatch, retire)`` triples,
+    a thread at a time in open order.  On one thread the k-th fill, the
+    k-th dispatch and the k-th retire are the same dispatch: a runner
+    belongs to one thread and retires in issue order."""
+    out = []
+    for tick in rec.named("serve.tick"):
+        triples = []
+        inside = [s for s in rec.spans if s["name"] in PER_DISPATCH
+                  and _inside(s, tick)]
+        for thread in sorted({s["thread"] for s in inside}):
+            mine = [s for s in inside if s["thread"] == thread]
+            by_name = [[s for s in mine if s["name"] == n]
+                       for n in PER_DISPATCH]
+            assert len({len(b) for b in by_name}) == 1, \
+                [len(b) for b in by_name]
+            triples.append(list(zip(*by_name)))
+        out.append((tick, triples))
+    return out
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+@pytest.mark.parametrize("pipeline", [1, 2])
+def test_each_dispatch_opens_fill_dispatch_retire_in_order(obs_off,
+                                                           pipeline, shards):
+    rec = SeqRecorder()
+    eng, traffic = _engine(rec, pipeline=pipeline, shards=shards)
+    _run(eng, traffic, 6)
+    assert rec.still_open() == []
+    n = sum(r.fused_dispatches for r in eng._runners)
+    assert n > 6                         # more than one a tick
+    for name in PER_DISPATCH:
+        assert len(rec.named(name)) == n
+    seen = 0
+    for tick, threads in _dispatches_by_tick(rec):
+        for triples in threads:
+            for fill, dispatch, retire in triples:
+                seen += 1
+                assert fill["close"] < dispatch["open"]
+                assert dispatch["close"] < retire["open"]
+                assert fill["tags"]["width"] == dispatch["tags"]["width"]
+                assert fill["tags"]["lanes"] == dispatch["tags"]["lanes"] \
+                    == retire["tags"]["lanes"]
+                assert 0 < fill["tags"]["live"] <= fill["tags"]["lanes"]
+    # every dispatch span was inside a tick, all three in the same one
+    assert seen == n
+    if shards == 2:
+        # each shard's runner on its own worker thread, tagged with it
+        per_shard = rec.named("serve.score_shard")
+        assert {s["tags"]["shard"] for s in per_shard} == {0, 1}
+        assert len({s["thread"] for s in per_shard}) == 2
+
+
+@pytest.mark.parametrize("pipeline", [1, 2])
+def test_a_pipelined_fill_starts_before_the_previous_retire(obs_off,
+                                                            pipeline):
+    """Depth 2 stages dispatch k+1 while dispatch k is in flight: its
+    fill opens before k's retire.  Depth 1 never does."""
+    rec = SeqRecorder()
+    eng, traffic = _engine(rec, pipeline=pipeline)
+    _run(eng, traffic, 6)
+    pairs = 0
+    for _, threads in _dispatches_by_tick(rec):
+        for triples in threads:
+            for (_, _, retire), (fill, _, _) in zip(triples, triples[1:]):
+                pairs += 1
+                if pipeline == 2:
+                    assert fill["open"] < retire["open"]
+                else:
+                    assert retire["close"] < fill["open"]
+    assert pairs > 0
+
+
+def test_an_aborted_dispatch_closes_its_spans(obs_off):
+    """A dispatch that raises with another in flight (no supervisor to
+    re-execute the tick): the tick fails, every span it opened is
+    closed, the dropped dispatch is never retired, and the next tick's
+    dispatches are whole again."""
+    rec = SeqRecorder()
+    eng, traffic = _engine(rec, pipeline=2, ckpt_every=0)
+    _run(eng, traffic, 3)
+    runner = eng.runner
+    real = runner._lane_exec_for
+    calls = []
+
+    def second_one_raises(shape, scratch):
+        exe = real(shape, scratch)
+        calls.append(shape)
+        if len(calls) != 2:
+            return exe
+
+        def boom(_):
+            assert runner.inflight_dispatches == 1
+            raise RuntimeError("scripted dispatch fault")
+        return boom
+
+    before = len(rec.spans)
+    runner._lane_exec_for = second_one_raises
+    with pytest.raises(RuntimeError, match="scripted dispatch fault"):
+        eng.tick(traffic.arrivals(3.0, 4.0))
+    del runner._lane_exec_for               # the method again
+    assert runner.inflight_dispatches == 0
+    assert rec.still_open() == []
+    failed = [s["name"] for s in rec.spans[before:]
+              if s["name"] in PER_DISPATCH]
+    assert failed == ["serve.lane_fill", "serve.lane_dispatch",
+                      "serve.lane_fill", "serve.lane_dispatch"]
+    before = len(rec.spans)
+    done = runner.fused_dispatches
+    eng.tick(traffic.arrivals(4.0, 5.0))
+    after = [s["name"] for s in rec.spans[before:]
+             if s["name"] in PER_DISPATCH]
+    n = runner.fused_dispatches - done
+    assert n > 0 and all(after.count(name) == n for name in PER_DISPATCH)
+    assert rec.still_open() == []
+
+
+def test_engine_spans_survive_the_chrome_round_trip(obs_off):
+    """``Tracer.to_chrome`` -> ``spans_from_chrome`` keeps the name, the
+    tags, the parent and the lane of every dispatch span."""
+    from anomod.utils.tracing import Tracer, spans_from_chrome
+    tracer = Tracer("anomod-serve")
+    eng, traffic = _engine(tracer, pipeline=2, shards=2)
+    _run(eng, traffic, 4)
+    back = spans_from_chrome(tracer.to_chrome())
+    assert len(back) == tracer.n_spans
+    kept = 0
+    for was, got in zip(tracer._spans, back):
+        assert (got["name"], got["parent"], got["tid"]) \
+            == (was["name"], was["parent"], was["tid"])
+        assert got["tags"] == {k: str(v) for k, v in was["tags"].items()}
+        kept += was["name"] in PER_DISPATCH
+    assert kept == 3 * sum(r.fused_dispatches for r in eng._runners)
+    # the two shards' dispatch spans on two lanes, neither the tick's
+    lanes = {s["tid"] for s in back if s["name"] in PER_DISPATCH}
+    tick_lane = {s["tid"] for s in back if s["name"] == "serve.tick"}
+    assert len(lanes) == 2 and not lanes & tick_lane
+
+
+# -- the product tracer's lanes (worker threads) ----------------------------
+
+def test_tracer_worker_thread_lanes_and_tags():
+    """Satellite pin: worker-thread spans export on their OWN chrome
+    lane (tid) with shard tags in args, and spans_from_chrome carries
+    the lane through the round trip."""
+    from anomod.utils.tracing import Tracer, spans_from_chrome
+    tr = Tracer("anomod-test")
+    with tr.span("coordinator"):
+        pass
+    # both workers alive at once (a finished thread's ident is
+    # reusable — the engine's ShardWorkers are persistent, which is
+    # what the lane-per-thread contract rides on)
+    barrier = threading.Barrier(2)
+
+    def worker(shard):
+        with tr.span("serve.score_shard", shard=shard, pipeline=2):
+            barrier.wait(timeout=10)
+
+    ts = [threading.Thread(target=worker, args=(s,)) for s in (0, 1)]
+    for t in ts:
+        t.start()
+    for t in ts:
+        t.join()
+    events = tr.to_chrome()
+    shard_spans = [e for e in events
+                   if e["name"] == "serve.score_shard"]
+    assert {e["args"]["shard"] for e in shard_spans} == {"0", "1"}
+    # worker lanes are distinct from the coordinator's lane 0
+    assert all(e["tid"] != 0 for e in shard_spans)
+    assert len({e["tid"] for e in shard_spans}) == 2
+    spans = spans_from_chrome(events)
+    got = [s for s in spans if s["name"] == "serve.score_shard"]
+    assert {s["tags"]["shard"] for s in got} == {"0", "1"}
+    assert all(s["tid"] != 0 for s in got)
+
+
+def test_sharded_engine_trace_carries_shard_tags():
+    """The engine's worker-thread score spans carry the shard tag into
+    the chrome export — a 2-shard trace's lanes group by shard."""
+    from anomod.serve.engine import run_power_law
+    from anomod.utils.tracing import Tracer
+    tracer = Tracer("anomod-serve")
+    run_power_law(n_tenants=6, n_services=4, capacity_spans_per_s=1000,
+                  overload=2.0, duration_s=20, tick_s=1.0, seed=5,
+                  window_s=5.0, baseline_windows=4, fault_tenants=1,
+                  buckets=(64, 256), lane_buckets=(1, 2, 4),
+                  max_backlog=1500, n_windows=16, shards=2, pipeline=2,
+                  tracer=tracer)
+    events = tracer.to_chrome()
+    shard_spans = [e for e in events
+                   if e["name"] == "serve.score_shard"]
+    assert {e["args"]["shard"] for e in shard_spans} == {"0", "1"}
+    assert len({e["tid"] for e in shard_spans}) == 2
 
 
 # -- the product tracer on the profiler's clock -----------------------------

@@ -502,7 +502,7 @@ def test_edge_locus_attribution_survives_sparse_density():
     offline sweep's knobs (60 traces, severity 0.3, noise 0.5) an
     edge-locus fault whose out-edge baseline holds only a handful of
     spans must still be attributed to the caller — the old fixed-width
-    pool with the hard C0 gate scored these rows 0 (docs/BENCHMARKS.md's
+    pool with the hard C0 gate scored these rows 0 (docs/QUALITY.md's
     0.17 collapse)."""
     label = labels.label_for("Lv_C_travel_detail_failure")
     hard = synth.HardMode(severity=0.3, noise=0.5, fault_locus="edge")

@@ -14,7 +14,7 @@ import pytest
 
 @pytest.fixture(scope="module")
 def tt_corpus():
-    """A real multi-experiment TT corpus staged exactly like bench.py
+    """A real multi-experiment TT corpus staged exactly like the replay cell
     (all 13 labels so the service vocabulary and sid range match the
     production replay), small enough to stage in seconds."""
     from anomod import labels, synth
