@@ -12,19 +12,24 @@ cell runs the sorted-window variant).
 Three structural fixes over the round-1 kernel (which measured 6.0e7
 spans/sec vs 1.1e8 for the XLA scan path):
 
-1. **Transposed formulation.**  out[F+H, SW+1] = rhsᵀ[F+H, B] @ onehot
-   [B, SW+1] puts the narrow 25-row feature axis on *sublanes* (25→32
-   padding, 1.3x) instead of lanes (25→128, 5x), and every operand is
-   built in its natural layout — the old kernel's in-kernel ``feats.T``
-   relayout is gone.
+1. **Transposed formulation.**  out[ROWS, SW+1] = rhsᵀ[ROWS, B] @ onehot
+   puts the narrow feature axis on *sublanes* (32 rows) instead of lanes
+   (25→128, 5x), and the planes are read as they lie, ``[6, B]`` with
+   spans on lanes.  The unsorted and the lane-stacked kernel still build
+   their one-hot span-major (``sid[:, None]``: one lane→sublane relayout
+   of the segment ids a block, XLU work that is most of their step); the
+   sorted-window kernel builds it segment-major and keeps spans on lanes
+   in every operand (:func:`make_pallas_replay_sorted_fn`).
 2. **bf16 one-hot + hi/lo moments, single MXU pass.**  The old kernel ran
    one f32 ``Precision.HIGHEST`` matmul (~6 bf16 MXU passes).  This kernel
    uses the same split as the XLA path (replay.py chunk_step): 0/1 planes
    exact in bf16, latency moments as a two-way bf16 hi/lo split, all in ONE
    bf16 matmul with f32 accumulation.
-3. **VMEM-sized tiles.**  The old [8192, SW+1] f32 one-hot tile was ~46 MB
-   — ~3x core VMEM (~16 MB), so Mosaic spilled it to HBM.  The default
-   block of 4096 keeps the bf16 tile under 12 MB.
+3. **A one-hot that fits VMEM.**  The old [8192, SW+1] f32 one-hot tile was
+   ~46 MB — ~3x core VMEM (~16 MB), so Mosaic spilled it to HBM; a bf16
+   tile of 4096 spans stays under 12 MB.  The sorted-window kernel's
+   [128, 4096] one-hot never exists as an array: Mosaic pushes the compare
+   masks into the MXU as they are made.
 
 ``inner_repeats`` replays the staged corpus on-device via an outer grid
 dimension (same measurement trick as the XLA path's fori_loop).
@@ -42,16 +47,24 @@ PLANES = ("valid", "err", "s5", "dur_raw", "dur", "dur2")
 N_PLANES = len(PLANES)
 
 
+#: rows of the moment group of the kernels' right-hand side: one packed
+#: bfloat16 tile (16 rows a vreg), so the histogram rows start on the next
+MOMENT_ROWS = 16
+
+
 def _build_rhs_t(planes, block, n_hist):
-    """Shared kernel-body stage for both replay kernels: the [3+6+H, B]
-    bf16 right-hand side — exact 0/1 planes, two-way hi/lo split of the
-    latency moments, and the in-kernel histogram bucket one-hot.  Traced
-    inside a pallas kernel (plain jnp ops only)."""
+    """Shared kernel-body stage for the replay kernels: the
+    ``[16 + H, B]`` bf16 right-hand side.  Rows 0:6 are the planes rounded
+    to bfloat16 (the three 0/1 planes exactly, the hi half of the three
+    latency moments), rows 8:14 what the rounding left (exactly 0 for the
+    0/1 planes, the lo half of the moments), rows 16: the in-kernel
+    histogram bucket one-hot; rows 6:8 and 14:16 are zero.  Every piece
+    starts on a float32 vreg (8 rows) and every group on a packed bfloat16
+    vreg (16 rows): no sublane shuffle.  Traced inside a pallas kernel
+    (plain jnp ops only)."""
     import jax
     import jax.numpy as jnp
 
-    exact = planes[0:3].astype(jnp.bfloat16)  # valid / err / 5xx
-    moments = planes[3:6]                     # dur_raw / dur / dur^2
     # The same values as replay._split_hi_lo, written as the convert pair
     # that function must avoid: ``lax.reduce_precision`` has no Pallas TPU
     # lowering in JAX 0.9.0 (tests/test_pallas_lowering.py trips the day
@@ -59,28 +72,30 @@ def _build_rhs_t(planes, block, n_hist):
     # XLA:TPU, does not elide the pair.  That it keeps the lo term is
     # pinned compiled, < 1e-4 against float64 for every kernel that calls
     # this (tpu_tests/test_mosaic_parity.py).
-    hi = moments.astype(jnp.bfloat16)
-    lo = (moments - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    valid = planes[0]
-    bucket = jnp.clip(planes[4].astype(jnp.int32), 0, n_hist - 1)
+    hi = planes.astype(jnp.bfloat16).astype(jnp.float32)      # [6, B]
+    lo = planes - hi
+    pad = jnp.zeros((MOMENT_ROWS // 2 - N_PLANES, block), jnp.float32)
+    moments = jnp.concatenate([hi, pad, lo, pad], axis=0)     # [16, B]
+    valid = planes[0:1]
+    bucket = jnp.clip(planes[4:5].astype(jnp.int32), 0, n_hist - 1)
     h_iota = jax.lax.broadcasted_iota(jnp.int32, (n_hist, block), 0)
-    bucket_oh = jnp.where(h_iota == bucket[None, :], valid[None, :],
-                          0.0).astype(jnp.bfloat16)       # [H, B]
-    return jnp.concatenate([exact, hi, lo, bucket_oh], axis=0)
+    bucket_oh = jnp.where(h_iota == bucket, valid, 0.0)       # [H, B]
+    return jnp.concatenate([moments, bucket_oh],
+                           axis=0).astype(jnp.bfloat16)
 
 
 def _recombine_moments(acc, n_segments):
-    """Shared epilogue: recombine hi+lo moment rows, drop the dead-pad
-    segment, transpose the row axis back behind the segment axis —
+    """Shared epilogue: add the lo rows back onto the hi rows, drop the
+    dead-pad segment, transpose the row axis back behind the segment axis —
     ``[ROWS, SW+1] -> [SW, F+H]``, or batched ``[L, ROWS, SW+1] ->
-    [L, SW, F+H]`` for the lane-stacked kernel.  The bf16 hi/lo split
-    layout (3 exact + 3 hi + 3 lo + H histogram rows) is encoded HERE
-    and in the kernels' rhs staging only."""
+    [L, SW, F+H]`` for the lane-stacked kernel.  The row order of
+    :func:`_build_rhs_t` is encoded THERE and HERE only."""
     import jax.numpy as jnp
 
+    half = MOMENT_ROWS // 2
     agg_t = jnp.concatenate(
-        [acc[..., 0:3, :], acc[..., 3:6, :] + acc[..., 6:9, :],
-         acc[..., 9:, :]], axis=-2)
+        [acc[..., 0:N_PLANES, :] + acc[..., half:half + N_PLANES, :],
+         acc[..., MOMENT_ROWS:, :]], axis=-2)
     return jnp.swapaxes(agg_t, -1, -2)[..., :n_segments, :]
 
 
@@ -105,7 +120,7 @@ def make_pallas_replay_fn(n_segments: int, n_hist: int = 16,
     from jax.experimental.pallas import tpu as pltpu
 
     SW1 = n_segments + 1          # + dead lane
-    ROWS = 3 + 6 + n_hist         # exact + (hi, lo) moments + histogram
+    ROWS = MOMENT_ROWS + n_hist   # hi + lo moment rows, histogram
 
     def kernel(sid_ref, planes_ref, out_ref):
         @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
@@ -113,7 +128,6 @@ def make_pallas_replay_fn(n_segments: int, n_hist: int = 16,
             out_ref[:] = jnp.zeros_like(out_ref)
 
         sid = sid_ref[:]                          # [B] int32
-        # [6, B] f32 natural layout -> shared bf16 rhs build
         rhs_t = _build_rhs_t(planes_ref[:], block, n_hist)
         seg_iota = jax.lax.broadcasted_iota(jnp.int32, (block, SW1), 1)
         onehot = (seg_iota == sid[:, None]).astype(jnp.bfloat16)
@@ -165,7 +179,7 @@ def make_pallas_lane_delta_fn(n_segments: int, n_hist: int = 16,
     launch per fused (lanes, width) shape.  Dead pad lanes carry all-pad
     rows (sid = SW, valid = 0) and produce exact-zero deltas, exactly as
     the scatter twin's dead segments.  ``block=0`` picks ``min(W, 4096)``
-    (the VMEM-tuned replay default); W must be a block multiple — serve
+    (the replay kernels' default); W must be a block multiple — serve
     widths are powers of two, so the default always divides.
 
     Parity contract: identical 0/1 and histogram planes to the scatter/
@@ -181,7 +195,7 @@ def make_pallas_lane_delta_fn(n_segments: int, n_hist: int = 16,
     from jax.experimental.pallas import tpu as pltpu
 
     SW1 = n_segments + 1          # + dead lane
-    ROWS = 3 + 6 + n_hist         # exact + (hi, lo) moments + histogram
+    ROWS = MOMENT_ROWS + n_hist   # hi + lo moment rows, histogram
 
     def run(sid, planes):
         L, W = sid.shape
@@ -269,15 +283,35 @@ def make_pallas_replay_sorted_fn(n_segments: int, n_hist: int = 16,
     over arrays staged by :func:`stage_sorted_planes`.
 
     Same fused pipeline (bf16 hi/lo split, in-kernel bucketing, resident
-    VMEM accumulator), but the one-hot and the MXU matmul are ``k`` lanes
-    wide instead of ``n_segments + 1``: each block's spans all live in one
-    aligned k-segment window (host staging guarantees it), so the block's
-    [ROWS, k] partial accumulates into a dynamic k-wide slice of the
-    accumulator at the window's column offset (``wids`` rides scalar
-    prefetch into the index-map/kernel).  For the TT bench corpus
-    (SW+1 = 1441, k = 128) that is ~11x less one-hot construction and MXU
-    work per span for ~5% padding — aligned windows keep global segment s
-    at column s, so the epilogue is unchanged."""
+    VMEM accumulator), but the one-hot and the MXU matmul are ``k``
+    segments wide instead of ``n_segments + 1``: each staged block's spans
+    all live in one aligned k-segment window (host staging guarantees it),
+    so the block's [ROWS, k] partial accumulates into a dynamic k-wide
+    slice of the accumulator at the window's column offset (``wids`` rides
+    scalar prefetch into SMEM).  Aligned windows keep global segment s at
+    column s, so the epilogue is the unsorted kernel's.
+
+    **Spans stay on lanes in every operand.**  The one-hot is built
+    segment-major, ``onehot_t[k, B] = (iota_k[:, None] == sid[None, :])``:
+    ``sid`` is read as it lies and broadcast along sublanes, and the
+    product contracts the lane axis of both operands
+    (``rhs_t[ROWS, B] · onehot_t[k, B]ᵀ``, the q·kᵀ form of an attention
+    kernel).  Mosaic then never materialises the one-hot: it packs the
+    compare masks and pushes them into the MXU as transposed weights, 8
+    pushes a 128-span tile, 256 a block of 4,096, and those pushes are
+    what bounds the kernel on a v5e.  A span-major one-hot
+    (``sid[:, None]``) costs a lane→sublane relayout of ``sid`` for each
+    of its 512 vregs, XLU work that was most of the step (PERF.md §5 has
+    the times of both, stage by stage).
+
+    **``S`` staged blocks a grid step**, ``S`` the largest of 8, 4, 2, 1
+    that divides the call's block count (a static of the traced shapes):
+    one DMA of ``S * block`` rows, then a loop over the blocks, four an
+    iteration so that one block's right-hand side is built while
+    another's masks are pushed (the scheduler does not overlap loop
+    iterations, and Mosaic unrolls a loop once or fully; four keeps the
+    program near 2,100 bundles).  Each block reads its own window id
+    from SMEM: a step may straddle a window boundary."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -285,24 +319,47 @@ def make_pallas_replay_sorted_fn(n_segments: int, n_hist: int = 16,
 
     nw = (n_segments + 1 + k - 1) // k
     NWK = nw * k
-    ROWS = 3 + 6 + n_hist         # exact + (hi, lo) moments + histogram
+    ROWS = MOMENT_ROWS + n_hist   # hi + lo moment rows, histogram
+    GROUP = 4                     # staged blocks a loop iteration
 
-    def kernel(wids_ref, sid_ref, planes_ref, out_ref):
-        @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
-        def _init():
-            out_ref[:] = jnp.zeros_like(out_ref)
+    def make_kernel(S):
+        def kernel(wids_ref, sid_ref, planes_ref, out_ref):
+            # read outside the loop: the interpreter resolves program_id
+            # in the kernel's own jaxpr only
+            step = pl.program_id(1)
 
-        sid = sid_ref[:]                          # [B] int32, window-local
-        # [6, B] f32 -> shared bf16 rhs build (same split as the unsorted
-        # kernel, so the two paths cannot diverge numerically)
-        rhs_t = _build_rhs_t(planes_ref[:], block, n_hist)
-        seg_iota = jax.lax.broadcasted_iota(jnp.int32, (block, k), 1)
-        onehot = (seg_iota == sid[:, None]).astype(jnp.bfloat16)  # [B, k]
-        partial = jax.lax.dot_general(
-            rhs_t, onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # [ROWS, k]
-        col = wids_ref[pl.program_id(1)] * k
-        out_ref[:, pl.ds(col, k)] += partial
+            @pl.when((pl.program_id(0) == 0) & (step == 0))
+            def _init():
+                out_ref[:] = jnp.zeros_like(out_ref)
+
+            seg_iota = jax.lax.broadcasted_iota(jnp.int32, (k, block), 0)
+
+            def fold(j):
+                """Staged block ``j`` of this step into its window."""
+                rows = pl.ds(pl.multiple_of(j * block, block), block)
+                sid = sid_ref[rows].reshape(1, block)   # window-local
+                # [6, B] f32 -> shared bf16 rhs build (same split as the
+                # unsorted kernel, so the two cannot diverge numerically)
+                rhs_t = _build_rhs_t(planes_ref[:, rows], block, n_hist)
+                onehot_t = (seg_iota == sid).astype(jnp.bfloat16)  # [k, B]
+                partial = jax.lax.dot_general(
+                    rhs_t, onehot_t, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)         # [ROWS, k]
+                col = pl.multiple_of(wids_ref[step * S + j] * k, k)
+                out_ref[:, pl.ds(col, k)] += partial
+
+            def fold_group(i, carry):
+                for u in range(GROUP):
+                    fold(GROUP * i + u)
+                return carry
+
+            if S <= GROUP:
+                for j in range(S):
+                    fold(j)
+            else:
+                jax.lax.fori_loop(0, S // GROUP, fold_group, 0)
+
+        return kernel
 
     @jax.jit
     def run(sid_local, planes, wids):
@@ -310,19 +367,21 @@ def make_pallas_replay_sorted_fn(n_segments: int, n_hist: int = 16,
         assert planes.shape == (N_PLANES, t), \
             "planes must be feature-major [6, T]"
         assert t % block == 0, f"span count {t} must be a multiple of {block}"
-        assert wids.shape == (t // block,)
+        n_blocks = t // block
+        assert wids.shape == (n_blocks,)
         if t == 0:
             # zero-block grid would skip the init step and return garbage
             return jnp.zeros((n_segments, N_PLANES + n_hist), jnp.float32)
-        grid = (inner_repeats, t // block)
+        S = next(s for s in (8, 4, 2, 1) if n_blocks % s == 0)
         acc = pl.pallas_call(
-            kernel,
+            make_kernel(S),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
-                grid=grid,
+                grid=(inner_repeats, n_blocks // S),
                 in_specs=[
-                    pl.BlockSpec((block,), lambda r, i, w: (i,)),
-                    pl.BlockSpec((N_PLANES, block), lambda r, i, w: (0, i)),
+                    pl.BlockSpec((S * block,), lambda r, i, w: (i,)),
+                    pl.BlockSpec((N_PLANES, S * block),
+                                 lambda r, i, w: (0, i)),
                 ],
                 out_specs=pl.BlockSpec((ROWS, NWK), lambda r, i, w: (0, 0)),
             ),

@@ -1200,7 +1200,12 @@ def stage_pallas_planes(chunks, xp=np):
 def pallas_block(chunk_size: int) -> int:
     """Pallas kernel block size for a staged corpus: must divide the span
     count (a chunk_size multiple) — chunk_size's largest power-of-2 factor,
-    capped at the VMEM-tuned 4096."""
+    capped at 4096.  For the unsorted kernel that cap keeps its
+    ``[block, SW+1]`` bfloat16 one-hot inside VMEM.  For the sorted-window
+    kernel it is the STAGING granularity and no longer the size of a grid
+    step: ``stage_sorted_planes`` pads each window's span run to a
+    multiple of it (a smaller block pads less, a larger one reads fewer
+    window ids), and the kernel folds up to eight such blocks a step."""
     block = min(4096, chunk_size & -chunk_size)
     if block < 128:
         raise ValueError(

@@ -43,12 +43,20 @@ def _case_replay():
 
 
 def _case_replay_sorted():
+    """The replay cell's own call (272 copies of 128 staged blocks: eight
+    blocks a grid step, the loop's SMEM reads of the window ids and the
+    dynamic column slice), a block count no power of two divides (one
+    block a step, no loop), and the on-device replication of
+    ``measure_throughput`` (four a step).  Shapes only: lowering allocates
+    nothing."""
     from anomod.ops.pallas_replay import make_pallas_replay_sorted_fn
-    t = 12 * BENCH_BLOCK
-    fn = make_pallas_replay_sorted_fn(BENCH_SW, N_HIST, block=BENCH_BLOCK,
-                                      inner_repeats=4096)
-    lower_for_tpu(fn, SDS((t,), I32), SDS((6, t), F32),
-                  SDS((t // BENCH_BLOCK,), I32))
+    for n_blocks, reps in ((272 * 128, 1), (11_605, 1), (12, 4096)):
+        t = n_blocks * BENCH_BLOCK
+        fn = make_pallas_replay_sorted_fn(BENCH_SW, N_HIST, block=BENCH_BLOCK,
+                                          inner_repeats=reps)
+        text = lower_for_tpu(fn, SDS((t,), I32), SDS((6, t), F32),
+                             SDS((n_blocks,), I32)).as_text()
+        assert text.count("tpu_custom_call") == 1
 
 
 def _case_lane_delta():

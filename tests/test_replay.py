@@ -133,17 +133,43 @@ def test_sorted_staging_reconstructs_segments():
     np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
-def test_pallas_sorted_kernel_matches_oracle():
+#: (blocks a window, inner_repeats, blocks a grid step) of a 5-window
+#: corpus (SW 600, k 128), or no list for 5,000 uniform spans.  The kernel
+#: folds S staged blocks a grid step, S the largest of 8, 4, 2, 1 that
+#: divides the block count.
+SORTED_CASES = {
+    "uniform-repeats2": (None, 2, None),
+    "S8-five-windows-in-one-step": ([3, 2, 1, 1, 1], 1, 8),
+    "S8-two-steps-repeats2": ([5, 4, 3, 2, 2], 2, 8),
+    "S4": ([3, 3, 2, 2, 2], 1, 4),
+    "S2-an-empty-window": ([1, 2, 1, 2, 0], 1, 2),
+    "S1-seven-blocks": ([3, 1, 1, 1, 1], 1, 1),
+    "S1-eleven-blocks-repeats2": ([5, 3, 1, 1, 1], 2, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SORTED_CASES))
+def test_pallas_sorted_kernel_matches_oracle(case):
     """The sorted-window kernel (interpret path) reproduces the unsorted
     oracle: 0/1 planes + histogram exactly, moments within the hi/lo
-    bound — including device-side replication via inner_repeats."""
+    bound — at every number of staged blocks a grid step, with window
+    boundaries inside a step (each block of a step reads its own window
+    id), and with device-side replication via inner_repeats."""
     from anomod.ops.pallas_replay import (make_pallas_replay_sorted_fn,
                                           pallas_replay_numpy,
                                           stage_sorted_planes)
+    blocks, repeats, per_step = SORTED_CASES[case]
     rng = np.random.default_rng(7)
     SW, H, K, BLOCK = 600, 16, 128, 256
-    n = 5000
-    sid = rng.integers(0, SW + 1, n).astype(np.int32)
+    if blocks is None:
+        sid = rng.integers(0, SW + 1, 5000).astype(np.int32)
+    else:
+        # a window's span count pads to exactly its block count
+        sid = rng.permutation(np.concatenate([
+            rng.integers(w * K, min((w + 1) * K, SW + 1),
+                         (nb - 1) * BLOCK + int(rng.integers(1, BLOCK + 1)))
+            for w, nb in enumerate(blocks) if nb])).astype(np.int32)
+    n = sid.shape[0]
     valid = (rng.random(n) < 0.9).astype(np.float32)
     dur_us = rng.lognormal(8.0, 1.0, n).astype(np.float32) * valid
     dur = np.log1p(dur_us)
@@ -155,10 +181,16 @@ def test_pallas_sorted_kernel_matches_oracle():
     ])
     sid_l, planes_s, wids = stage_sorted_planes(sid, planes, SW,
                                                 k=K, block=BLOCK)
+    if blocks is not None:
+        assert np.bincount(wids, minlength=len(blocks)).tolist() == blocks
+        assert per_step == next(s for s in (8, 4, 2, 1)
+                                if wids.shape[0] % s == 0)
+        if per_step > 1:                        # a step straddles windows
+            assert len(set(wids[:per_step].tolist())) > 1
     fn = make_pallas_replay_sorted_fn(SW, H, k=K, block=BLOCK,
-                                      interpret=True, inner_repeats=2)
+                                      interpret=True, inner_repeats=repeats)
     got = np.asarray(fn(sid_l, planes_s, wids))
-    want = pallas_replay_numpy(sid, planes, SW, H) * 2
+    want = pallas_replay_numpy(sid, planes, SW, H) * repeats
     np.testing.assert_array_equal(got[:, :3], want[:, :3])    # exact planes
     np.testing.assert_array_equal(got[:, 6:], want[:, 6:])    # histogram
     np.testing.assert_allclose(got[:, 3:6], want[:, 3:6],     # hi/lo bound

@@ -144,6 +144,48 @@ def test_replay_sorted_kernel_compiled(tt_corpus):
                                atol=3e-2)
 
 
+@pytest.mark.parametrize("n_blocks,per_step", [(16, 8), (12, 4), (7, 1)])
+def test_replay_sorted_kernel_blocks_a_step_compiled(tt_corpus, n_blocks,
+                                                     per_step):
+    """The sorted-window kernel folds ``per_step`` staged blocks a grid
+    step (the largest of 8, 4, 2, 1 that divides the block count) and a
+    step straddles window boundaries: each block of the step reads its
+    own window id from SMEM and adds into its own columns.  Compiled,
+    against float64 over the very rows handed to it: 0/1 planes and
+    histogram exact, latency moments < 1e-4 (the lo term kept through
+    the segment-major one-hot's masked pushes)."""
+    from anomod.ops.pallas_replay import (make_pallas_replay_sorted_fn,
+                                          pallas_replay_numpy,
+                                          stage_sorted_planes)
+    from anomod.replay import pallas_block, stage_pallas_planes
+
+    _, cfg, chunks, _ = tt_corpus
+    block = pallas_block(cfg.chunk_size)
+    sid_l, planes_s, wids = stage_sorted_planes(
+        *stage_pallas_planes(chunks), cfg.sw, block=block)
+    assert wids.shape[0] >= n_blocks
+    # staged arrays are whole blocks: the first n_blocks are a corpus too
+    sid_l, planes_s, wids = (sid_l[:n_blocks * block],
+                             planes_s[:, :n_blocks * block], wids[:n_blocks])
+    assert next(s for s in (8, 4, 2, 1) if n_blocks % s == 0) == per_step
+    if per_step > 1:
+        assert len(set(wids[:per_step].tolist())) > 1
+    fn = make_pallas_replay_sorted_fn(cfg.sw, cfg.n_hist_buckets,
+                                      block=block)
+    out = np.asarray(fn(sid_l, planes_s, wids))
+    seg = sid_l + np.repeat(wids, block) * 128
+    want = pallas_replay_numpy(seg, planes_s, cfg.sw, cfg.n_hist_buckets)
+    np.testing.assert_array_equal(out[:, :3], want[:, :3])
+    np.testing.assert_array_equal(out[:, 6:], want[:, 6:])
+    moments = np.zeros((cfg.sw + 1, 3), np.float64)
+    np.add.at(moments, seg, planes_s[3:6].astype(np.float64).T)
+    moments = moments[:cfg.sw]
+    live = moments[:, 1] > 0
+    assert live.any()
+    rel = np.abs(out[live, 3:6] - moments[live]) / moments[live]
+    assert rel.max() < 1e-4, rel.max()
+
+
 @pytest.mark.parametrize("engine", ["pallas", "matmul"])
 def test_lane_delta_kernel_compiled(engine):
     """The serving plane's lane-stacked score step at serve shapes —
