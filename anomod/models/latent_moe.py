@@ -31,6 +31,8 @@ import math
 
 import numpy as np
 
+from anomod.models import seqcommon
+from anomod.models.seqcommon import rmsnorm  # noqa: F401  (the layers' norm)
 from anomod.ops import latent_attention as la
 from anomod.ops import routed_experts as rx
 
@@ -68,11 +70,7 @@ class DecoderConfig:
     def from_dict(cls, d: dict) -> "DecoderConfig":
         """From a configuration file: the public keys at the top level,
         the sizes this repo set under ``assumed``."""
-        flat = dict(d)
-        flat.update({k: v for k, v in d.get("assumed", {}).items()
-                     if not isinstance(v, (dict, list, str))})
-        flat.setdefault("vocab_held", flat["vocab_size"])
-        flat.setdefault("experts_held", flat["n_routed_experts"])
+        flat = seqcommon.flat_spec(d)
         kw = {f.name: flat[f.name] for f in dataclasses.fields(cls)
               if f.name in flat}
         kw["rope_scaling"] = tuple(sorted(
@@ -181,14 +179,6 @@ def rope(x, pos, inv_freq, amplitude: float = 1.0):
                            axis=-1).astype(x.dtype)
 
 
-def rmsnorm(x, w, eps: float):
-    import jax
-    import jax.numpy as jnp
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return (y * w).astype(x.dtype)
-
-
 # -- parameters ---------------------------------------------------------------
 
 def param_shapes(cfg: DecoderConfig) -> dict:
@@ -233,66 +223,20 @@ F32_LEAVES = ("router", "router_bias", "attn_norm", "ffn_norm", "q_norm",
               "kv_norm", "final_norm")
 
 
-def _lowbias32(x):
-    import jax.numpy as jnp
-    x = x ^ (x >> jnp.uint32(16))
-    x = x * jnp.uint32(0x7FEB352D)
-    x = x ^ (x >> jnp.uint32(15))
-    x = x * jnp.uint32(0x846CA68B)
-    return x ^ (x >> jnp.uint32(16))
-
-
 def init_params(cfg: DecoderConfig, seed: int, dtype=None) -> dict:
-    """Seeded weights made on the device in one program, each element an
-    integer hash of its index and the leaf's key (uniform, unit variance):
-    a matrix over ``fan_in ** 0.5``, a norm weight ``1 + 0.1 u``, the
-    router's bias ``0.1 u``.  The same seed gives the same weights on
-    every backend; a stack's experts are the held experts of THIS draw
-    only (another share is another seed)."""
-    import jax
+    """Seeded weights made on the device in one program by the plane's
+    rule (:func:`anomod.models.seqcommon.draw_params`): a matrix over
+    ``fan_in ** 0.5``, a norm weight ``1 + 0.1 u``, the router's bias
+    ``0.1 u``.  A stack's experts are the held experts of THIS draw only
+    (another share is another seed)."""
     import jax.numpy as jnp
-    from anomod.replay import named_jit
-    dtype = dtype or jnp.bfloat16
-    seed = int(seed)
-    base = (seed ^ (seed >> 32)) & 0xFFFFFFFF
-    flat = []
-    for name, spec in param_shapes(cfg).items():
-        flat += ([((name, k), s) for k, s in spec.items()]
-                 if isinstance(spec, dict) else [((name,), spec)])
-
-    def make():
-        out = {}
-        for n, (path, (shape, fan)) in enumerate(flat):
-            size = int(np.prod(shape))
-            key = jnp.uint32((base + (n + 1) * 0x9E3779B9) & 0xFFFFFFFF)
-            h = _lowbias32(_lowbias32(jax.lax.iota(jnp.uint32, size)) ^ key)
-            u = ((h >> jnp.uint32(8)).astype(jnp.float32) * 2.0 ** -24
-                 - 0.5) * 12.0 ** 0.5
-            if fan is None:
-                w = 1.0 + 0.1 * u
-            elif fan == "bias":
-                w = 0.1 * u
-            else:
-                w = (u * fan ** -0.5).astype(
-                    jnp.float32 if path[-1] in F32_LEAVES else dtype)
-            node = out
-            for k in path[:-1]:
-                node = node.setdefault(k, {})
-            node[path[-1]] = w.reshape(shape)
-        out.setdefault("dense", {})
-        out.setdefault("moe", {})
-        return out
-
-    return named_jit("anomod_seq_init", make)()
+    return seqcommon.draw_params(
+        param_shapes(cfg), seed, dtype or jnp.bfloat16, F32_LEAVES,
+        groups=("dense", "moe"))
 
 
 def param_count(cfg: DecoderConfig) -> int:
-    total = 0
-    for spec in param_shapes(cfg).values():
-        for shape, _ in (spec.values() if isinstance(spec, dict)
-                         else [spec]):
-            total += int(np.prod(shape))
-    return total
+    return seqcommon.param_count(param_shapes(cfg))
 
 
 # -- the serving step ---------------------------------------------------------
@@ -315,21 +259,16 @@ def empty_plan(cfg: DecoderConfig, caps: dict, trash_row: int) -> dict:
     """A plan of no work at ``caps`` (numpy, int32): every token a pad
     that writes the never-read slot 0 and reads nothing; every segment
     leaves its hidden state in ``trash_row`` of ``h_last``."""
-    T, S, G, P = (caps[k] for k in ("tokens", "segments", "groups", "pairs"))
+    T, G, P = (caps[k] for k in ("tokens", "groups", "pairs"))
     z = lambda n: np.zeros((n,), np.int32)
-    return {
-        "tok_id": z(T), "tok_pos": z(T),
-        "tok_seg": np.full((T,), -1, np.int32),
-        "tok_slot": z(T), "tok_ctx": np.full((T,), -1, np.int32),
-        "tok_expanded": z(T),
-        "seg_blocks": np.zeros((S + 1, cfg.session_blocks), np.int32),
-        "last_src": z(S), "last_row": np.full((S,), trash_row, np.int32),
-        "groups": {"tok0": z(G), "ntok": z(G), "seg": z(G), "nblk": z(G),
-                   "n_groups": np.int32(0)},
-        "pairs": {"seg": z(P), "q0": z(P), "n_tiles": z(P), "blk0": z(P),
-                  "n_pairs": np.int32(0)},
-        "audit": z(caps["audit"]),
-    }
+    return dict(
+        seqcommon.empty_token_plan(T, caps["segments"], cfg.session_blocks,
+                                   caps["audit"], trash_row),
+        tok_expanded=z(T),
+        groups={"tok0": z(G), "ntok": z(G), "seg": z(G), "nblk": z(G),
+                "n_groups": np.int32(0)},
+        pairs={"seg": z(P), "q0": z(P), "n_tiles": z(P), "blk0": z(P),
+               "n_pairs": np.int32(0)})
 
 
 def _pad_rows(a, n):
@@ -413,8 +352,8 @@ def moe_parts(cfg: DecoderConfig, lp: dict, h, valid, capacity: int):
         h, lp["router"], lp["router_bias"], cfg.num_experts_per_tok,
         cfg.routed_scaling_factor, cfg.norm_topk_prob)
     routed, counts = rx.held_expert_sum(
-        h, experts, weights, valid, lp["e_gate"], lp["e_up"], lp["e_down"],
-        cfg.experts_lo, capacity)
+        h, experts, weights, valid, rx.gated_silu,
+        (lp["e_gate"], lp["e_up"], lp["e_down"]), cfg.experts_lo, capacity)
     return routed, swiglu(h, lp["s_gate"], lp["s_up"], lp["s_down"]), counts
 
 
@@ -464,28 +403,8 @@ def append_step(cfg: DecoderConfig, params: dict, pool, h_last, plan: dict):
     if cfg.n_moe:
         carry, counts = jax.lax.scan(layer("moe"), carry, params["moe"])
     x, pool, _ = carry
-    hn = rmsnorm(x, params["final_norm"], cfg.rms_norm_eps)
-    # a token's context: the packed token before it, its session's last
-    # hidden state of an earlier step, or nothing
-    ctx_i = plan["tok_ctx"]
-    ctx = jnp.where((ctx_i >= T)[:, None], h_last[jnp.maximum(ctx_i - T, 0)],
-                    hn[jnp.clip(ctx_i, 0, T - 1)])
-    chunk = min(T, 1024)
-
-    def score(args):
-        c, tok = args
-        logits = jnp.dot(c, params["head"],
-                         preferred_element_type=jnp.float32)
-        return jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
-            logits, tok[:, None], axis=1)[:, 0]
-
-    surprisal = jax.lax.map(score, (
-        ctx.reshape(T // chunk, chunk, -1),
-        plan["tok_id"].reshape(T // chunk, chunk))).reshape(T)
-    surprisal = jnp.where(ctx_i < 0, math.log(cfg.vocab_held), surprisal)
-    audit = jnp.dot(hn[plan["audit"]], params["head"],
-                    preferred_element_type=jnp.float32)
-    h_last = h_last.at[plan["last_row"]].set(hn[plan["last_src"]])
+    h_last, surprisal, audit = seqcommon.score_step(
+        x, params, h_last, plan, cfg.rms_norm_eps, cfg.vocab_held)
     return pool.reshape(shape), h_last, surprisal, audit, counts
 
 

@@ -30,16 +30,34 @@ def route(x, w_router, bias, top_k: int, scaling: float, norm_topk: bool):
     return experts.astype(jnp.int32), w * scaling
 
 
-def held_expert_sum(x, experts, weights, valid, w_gate, w_up, w_down,
+def gated_silu(xs, dot, w_gate, w_up, w_down):
+    """An expert's body with three weights: ``(silu(x W_gate) * (x W_up))
+    W_down`` (``w_gate`` / ``w_up`` ``[E, D, F]``, ``w_down`` ``[E, F,
+    D]``); ``dot`` is the grouped matmul over the round's rows."""
+    import jax
+    mid = (jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)).astype(xs.dtype)
+    return dot(mid, w_down)
+
+
+def relu2(xs, dot, w_1, w_2):
+    """An expert's body with two weights, not gated: ``relu(x W_1)^2 W_2``
+    (``w_1`` ``[E, D, F]``, ``w_2`` ``[E, F, D]``)."""
+    import jax.numpy as jnp
+    mid = jnp.square(jnp.maximum(dot(xs, w_1), 0.0)).astype(xs.dtype)
+    return dot(mid, w_2)
+
+
+def held_expert_sum(x, experts, weights, valid, body, expert_weights,
                     lo: int, capacity: int):
     """``sum_e w_e * expert_e(x)`` over the held experts ``[lo, lo + E)``
-    (``w_gate`` / ``w_up`` ``[E, D, F]``, ``w_down`` ``[E, F, D]``), for
-    the ``valid`` rows of ``x`` ``[T, D]``.  Returns ``(out [T, D]
-    float32, tokens per held expert [E] int32)``."""
+    for the ``valid`` rows of ``x`` ``[T, D]``; the expert is ``body(rows,
+    dot, *expert_weights)`` (:func:`gated_silu`, :func:`relu2`), every
+    weight stacked ``[E, ...]``.  Returns ``(out [T, D] float32, tokens
+    per held expert [E] int32)``."""
     import jax
     import jax.numpy as jnp
     T, k = experts.shape
-    E = w_gate.shape[0]
+    E = expert_weights[0].shape[0]
     local = experts - lo
     held = (local >= 0) & (local < E) & valid[:, None]
     local = jnp.where(held, local, E).reshape(-1)         # E sorts last
@@ -63,10 +81,10 @@ def held_expert_sum(x, experts, weights, valid, w_gate, w_up, w_down,
         xs = x[tok]
         dot = lambda a, w: jax.lax.ragged_dot(
             a, w, sizes, preferred_element_type=jnp.float32)
-        mid = (jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)).astype(x.dtype)
         # rows past the held pairs are the kernel's to leave undefined
         y = jnp.where(live[:, None],
-                      dot(mid, w_down) * w_flat[pair][:, None], 0.0)
+                      body(xs, dot, *expert_weights)
+                      * w_flat[pair][:, None], 0.0)
         return out.at[jnp.where(live, tok, T)].add(y, mode="drop")
 
     rounds = (n_held + capacity - 1) // capacity

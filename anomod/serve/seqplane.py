@@ -1,7 +1,10 @@
 """The sequence-model plane of the serve tick: every served span is one
-event token of its tenant's session, scored by a latent-attention,
-routed-expert decoder (:mod:`anomod.models.latent_moe`) as its surprisal
-``-log p(token | the tenant's session so far)``.
+event token of its tenant's session, scored by a decoder as its surprisal
+``-log p(token | the tenant's session so far)``.  The decoder is picked
+by the configuration's ``model_type`` (:data:`MODELS`): a
+latent-attention, routed-expert one (:mod:`anomod.models.latent_moe`) or
+a hybrid of state-space, attention and latent-expert layers
+(:mod:`anomod.models.hybrid_ssm_moe`).
 
 A plane beside ``_rca_step``: it reads the tick's served batches and
 writes nothing the sketch planes read, so states, alerts and shed
@@ -41,14 +44,23 @@ import zlib
 import numpy as np
 
 from anomod.models import latent_moe as lm
+from anomod.models import seqcommon
 from anomod.ops import latent_attention as la
 from anomod.utils.tracing import span_of
 
+#: every plane counts all of these; a model's own read 0 under the other
 COUNTERS = ("seq_tokens", "seq_pairs", "seq_absorbed_tokens",
             "seq_absorbed_pairs", "seq_absorbed_group_blocks",
             "seq_expanded_keys", "seq_keys", "seq_pad_tokens", "seq_steps",
             "expert_tokens_max", "expert_tokens_mean", "sessions_rolled",
-            "sessions_evicted", "pool_blocks_held")
+            "sessions_evicted", "pool_blocks_held",
+            "ssm_recurrent_tokens", "ssm_scan_tokens", "ssm_scan_blocks",
+            "ssm_scan_pairs", "ssm_state_rows", "gqa_pairs", "gqa_keys", "state_slots_held",
+            "sessions_evicted_by_slots", "steps_split_by_slots")
+#: counters that hold the table's present count, not a sum over steps
+GAUGES = ("sessions_rolled", "sessions_evicted", "pool_blocks_held",
+          "state_slots_held", "sessions_evicted_by_slots",
+          "steps_split_by_slots")
 N_STATUS, N_KIND = 4, 3
 #: logits rows kept for each audit tenant, the newest
 AUDIT_KEEP = 32
@@ -71,38 +83,58 @@ def tokenise(service, duration_us, status, kind, n_hist: int) -> np.ndarray:
 
 
 class Session:
-    __slots__ = ("blocks", "length", "number")
+    __slots__ = ("blocks", "length", "number", "slot")
 
     def __init__(self, number: int):
-        self.blocks, self.length, self.number = [], 0, number
+        self.blocks, self.length, self.number, self.slot = [], 0, number, 0
 
 
 class SessionTable:
-    """Sessions, their blocks and the bounded-memory policy.  ``append``
-    takes one step's ``(tenant, n_tokens)`` chunks in ascending tenant
-    order and returns its segments ``(tenant, session number, start
-    position, n, blocks)``; blocks of a session that rolled inside the
-    step are freed when the step has been planned (the step still reads
-    them)."""
+    """Sessions, their blocks, their state slots and the bounded-memory
+    policy.  ``append`` takes one step's ``(tenant, n_tokens)`` chunks in
+    ascending tenant order and returns its segments ``(tenant, session
+    number, start position, n, blocks)``, with the session's slot as a
+    sixth where the table has ``state_slots``; blocks and slot of a
+    session that rolled inside the step are freed when the step has been
+    planned (the step still reads them), so a session begun in a step
+    takes a slot of its own.  ``place`` cuts a tick's chunks into steps
+    the slots can hold.  Without ``state_slots`` the table knows blocks
+    only."""
 
     def __init__(self, n_blocks: int, context_tokens: int,
-                 block_tokens: int):
+                 block_tokens: int, state_slots: int = None):
         self.context, self.block = int(context_tokens), int(block_tokens)
         self.free = collections.deque(range(1, int(n_blocks)))
         self.usable = len(self.free)
+        self.free_slots = None if state_slots is None \
+            else collections.deque(range(1, int(state_slots)))
+        self.usable_slots = 0 if state_slots is None \
+            else len(self.free_slots)
         self.sessions = collections.OrderedDict()   # tenant -> Session, LRU
         self.started = {}                           # tenant -> sessions begun
         self.rolled = self.evicted = 0
+        self.evicted_by_slots = self.steps_split = 0
 
     @property
     def blocks_held(self) -> int:
         return self.usable - len(self.free)
 
+    @property
+    def slots_held(self) -> int:
+        return self.usable_slots - len(self.free_slots or ())
+
     def _begin(self, tenant: int) -> Session:
         n = self.started.get(tenant, 0)
         self.started[tenant] = n + 1
         s = self.sessions[tenant] = Session(n)
+        if self.free_slots is not None:
+            s.slot = self.free_slots.popleft()
         return s
+
+    def _end(self, s: Session) -> None:
+        self.free.extend(s.blocks)
+        if self.free_slots is not None:
+            self.free_slots.append(s.slot)
 
     def _needed(self, tenant: int, n: int) -> int:
         """Blocks ``n`` more tokens of ``tenant`` take now (a session
@@ -118,29 +150,65 @@ class SessionTable:
                 if length + take < self.context else (0, 0)
         return need
 
+    def _begun(self, tenant: int, n: int) -> int:
+        """Sessions ``n`` more tokens of ``tenant`` begin now, each of
+        which takes a slot (a live one goes on in its own)."""
+        s = self.sessions.get(tenant)
+        if s is None:
+            return -(-n // self.context)
+        return -(-max(n - (self.context - s.length), 0) // self.context)
+
+    def place(self, chunks: list) -> list:
+        """A tick's chunks in ascending tenant order as the segments of
+        one step or, where the sessions they touch outnumber the slots,
+        of further steps."""
+        if self.free_slots is None:
+            return [self.append(chunks)]
+        touched = lambda t, n: self._begun(t, n) + (t in self.sessions)
+        steps, cur, demand = [], [], 0
+        for t, n in chunks:
+            d = touched(t, n)
+            if cur and demand + d > self.usable_slots:
+                steps.append(self.append(cur))
+                self.steps_split += 1
+                cur, demand, d = [], 0, touched(t, n)
+            cur.append((t, n))
+            demand += d
+        return steps + [self.append(cur)]
+
     def append(self, chunks: list) -> list:
         for t, _ in chunks:
             if t in self.sessions:
                 self.sessions.move_to_end(t)
         # room first: end the least recently appended sessions (this
         # step's own count as appended now, in tenant order) until the
-        # step's blocks are free; nothing ends while it is placed
+        # step's blocks and slots are free; nothing ends while it is placed
         need = {t: self._needed(t, n) for t, n in chunks}
         short = sum(need.values()) - len(self.free)
+        slots = self.free_slots is not None
+        begun = {t: self._begun(t, n) for t, n in chunks} if slots else {}
+        short_slots = sum(begun.values()) - len(self.free_slots) \
+            if slots else 0
         sizes = dict(chunks)
-        while short > 0:
+        while short > 0 or short_slots > 0:
             if not self.sessions:
                 raise RuntimeError(
-                    "the latent pool cannot hold one step's tokens")
+                    "the pools cannot hold one step's tokens")
             victim, s = next(iter(self.sessions.items()))
             del self.sessions[victim]
-            self.free.extend(s.blocks)
+            self._end(s)
             self.evicted += 1
+            self.evicted_by_slots += short_slots > 0
             short -= len(s.blocks)
+            short_slots -= slots
             if victim in need:
                 fresh = self._needed(victim, sizes[victim])
                 short += fresh - need[victim]
                 need[victim] = fresh
+                if slots:
+                    again = self._begun(victim, sizes[victim])
+                    short_slots += again - begun[victim]
+                    begun[victim] = again
         segments, ended = [], []
         for tenant, n in chunks:
             while n > 0:
@@ -149,14 +217,15 @@ class SessionTable:
                 for _ in range(-(-(s.length + take) // self.block)
                                - len(s.blocks)):
                     s.blocks.append(self.free.popleft())
-                segments.append((tenant, s.number, s.length, take, s.blocks))
+                segments.append((tenant, s.number, s.length, take, s.blocks)
+                                + ((s.slot,) if slots else ()))
                 s.length += take
                 n -= take
                 if s.length == self.context:
-                    ended.append(self.sessions.pop(tenant).blocks)
+                    ended.append(self.sessions.pop(tenant))
                     self.rolled += 1
-        for blocks in ended:
-            self.free.extend(blocks)
+        for s in ended:
+            self._end(s)
         for t, _ in chunks:             # same-step sessions: by tenant id
             if t in self.sessions:
                 self.sessions.move_to_end(t)
@@ -176,32 +245,12 @@ def build_plan(cfg, caps: dict, segments: list, tokens: np.ndarray,
     ``plan["audit"]``."""
     plan = lm.empty_plan(cfg, caps, len(tenant_ids))
     S, n_tok = len(segments), len(tokens)
-    tenant, number, start, n, blocks = zip(*segments)
-    tenant, start, n = (np.asarray(a, np.int64) for a in (tenant, start, n))
-    off = np.concatenate([[0], np.cumsum(n)[:-1]])
-    row = np.searchsorted(tenant_ids, tenant)
-    for s, b in enumerate(blocks):
-        plan["seg_blocks"][s, :len(b)] = b
-    seg = np.repeat(np.arange(S), n)
-    pos = start[seg] + np.arange(n_tok) - off[seg]
-    first = np.arange(n_tok) == off[seg]
-    plan["tok_id"][:n_tok] = tokens
-    plan["tok_pos"][:n_tok] = pos
-    plan["tok_seg"][:n_tok] = seg
-    plan["tok_slot"][:n_tok] = plan["seg_blocks"][
-        seg, pos // cfg.block_tokens] * cfg.block_tokens \
-        + pos % cfg.block_tokens
-    plan["tok_ctx"][:n_tok] = np.where(
-        first, np.where(pos > 0, caps["tokens"] + row[seg], -1),
-        np.arange(n_tok) - 1)
-    # the last segment of a tenant in this step leaves its hidden state
-    last = np.ones(S, bool)
-    last[:-1] = tenant[1:] != tenant[:-1]
-    plan["last_src"][:S] = np.where(last, off + n - 1, 0)
-    plan["last_row"][:S] = np.where(last, row, len(tenant_ids))
+    f = seqcommon.fill_token_plan(plan, caps, cfg.block_tokens, segments,
+                                  tokens, tenant_ids, audit)
+    start, n, off, total, seg = (f[k] for k in ("start", "n", "off",
+                                                "total", "seg"))
     # the form of each segment, from sizes alone; the longest first into
     # the pair list, whatever it cannot hold stays absorbed
-    total = start + n
     dims = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
             cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank)
     expanded = np.zeros(S, bool)
@@ -237,12 +286,6 @@ def build_plan(cfg, caps: dict, segments: list, tokens: np.ndarray,
         g["tok0"][:G], g["ntok"][:G] = g_tok0[order], g_ntok[order]
         g["seg"][:G], g["nblk"][:G] = g_seg[order], g_nblk[order]
         g["n_groups"] = np.int32(G)
-    rows = [s for s in range(S) if last[s] and int(tenant[s]) in audit][
-        :caps["audit"]]
-    for i, s in enumerate(rows):
-        plan["audit"][i] = off[s] + n[s] - 1
-    audit_rows = [(int(tenant[s]), number[s], int(total[s]) - 1)
-                  for s in rows]
     pairs_of = n * start + n * (n + 1) // 2
     return plan, {"seq_tokens": n_tok, "seq_pairs": int(pairs_of.sum()),
                   "seq_absorbed_tokens": int(n[~expanded].sum()),
@@ -250,7 +293,77 @@ def build_plan(cfg, caps: dict, segments: list, tokens: np.ndarray,
                   "seq_absorbed_group_blocks":
                       int(plan["groups"]["nblk"].sum()),
                   "seq_expanded_keys": int(total[expanded].sum()),
-                  "seq_keys": int(total.sum())}, audit_rows
+                  "seq_keys": int(total.sum())}, f["audit_rows"]
+
+
+class LatentMoE:
+    """What the plane asks of a model (configuration, weights, pools,
+    plan, step), for :mod:`anomod.models.latent_moe`."""
+
+    def __init__(self, spec: dict):
+        self.cfg = lm.DecoderConfig.from_dict(spec)
+        self.state_slots = None
+
+    def init_params(self, seed: int) -> dict:
+        return lm.init_params(self.cfg, seed)
+
+    def init_state(self, n_tenants: int) -> dict:
+        import jax.numpy as jnp
+        cfg = self.cfg
+        return {"pool": jnp.zeros((cfg.num_hidden_layers, cfg.pool_blocks,
+                                   cfg.block_tokens, cfg.pool_row_width),
+                                  jnp.bfloat16),
+                "h_last": jnp.zeros((n_tenants + 1, cfg.hidden_size),
+                                    jnp.bfloat16)}
+
+    def caps(self, tokens: int, segments: int) -> dict:
+        return lm.plan_caps(self.cfg, tokens, segments)
+
+    def empty_plan(self, caps: dict, trash_row: int) -> dict:
+        return lm.empty_plan(self.cfg, caps, trash_row)
+
+    def build_plan(self, caps, segments, tokens, tenant_ids, audit):
+        return build_plan(self.cfg, caps, segments, tokens, tenant_ids,
+                          audit)
+
+    def step(self, params: dict, state: dict, plan: dict):
+        pool, h_last, *out = lm.append_step(
+            self.cfg, params, state["pool"], state["h_last"], plan)
+        return ({"pool": pool, "h_last": h_last},) + tuple(out)
+
+
+class HybridSsmMoE:
+    """The same for :mod:`anomod.models.hybrid_ssm_moe`, whose sessions
+    hold a state slot beside their blocks."""
+
+    def __init__(self, spec: dict):
+        from anomod.models import hybrid_ssm_moe as hm
+        self.hm, self.cfg = hm, hm.HybridConfig.from_dict(spec)
+        self.state_slots = self.cfg.state_slots
+
+    def init_params(self, seed: int) -> dict:
+        return self.hm.init_params(self.cfg, seed)
+
+    def init_state(self, n_tenants: int) -> dict:
+        return self.hm.init_state(self.cfg, n_tenants)
+
+    def caps(self, tokens: int, segments: int) -> dict:
+        return self.hm.plan_caps(self.cfg, tokens, segments)
+
+    def empty_plan(self, caps: dict, trash_row: int) -> dict:
+        return self.hm.empty_plan(self.cfg, caps, trash_row)
+
+    def build_plan(self, caps, segments, tokens, tenant_ids, audit):
+        return self.hm.build_plan(self.cfg, caps, segments, tokens,
+                                  tenant_ids, audit)
+
+    def step(self, params: dict, state: dict, plan: dict):
+        return self.hm.append_step(self.cfg, params, state, plan)
+
+
+#: ``model_type`` of a configuration -> its model; a configuration that
+#: names none is the latent-attention decoder's
+MODELS = {"nemotron_h": HybridSsmMoE}
 
 
 class SeqPlane:
@@ -260,12 +373,12 @@ class SeqPlane:
 
     def __init__(self, spec, tenant_ids, n_services: int, n_hist: int,
                  window_us: int, t0_us: int = 0, tracer=None):
-        import jax.numpy as jnp
         from anomod.replay import named_jit
         if isinstance(spec, str):
             with open(spec) as f:
                 spec = json.load(f)
-        self.cfg = cfg = lm.DecoderConfig.from_dict(spec)
+        self.model = MODELS.get(spec.get("model_type"), LatentMoE)(spec)
+        self.cfg = cfg = self.model.cfg
         if vocab_needed(n_services, n_hist) > cfg.vocab_held:
             raise ValueError(
                 f"{n_services} services x {n_hist} buckets need "
@@ -282,19 +395,15 @@ class SeqPlane:
         self.n_tenants, self.n_hist = len(self.tenant_ids), int(n_hist)
         self.window_us, self.t0_us = int(window_us), int(t0_us)
         self.tracer = tracer
-        self.params = lm.init_params(cfg, int(spec.get("weights_seed", 0)))
-        self.pool = jnp.zeros((cfg.num_hidden_layers, cfg.pool_blocks,
-                               cfg.block_tokens, cfg.pool_row_width),
-                              jnp.bfloat16)
-        self.h_last = jnp.zeros((self.n_tenants + 1, cfg.hidden_size),
-                                jnp.bfloat16)
+        self.params = self.model.init_params(
+            int(spec.get("weights_seed", 0)))
+        #: the donated device state, by name: the attention cache
+        #: ``pool``, ``h_last``, and a model's own beside them
+        self.state = self.model.init_state(self.n_tenants)
         self.table = SessionTable(cfg.pool_blocks, cfg.context_tokens,
-                                  cfg.block_tokens)
-        self._step = named_jit(
-            "anomod_seq_step",
-            lambda params, pool, h_last, plan: lm.append_step(
-                cfg, params, pool, h_last, plan),
-            donate_argnums=(1, 2))
+                                  cfg.block_tokens, self.model.state_slots)
+        self._step = named_jit("anomod_seq_step", self.model.step,
+                               donate_argnums=(1,))
         self.counters = dict.fromkeys(COUNTERS, 0)
         #: (tenant, window, spans, mean surprisal, max surprisal) of
         #: closed windows, the newest last (bounded)
@@ -308,23 +417,30 @@ class SeqPlane:
             maxlen=AUDIT_KEEP * max(len(self.audit), 1))
         self.tick_doc = None
 
+    # the attention cache and the last hidden states by their old names
+    pool = property(lambda self: self.state.get("pool"),
+                    lambda self, v: self.state.__setitem__("pool", v))
+    h_last = property(lambda self: self.state.get("h_last"),
+                      lambda self, v: self.state.__setitem__("h_last", v))
+
     def caps(self, tokens: int) -> dict:
-        return lm.plan_caps(self.cfg, tokens, 2 * self.n_tenants + 64)
+        return self.model.caps(tokens, 2 * self.n_tenants + 64)
 
     def warm(self) -> None:
         """Compile every grid size (a step of pads each)."""
         for t in self.grid:
-            self._run(lm.empty_plan(self.cfg, self.caps(t), self.n_tenants))
+            self._run(self.model.empty_plan(self.caps(t), self.n_tenants))
 
     def _run(self, plan: dict):
         import jax
-        self.pool, self.h_last, surprisal, audit, counts = self._step(
-            self.params, self.pool, self.h_last, jax.device_put(plan))
+        self.state, surprisal, audit, counts = self._step(
+            self.params, self.state, jax.device_put(plan))
         return np.asarray(surprisal), audit, np.asarray(counts)
 
     def close(self) -> None:
-        """Free the device state (the pool first)."""
-        self.pool = self.h_last = self.params = None
+        """Free the device state (the pools first)."""
+        self.state = {}
+        self.params = None
 
     # -- one tick ---------------------------------------------------------
 
@@ -345,9 +461,14 @@ class SeqPlane:
             tenants = np.repeat([served[i].tenant_id for i in order],
                                 [served[i].n_spans for i in order])
             ids, counts = np.unique(tenants, return_counts=True)
-            segments = self.table.append(list(zip(ids.tolist(),
-                                                  counts.tolist())))
-            steps = self._plan_steps(segments, tokens)
+            placed = self.table.place(list(zip(ids.tolist(),
+                                               counts.tolist())))
+            segments = [seg for step in placed for seg in step]
+            steps, at = [], 0
+            for step in placed:
+                n = sum(seg[3] for seg in step)
+                steps += self._plan_steps(step, tokens[at:at + n])
+                at += n
         with span_of(self.tracer, "serve.seq_model", steps=len(steps)):
             surprisal = []
             for plan, stats, audit_rows, pad in steps:
@@ -371,23 +492,28 @@ class SeqPlane:
             closed = self._roll_up(tenants, starts, surprisal)
             if self.audit:
                 at = 0
-                for tenant, number, start, n, _ in segments:
+                for tenant, number, start, n, *_ in segments:
                     if tenant in self.audit:
                         self.audit_segments.append(
                             (tenant, number, start, tokens[at:at + n],
                              surprisal[at:at + n]))
                     at += n
-            c["sessions_rolled"] = self.table.rolled
-            c["sessions_evicted"] = self.table.evicted
-            c["pool_blocks_held"] = self.table.blocks_held
+            table = self.table
+            c.update(zip(GAUGES, (
+                table.rolled, table.evicted, table.blocks_held,
+                table.slots_held, table.evicted_by_slots,
+                table.steps_split)))
             self.tick_doc = {
                 "tokens": int(len(tokens)), "steps": len(steps),
                 "absorbed_group_blocks": sum(
-                    step[1]["seq_absorbed_group_blocks"] for step in steps),
+                    step[1].get("seq_absorbed_group_blocks", 0)
+                    for step in steps),
                 "windows_closed": closed,
                 "sessions_rolled": self.table.rolled,
                 "sessions_evicted": self.table.evicted,
                 "blocks_held": self.table.blocks_held,
+                **({"slots_held": self.table.slots_held}
+                   if self.model.state_slots else {}),
                 "digest": zlib.crc32(surprisal.tobytes())}
 
     def _plan_steps(self, segments: list, tokens: np.ndarray) -> list:
@@ -399,18 +525,18 @@ class SeqPlane:
         steps, cur, room, at = [], [], biggest, 0
         queue = collections.deque(segments)
         while queue:
-            tenant, number, start, n, blocks = queue.popleft()
+            tenant, number, start, n, *held = queue.popleft()
             take = min(n, room)
-            cur.append((tenant, number, start, take, blocks))
+            cur.append((tenant, number, start, take, *held))
             room -= take
             if take < n:
                 queue.appendleft((tenant, number, start + take, n - take,
-                                  blocks))
+                                  *held))
             if not room or len(cur) == seg_cap or not queue:
                 used = biggest - room
                 size = next(t for t in self.grid if t >= used)
-                steps.append(build_plan(
-                    self.cfg, self.caps(size), cur, tokens[at:at + used],
+                steps.append(self.model.build_plan(
+                    self.caps(size), cur, tokens[at:at + used],
                     self.tenant_ids, self.audit) + (size - used,))
                 at += used
                 cur, room = [], biggest

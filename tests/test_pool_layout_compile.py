@@ -222,3 +222,70 @@ def test_seq_step_absorbed_form_is_one_mosaic_kernel(one_chip, optimizing):
     # chip's 16.9e9 with 0.5e9 left for the sketch planes' state
     assert mem.temp_size_in_bytes < 0.55 * pool_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
+
+
+# -- the hybrid model's two kinds of cache (PR 34) ----------------------------
+
+def test_hybrid_step_writes_the_slot_pool_and_the_kv_pool_in_place(
+        one_chip, optimizing):
+    """The serving step of ``n3s-fleet-overload`` at its published widths
+    and its real pools (640 state slots of 10.8 MB, 524,288 cached
+    tokens), compiled for the chip at the token grid's largest size:
+    every donated pool comes back in its own buffer, no op copies a
+    slot pool whole (its minor dimension is the state's 128, a slot's
+    convolution tail one row of 30,720), both named calls reach the
+    device ops' metadata, and everything fits the chip beside the
+    weights."""
+    import json
+    import os
+
+    import numpy as np
+
+    from anomod.models import hybrid_ssm_moe as hm
+    from anomod.ops import gqa_attention, ssm_scan
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron3-super-ep8-share.json")) as f:
+        spec = json.load(f)
+    cfg = hm.HybridConfig.from_dict(spec)
+
+    def sds(shape, dtype):
+        return SDS(tuple(shape), dtype, sharding=one_chip)
+
+    params = {}
+    for name, leaf in hm.param_shapes(cfg).items():
+        floats = lambda k, rule: jnp.float32 if (
+            k in hm.F32_LEAVES or rule is None or rule == "bias"
+            or callable(rule)) else jnp.bfloat16
+        params[name] = (
+            {k: sds(s, floats(k, r)) for k, (s, r) in leaf.items()}
+            if isinstance(leaf, dict) else sds(leaf[0], floats(name,
+                                                               leaf[1])))
+    n_tenants = spec["fleet"]["n_tenants"]
+    caps = hm.plan_caps(cfg, max(spec["assumed"]["token_grid"]),
+                        2 * n_tenants + 64)
+    plan = jax.tree_util.tree_map(
+        lambda a: sds(np.shape(a), jnp.int32), hm.empty_plan(cfg, caps, 0))
+    state = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: hm.init_state(cfg, n_tenants)))
+    assert state["ssm"].shape == (5, 640, 128, 64, 128)
+    assert state["conv"].shape == (5, 640, 30720)
+    assert state["pool"].shape == (1, 4096, 128, 512)
+    step = jax.jit(lambda p, s, plan: hm.append_step(cfg, p, s, plan),
+                   donate_argnums=(1,))
+    compiled = step.lower(params, state, plan).compile()
+    text = compiled.as_text()
+    for shape in ("bf16[5,640,128,64,128]", "bf16[5,640,30720]",
+                  "bf16[3200,30720]", "bf16[524288,512]"):
+        assert not re.search(rf"= {re.escape(shape)}[^=\n]* copy\(", text)
+    assert f"/{ssm_scan.SCOPE}/while/body/" in text
+    assert f"/{gqa_attention.SCOPE}/while/body/" in text
+    mem = compiled.memory_analysis()
+    held = sum(2 * int(np.prod(a.shape)) for a in state.values())
+    assert held > 7.4e9 and mem.alias_size_in_bytes >= held
+    # 1.9e9 of temporaries at 8,192 tokens (the Mamba projection's
+    # float32 output is 0.6e9 of it); weights 5.5e9 and pools 7.5e9
+    assert mem.temp_size_in_bytes < 2.3e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
