@@ -215,7 +215,8 @@ def param_count(cfg: HybridConfig) -> int:
 def plan_caps(cfg: HybridConfig, tokens: int, segments: int) -> dict:
     """Static sizes of a step's plan at ``tokens`` packed tokens."""
     seg = min(tokens, segments)
-    return dict(ss.work_caps(seg), tokens=tokens, segments=seg, audit=64,
+    return dict(ss.work_caps(tokens, seg, cfg.chunk_size), tokens=tokens,
+                segments=seg, audit=64,
                 pairs=ga.pairs_needed(seg, cfg.pool_tokens,
                                       cfg.block_tokens))
 
@@ -228,7 +229,7 @@ def _zero_row(cfg: HybridConfig, caps: dict) -> int:
 
 def empty_plan(cfg: HybridConfig, caps: dict, trash_row: int) -> dict:
     """A plan of no work at ``caps`` (numpy, int32): every token a pad, no
-    pair, no chunk in either scan list; the convolution reads the zero
+    pair, no chunk in the scan's work list; the convolution reads the zero
     row everywhere and every segment row is the never-allocated slot 0."""
     T, S, P = caps["tokens"], caps["segments"], caps["pairs"]
     taps = cfg.conv_kernel - 1
@@ -239,11 +240,10 @@ def empty_plan(cfg: HybridConfig, caps: dict, trash_row: int) -> dict:
                                    trash_row),
         pairs={"seg": z(P), "q0": z(P), "n_tiles": z(P), "blk0": z(P),
                "n_pairs": np.int32(0)},
-        seg={"tok0": z(S + 1), "n": z(S + 1), "slot": z(S + 1),
-             "fresh": z(S + 1)},
+        seg_slot=z(S + 1),
         conv_src=np.full((taps, T), zero_row, np.int32),
         tail_src=np.full((S + 1, taps), zero_row, np.int32),
-        work=ss.empty_work(caps, S))
+        work=ss.empty_work(caps))
 
 
 def build_plan(cfg: HybridConfig, caps: dict, segments: list,
@@ -270,10 +270,8 @@ def build_plan(cfg: HybridConfig, caps: dict, segments: list,
     p["blk0"][:P] = (np.arange(P) - np.repeat(np.cumsum(runs) - runs, runs)
                      ) * ga.KV_BLOCKS
     p["n_pairs"] = np.int32(P)
-    sg = plan["seg"]
-    sg["tok0"][:S], sg["n"][:S] = off, n
-    sg["slot"][:S] = [s[5] for s in segments]
-    sg["fresh"][:S] = start == 0
+    slot = plan["seg_slot"]
+    slot[:S] = [s[5] for s in segments]
     # the convolution's earlier inputs: a packed token of the same chunk,
     # the session's carried tail, or (a fresh session) the zero row
     taps = cfg.conv_kernel - 1
@@ -291,7 +289,8 @@ def build_plan(cfg: HybridConfig, caps: dict, segments: list,
             behind >= 0, off + behind,
             np.where(start == 0, zero_row, tail0 + taps + behind))
     stats = ss.work_lists(
-        plan["work"], n, ss.recurrent_is_cheaper(
+        plan["work"], off, n, slot[:S], start == 0,
+        ss.recurrent_is_cheaper(
             n, cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size,
             cfg.n_groups, cfg.chunk_size), cfg.chunk_size)
     stats.update(seq_tokens=n_tok,
@@ -305,17 +304,18 @@ def build_plan(cfg: HybridConfig, caps: dict, segments: list,
 
 def init_state(cfg: HybridConfig, n_tenants: int, dtype=None) -> dict:
     """The donated device state: the K/V pool, the two slot pools (slot 0
-    is never allocated and takes the pads' writes; a state's minor
-    dimension is the lane tile's 128, a slot's convolution tail one row)
-    and each tenant's last hidden state."""
+    is never allocated and takes the pads' writes; a state is held
+    transposed, ``[state, heads * head_dim]``, as the scan's kernel works
+    on it; a slot's convolution tail is one row) and each tenant's last
+    hidden state."""
     import jax.numpy as jnp
     dtype = dtype or jnp.bfloat16
     n_m, n_a = cfg.count("mamba"), cfg.count("attn")
     return {
         "pool": jnp.zeros((n_a, cfg.pool_blocks, cfg.block_tokens,
                            cfg.kv_row_width), dtype),
-        "ssm": jnp.zeros((n_m, cfg.state_slots, cfg.mamba_num_heads,
-                          cfg.mamba_head_dim, cfg.ssm_state_size), dtype),
+        "ssm": jnp.zeros((n_m, cfg.state_slots, cfg.ssm_state_size,
+                          cfg.d_inner), dtype),
         "conv": jnp.zeros((n_m, cfg.state_slots,
                            (cfg.conv_kernel - 1) * cfg.conv_dim), dtype),
         "h_last": jnp.zeros((n_tenants + 1, cfg.hidden_size), dtype)}
@@ -331,14 +331,13 @@ def mamba_mixer(cfg: HybridConfig, lp: dict, u, plan: dict, ssm, conv,
     import jax.numpy as jnp
     f32 = jnp.float32
     T = u.shape[0]
-    H, P, N, G = (cfg.mamba_num_heads, cfg.mamba_head_dim,
-                  cfg.ssm_state_size, cfg.n_groups)
+    P, N, G = cfg.mamba_head_dim, cfg.ssm_state_size, cfg.n_groups
     di, C, taps = cfg.d_inner, cfg.conv_dim, cfg.conv_kernel - 1
     proj = jnp.dot(u, lp["w_in"], preferred_element_type=f32)
     z, xbc = (proj[:, :di].astype(u.dtype),
               proj[:, di:di + C].astype(u.dtype))
     dt = jax.nn.softplus(proj[:, di + C:] + lp["dt_bias"])
-    slot = plan["seg"]["slot"]
+    slot = plan["seg_slot"]
     src = jnp.concatenate([xbc, conv[layer, slot].reshape(-1, C),
                            jnp.zeros((1, C), u.dtype)])
     w = lp["conv_w"].astype(f32)
@@ -349,19 +348,18 @@ def mamba_mixer(cfg: HybridConfig, lp: dict, u, plan: dict, ssm, conv,
     conv = conv.at[layer, slot].set(
         src[plan["tail_src"]].reshape(-1, taps * C))
     xbc = jax.nn.silu(acc).astype(u.dtype)
-    x = xbc[:, :di].reshape(T, H, P)
-    B = xbc[:, di:di + G * N].reshape(T, G, N)
-    Cm = xbc[:, di + G * N:].reshape(T, G, N)
+    x = xbc[:, :di]
     # a NAMED CALL: its name reaches the device ops' metadata, which is
-    # how a trace reduction finds the recurrence
+    # how a trace reduction finds the recurrence; and ONE jitted function
+    # for every layer (the layer's pool row is an argument), so that a
+    # step's program traces and lowers the kernel once, not a layer
     y, ssm = jax.named_call(
-        lambda x, B, Cm, dt, A, ssm, seg, work: ss.ssm_scan(
-            x, B, Cm, dt, A, ssm, layer, seg, work, cfg.chunk_size),
-        name=ss.SCOPE)(x, B, Cm, dt, -jnp.exp(lp["a_log"]), ssm,
-                       plan["seg"], plan["work"])
-    y = y.astype(f32) + lp["d"][:, None] * x.astype(f32)
-    y = (y.reshape(T, di) * jax.nn.silu(z.astype(f32))).reshape(
-        T, G, di // G)
+        jax.jit(ss.ssm_scan, static_argnames="chunk"), name=ss.SCOPE)(
+            x, xbc[:, di:di + G * N], xbc[:, di + G * N:], dt,
+            -jnp.exp(lp["a_log"]), ssm, jnp.int32(layer), plan["work"],
+            chunk=cfg.chunk_size)
+    y = y.astype(f32) + jnp.repeat(lp["d"], P) * x.astype(f32)
+    y = (y * jax.nn.silu(z.astype(f32))).reshape(T, G, di // G)
     y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
                           + cfg.layer_norm_epsilon)
     y = (y.reshape(T, di) * lp["gate_norm"]).astype(u.dtype)

@@ -123,6 +123,9 @@ def force_form(monkeypatch):
     ("chunked", (5, 1, 20, 3)),
     # a block exactly full (8), one token past it (9), two blocks and one
     ("chunked", (8, 9, 17, 1)),
+    # four blocks at once, then windows cut the chunks where they fall
+    ("chunked", (32, 3, 8, 16)),
+    ("recurrent", (8, 9, 17, 1)),
     ("by_size", (1, 7, 1, 1, 12)),
 ])
 def test_each_scan_form_equals_the_token_by_token_reference(
@@ -154,16 +157,13 @@ def test_each_scan_form_equals_the_token_by_token_reference(
     # seven tenants in one tick against five slots: two steps
     ("split_step", [[(0, 3), (1, 1)], [(t, 1 + t % 3) for t in range(7)],
                     [(6, 2), (0, 1)]]),
-    # more batches than one of each list, ragged trips
+    # chunks of both forms side by side in the windows, cut by their edges
     ("many_chunks", [[(t, 1 + (5 * t) % 11) for t in range(5)],
                      [(t, 1 + (3 * t) % 7) for t in range(5)]]),
 ])
 def test_chunks_through_both_caches_equal_one_full_forward(
-        model, monkeypatch, case, ticks):
+        model, case, ticks):
     spec, cfg, params = model
-    if case == "many_chunks":
-        for name, value in (("RECURRENT_BATCH", 2), ("SCAN_BATCH", 2)):
-            monkeypatch.setattr(ss, name, value)
     run = Stepper(cfg, params)
     rng = np.random.default_rng(1)
     for chunks in ticks:
@@ -178,6 +178,86 @@ def test_chunks_through_both_caches_equal_one_full_forward(
         assert run.table.slots_held <= 5
     if case == "split_step":
         assert run.table.steps_split == 1 and run.table.evicted >= 2
+
+
+def scan_by_tokens(x, B, C, dt, A, pool, layer, chunks):
+    """The recurrence a token after another in float32 (numpy; ``x``
+    ``[T, H, P]``, ``B`` / ``C`` ``[T, G, N]``): ``chunks`` ``(tok0, n,
+    slot, fresh)``.  Returns ``(y, {slot: state [N, H * P]})``.  The chip's
+    twin of the test below, ``tpu_tests/test_ssm_scan.py``, uses it too."""
+    x, B, C, dt, A, pool = (np.asarray(a, np.float32)
+                            for a in (x, B, C, dt, A, pool))
+    (T, H, P), (G, N) = x.shape, B.shape[1:]
+    y, states = np.zeros_like(x), {}
+    for tok0, n, slot, fresh in chunks:
+        S = np.zeros((H, P, N), np.float32) if fresh else \
+            pool[layer, slot].reshape(N, H, P).transpose(1, 2, 0).copy()
+        for t in range(tok0, tok0 + n):
+            Bt, Ct = (np.repeat(a[t], H // G, axis=0) for a in (B, C))
+            S = np.exp(dt[t] * A)[:, None, None] * S \
+                + (dt[t][:, None] * x[t])[:, :, None] * Bt[:, None, :]
+            y[t] = (S * Ct[:, None, :]).sum(-1)
+        states[slot] = S.transpose(2, 0, 1).reshape(N, H * P)
+    return y, states
+
+
+#: (tokens, recurrent?) of the chunks packed back to back from row 0 in
+#: windows of 8 rows; every third chunk's session is fresh
+SCAN_STEPS = {
+    # the first chunk ends mid-window: the neighbour's rows are its own
+    "neighbours_mid_block": [(5, False), (6, False), (3, True), (9, False)],
+    "fresh_and_carried": [(8, False), (1, True), (2, True), (17, False)],
+    "one_block_one_more_four": [(8, False), (9, False), (32, False)],
+    "no_recurrent_chunk": [(3, False), (13, False), (1, False)],
+    "no_chunked_chunk": [(3, True), (13, True), (1, True), (8, True)],
+    "a_recurrent_chunk_across_an_edge": [(6, False), (5, True), (7, True)],
+    "no_chunk_at_all": [],
+}
+
+
+@pytest.mark.parametrize("case", sorted(SCAN_STEPS))
+@pytest.mark.parametrize("layer", [0, 1])
+def test_the_scan_kernel_step_by_step(case, layer):
+    """``ssm_scan`` itself (the interpreter's run of the kernel) against
+    the token-by-token recurrence: ``y`` of every chunk's own rows, zeros
+    elsewhere; the named slots' new states; every other slot of the pool,
+    slot 0 and the other layer among them, bit for bit as it was."""
+    H, P, N, G, Q, T, slots = 4, 16, 16, 2, 8, 64, 9
+    steps = SCAN_STEPS[case]
+    n = np.asarray([c[0] for c in steps], np.int64)
+    rec = np.asarray([c[1] for c in steps], bool)
+    tok0 = np.cumsum(n) - n
+    slot = np.asarray([7, 2, 5, 8, 3, 1][:len(n)], np.int64)
+    fresh = (np.arange(len(n)) % 3 == 1).astype(np.int64)
+    work = ss.empty_work(ss.work_caps(T, 8, Q))
+    stats = ss.work_lists(work, tok0, n, slot, fresh, rec, Q)
+    assert stats["ssm_recurrent_tokens"] == n[rec].sum()
+    assert stats["ssm_scan_tokens"] == n[~rec].sum()
+    assert stats["ssm_scan_blocks"] == (-(-n[~rec] // Q)).sum()
+    live = work["flags"][:int(work["n_items"])]
+    assert (live & ss.FIRST > 0).sum() == (live & ss.LAST > 0).sum() \
+        == len(n) and work["n_items"] >= 1
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 6)
+    x = jax.random.normal(ks[0], (T, H, P), jnp.float32)
+    B, C = (0.5 * jax.random.normal(k, (T, G, N), jnp.float32)
+            for k in ks[1:3])
+    dt = jax.random.uniform(ks[3], (T, H), jnp.float32, 0.001, 0.1)
+    A = -jax.random.uniform(ks[4], (H,), jnp.float32, 1.0, 16.0)
+    pool = jax.random.normal(ks[5], (2, slots, N, H * P), jnp.float32)
+    y, after = jax.jit(lambda *a: ss.ssm_scan(*a, layer, work, Q))(
+        x.reshape(T, -1), B.reshape(T, -1), C.reshape(T, -1), dt, A, pool)
+    y = y.reshape(T, H, P)
+    want_y, want = scan_by_tokens(x, B, C, dt, A, pool, layer,
+                                   zip(tok0, n, slot, fresh))
+    np.testing.assert_allclose(np.asarray(y), want_y, atol=2e-5)
+    assert not np.asarray(y)[n.sum():].any()
+    after, pool = np.asarray(after), np.asarray(pool)
+    for s in range(slots):
+        if s in want:
+            np.testing.assert_allclose(after[layer, s], want[s], atol=2e-5)
+        else:
+            np.testing.assert_array_equal(after[layer, s], pool[layer, s])
+    np.testing.assert_array_equal(after[1 - layer], pool[1 - layer])
 
 
 def test_param_count_at_published_widths_is_the_issues_arithmetic():

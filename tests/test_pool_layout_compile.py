@@ -226,16 +226,19 @@ def test_seq_step_absorbed_form_is_one_mosaic_kernel(one_chip, optimizing):
 
 # -- the hybrid model's two kinds of cache (PR 34) ----------------------------
 
+@pytest.mark.parametrize("grid_size", [min, max], ids=["min", "max"])
 def test_hybrid_step_writes_the_slot_pool_and_the_kv_pool_in_place(
-        one_chip, optimizing):
+        one_chip, optimizing, grid_size):
     """The serving step of ``n3s-fleet-overload`` at its published widths
     and its real pools (640 state slots of 10.8 MB, 524,288 cached
-    tokens), compiled for the chip at the token grid's largest size:
-    every donated pool comes back in its own buffer, no op copies a
-    slot pool whole (its minor dimension is the state's 128, a slot's
-    convolution tail one row of 30,720), both named calls reach the
-    device ops' metadata, and everything fits the chip beside the
-    weights."""
+    tokens), compiled for the chip at the token grid's least and largest
+    size: every donated pool comes back in its own buffer, no op copies a
+    slot pool whole (a state is ``[128, 8192]``, a slot's convolution
+    tail one row of 30,720), the recurrence is the Mosaic kernel (PR 35:
+    one custom call a Mamba layer under the scan's call name, which is
+    what ``ssm_scan_roofline`` finds it by, and no loop left there), the
+    attention's named call reaches the device ops' metadata, and
+    everything fits the chip beside the weights."""
     import json
     import os
 
@@ -263,29 +266,37 @@ def test_hybrid_step_writes_the_slot_pool_and_the_kv_pool_in_place(
             if isinstance(leaf, dict) else sds(leaf[0], floats(name,
                                                                leaf[1])))
     n_tenants = spec["fleet"]["n_tenants"]
-    caps = hm.plan_caps(cfg, max(spec["assumed"]["token_grid"]),
-                        2 * n_tenants + 64)
+    tokens = grid_size(spec["assumed"]["token_grid"])
+    caps = hm.plan_caps(cfg, tokens, 2 * n_tenants + 64)
     plan = jax.tree_util.tree_map(
         lambda a: sds(np.shape(a), jnp.int32), hm.empty_plan(cfg, caps, 0))
     state = jax.tree_util.tree_map(
         lambda a: sds(a.shape, a.dtype),
         jax.eval_shape(lambda: hm.init_state(cfg, n_tenants)))
-    assert state["ssm"].shape == (5, 640, 128, 64, 128)
+    assert state["ssm"].shape == (5, 640, 128, 8192)
     assert state["conv"].shape == (5, 640, 30720)
     assert state["pool"].shape == (1, 4096, 128, 512)
     step = jax.jit(lambda p, s, plan: hm.append_step(cfg, p, s, plan),
                    donate_argnums=(1,))
     compiled = step.lower(params, state, plan).compile()
     text = compiled.as_text()
-    for shape in ("bf16[5,640,128,64,128]", "bf16[5,640,30720]",
+    for shape in ("bf16[5,640,128,8192]", "bf16[5,640,30720]",
                   "bf16[3200,30720]", "bf16[524288,512]"):
         assert not re.search(rf"= {re.escape(shape)}[^=\n]* copy\(", text)
-    assert f"/{ssm_scan.SCOPE}/while/body/" in text
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and f"/{ssm_scan.SCOPE}/" in line]
+    assert len(kernels) == cfg.count("mamba") \
+        and all("/pallas_call" in k for k in kernels)
+    assert not re.search(
+        rf'while\([^\n]*op_name="[^"]*/{ssm_scan.SCOPE}/', text)
     assert f"/{gqa_attention.SCOPE}/while/body/" in text
     mem = compiled.memory_analysis()
     held = sum(2 * int(np.prod(a.shape)) for a in state.values())
     assert held > 7.4e9 and mem.alias_size_in_bytes >= held
-    # 1.9e9 of temporaries at 8,192 tokens (the Mamba projection's
-    # float32 output is 0.6e9 of it); weights 5.5e9 and pools 7.5e9
-    assert mem.temp_size_in_bytes < 2.3e9
+    # 1.07e9 / 1.86e9 of temporaries at 4,096 / 8,192 tokens (the Mamba
+    # projection's float32 output is 0.3e9 / 0.6e9 of it; the scan's own
+    # are its operands laid out by group, 0.04e9); weights 5.5e9 and pools
+    # 7.5e9
+    assert mem.temp_size_in_bytes < (1.2e9 if tokens == 4096 else 2.0e9)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
