@@ -18,12 +18,15 @@ import numpy as np
 def flat_spec(d: dict) -> dict:
     """A configuration file's object flattened for a model's dataclass:
     the public keys at the top level, the sizes this repo set under
-    ``assumed`` beside them, the share defaulting to the whole."""
+    ``assumed`` beside them, the share defaulting to the whole (the
+    experts under either of the two names the public configurations
+    give their count)."""
     flat = dict(d)
     flat.update({k: v for k, v in d.get("assumed", {}).items()
                  if not isinstance(v, (dict, list, str))})
     flat.setdefault("vocab_held", flat["vocab_size"])
-    flat.setdefault("experts_held", flat["n_routed_experts"])
+    flat.setdefault("experts_held", flat.get("n_routed_experts",
+                                             flat.get("num_experts")))
     return flat
 
 

@@ -8,12 +8,17 @@ step appends a packed batch of new tokens of many sessions; their rows
 are in the pool already when attention runs, so every key is read from
 the pool through the session's block table and masked by position (a key
 at position ``p`` of the same session is visible to the query at position
-``>= p``).  No positional encoding is applied.
+``>= p`` and, under a ``window``, ``< p + window``: ``window`` keys, the
+query's own among them).  No positional encoding is applied here: a caller
+that has one applies it before the keys are cached.
 
 Work list: (chunk, run of ``KV_BLOCKS`` cached blocks) pairs, query tiles
 of ``Q_TILE`` tokens inside, each tile's ``heads / kv`` query heads of a
 group scored against the group's one key head; two nested loops with
 traced bounds and an online softmax state over the packed tokens.
+:func:`pair_runs` builds a list that holds, for each run, only the query
+tiles that can see one of its keys (under a window: never a block that no
+query of the chunk sees).
 """
 
 from __future__ import annotations
@@ -21,8 +26,10 @@ from __future__ import annotations
 Q_TILE = 64        # query tokens of a tile
 KV_BLOCKS = 4      # cached blocks read at once
 NEG = -1e30        # the running maximum's floor (finite: no NaN from -inf)
-#: the name of the call that holds the attention loops
+#: the names of the calls that hold the attention loops: full layers,
+#: and layers under a sliding window
 SCOPE = "anomod_seq_gqa"
+SWA_SCOPE = "anomod_seq_swa"
 
 
 def pairs_needed(segments: int, pool_tokens: int, block: int) -> int:
@@ -31,13 +38,52 @@ def pairs_needed(segments: int, pool_tokens: int, block: int) -> int:
     return segments + pool_tokens // (KV_BLOCKS * block) + 1
 
 
+def window_pairs_needed(segments: int, tokens: int, window: int,
+                        block: int) -> int:
+    """Rows of :func:`pair_runs`' list under ``window`` that always
+    suffice at ``tokens`` packed tokens: a chunk of ``n`` tokens walks the
+    blocks of ``n + window - 1`` keys, whatever their place in a block."""
+    runs_a_chunk = -(-(window + 2 * block) // (KV_BLOCKS * block)) + 1
+    return runs_a_chunk * segments + tokens // (KV_BLOCKS * block) + 1
+
+
+def pair_runs(start, n, off, block: int, window: int = None) -> dict:
+    """The work list of chunks ``(start position, n tokens, first packed
+    token off)`` (int64 arrays ``[S]``): for each chunk the runs of
+    ``KV_BLOCKS`` blocks from the first block one of its queries sees
+    (under ``window``: the block of position ``start - window + 1``) to
+    the block of its last token, and for each run the query tiles that
+    see one of its keys (a tile before the run's first key sees none;
+    under ``window`` neither does one wholly past its last key's reach).
+    Returns ``seg``, ``q0``, ``n_tiles``, ``blk0`` (int64 ``[P]``)."""
+    import numpy as np
+    S = len(n)
+    b_lo = np.zeros(S, np.int64) if window is None \
+        else np.maximum(start - window + 1, 0) // block
+    runs = -(-((start + n - 1) // block + 1 - b_lo) // KV_BLOCKS)
+    seg = np.repeat(np.arange(S), runs)
+    P = len(seg)
+    blk0 = b_lo[seg] + (np.arange(P) - np.repeat(np.cumsum(runs) - runs,
+                                                 runs)) * KV_BLOCKS
+    # positions relative to the chunk's first query
+    first_key = blk0 * block - start[seg]
+    t_lo = np.maximum(first_key // Q_TILE, 0)
+    t_hi = -(-n[seg] // Q_TILE) - 1
+    if window is not None:
+        reach = first_key + KV_BLOCKS * block - 1 + window - 1
+        t_hi = np.minimum(t_hi, reach // Q_TILE)
+    return {"seg": seg, "q0": off[seg] + t_lo * Q_TILE,
+            "n_tiles": t_hi - t_lo + 1, "blk0": blk0}
+
+
 def append_attention(q, q_pos, q_seg, pool, seg_blocks, pairs, kv: int,
-                     scale: float, block: int):
+                     scale: float, block: int, window: int = None):
     """``q`` ``[T + Q_TILE, H, d]``, ``q_pos`` / ``q_seg`` ``[T +
     Q_TILE]``, ``pool`` ``[rows, block, 2 * kv * d]`` (a layer's rows are
     addressed by ``seg_blocks`` with the layer's offset added);
     ``pairs``: ``seg``, ``q0``, ``n_tiles``, ``blk0`` ``[P]`` and
-    ``n_pairs``.  Returns ``[T + Q_TILE, H, d]`` (rows of no chunk are
+    ``n_pairs``; ``window``: a query sees the ``window`` newest keys up
+    to its own.  Returns ``[T + Q_TILE, H, d]`` (rows of no chunk are
     zero)."""
     import jax
     import jax.numpy as jnp
@@ -65,7 +111,10 @@ def append_attention(q, q_pos, q_seg, pool, seg_blocks, pairs, kv: int,
             s = jnp.einsum("qgrd,kgd->grqk", cut(qg), keys,
                            preferred_element_type=f32) * scale
             see = ((cut(q_seg) == seg)[:, None]
-                   & (kv_pos[None, :] <= cut(q_pos)[:, None]))[None, None]
+                   & (kv_pos[None, :] <= cut(q_pos)[:, None]))
+            if window is not None:
+                see = see & (kv_pos[None, :] > cut(q_pos)[:, None] - window)
+            see = see[None, None]
             s = jnp.where(see, s, NEG)
             m_old = jnp.moveaxis(cut(m), 0, -1)           # [kv, R, Q]
             m_new = jnp.maximum(m_old, s.max(axis=-1))

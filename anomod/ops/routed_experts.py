@@ -15,15 +15,20 @@ rows; the number of rounds is data (one where routing is even, up to
 from __future__ import annotations
 
 
-def route(x, w_router, bias, top_k: int, scaling: float, norm_topk: bool):
-    """Sigmoid scores in float32, top-k of ``score + bias`` (the bias only
-    chooses), weights from the unbiased scores, normalised and scaled.
-    Returns ``(experts [T, k] int32, weights [T, k] float32)``."""
+def route(x, w_router, bias, top_k: int, scaling: float, norm_topk: bool,
+          score: str = "sigmoid"):
+    """Scores in float32 (``score``: ``"sigmoid"`` of each logit, or
+    ``"softmax"`` over all of them), top-k of ``score + bias`` (the bias
+    only chooses; ``None``: the router has none), weights from the
+    unbiased scores, normalised and scaled.  Returns ``(experts [T, k]
+    int32, weights [T, k] float32)``."""
     import jax
     import jax.numpy as jnp
-    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), w_router,
-                               precision=jax.lax.Precision.HIGHEST))
-    _, experts = jax.lax.top_k(s + bias, top_k)
+    logits = jnp.dot(x.astype(jnp.float32), w_router,
+                     precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(logits) if score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    _, experts = jax.lax.top_k(s if bias is None else s + bias, top_k)
     w = jnp.take_along_axis(s, experts, axis=1)
     if norm_topk:
         w = w / (w.sum(axis=1, keepdims=True) + 1e-20)

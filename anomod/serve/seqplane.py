@@ -4,7 +4,9 @@ event token of its tenant's session, scored by a decoder as its surprisal
 by the configuration's ``model_type`` (:data:`MODELS`): a
 latent-attention, routed-expert one (:mod:`anomod.models.latent_moe`) or
 a hybrid of state-space, attention and latent-expert layers
-(:mod:`anomod.models.hybrid_ssm_moe`).
+(:mod:`anomod.models.hybrid_ssm_moe`), or one of window-and-full
+attention layers with their own head counts over two K/V pools and small
+routed experts (:mod:`anomod.models.swa_moe`).
 
 A plane beside ``_rca_step``: it reads the tick's served batches and
 writes nothing the sketch planes read, so states, alerts and shed
@@ -56,11 +58,15 @@ COUNTERS = ("seq_tokens", "seq_pairs", "seq_absorbed_tokens",
             "sessions_evicted", "pool_blocks_held",
             "ssm_recurrent_tokens", "ssm_scan_tokens", "ssm_scan_blocks",
             "ssm_scan_pairs", "ssm_state_rows", "gqa_pairs", "gqa_keys", "state_slots_held",
-            "sessions_evicted_by_slots", "steps_split_by_slots")
+            "sessions_evicted_by_slots", "steps_split_by_slots",
+            "full_pairs", "full_keys", "swa_pairs", "swa_keys",
+            "win_blocks_held", "win_blocks_freed",
+            "sessions_evicted_by_window", "steps_split_by_window")
 #: counters that hold the table's present count, not a sum over steps
 GAUGES = ("sessions_rolled", "sessions_evicted", "pool_blocks_held",
           "state_slots_held", "sessions_evicted_by_slots",
-          "steps_split_by_slots")
+          "steps_split_by_slots", "win_blocks_held", "win_blocks_freed",
+          "sessions_evicted_by_window", "steps_split_by_window")
 N_STATUS, N_KIND = 4, 3
 #: logits rows kept for each audit tenant, the newest
 AUDIT_KEEP = 32
@@ -83,10 +89,13 @@ def tokenise(service, duration_us, status, kind, n_hist: int) -> np.ndarray:
 
 
 class Session:
-    __slots__ = ("blocks", "length", "number", "slot")
+    __slots__ = ("blocks", "length", "number", "slot", "ring", "ring_lo")
 
     def __init__(self, number: int):
         self.blocks, self.length, self.number, self.slot = [], 0, number, 0
+        #: the window blocks held, the oldest first, and the place in the
+        #: session of the first of them (in blocks)
+        self.ring, self.ring_lo = [], 0
 
 
 class SessionTable:
@@ -99,13 +108,39 @@ class SessionTable:
     planned (the step still reads them), so a session begun in a step
     takes a slot of its own.  ``place`` cuts a tick's chunks into steps
     the slots can hold.  Without ``state_slots`` the table knows blocks
-    only."""
+    only.
+
+    With ``window_blocks`` (and ``window``, in tokens) there is a second
+    kind of block, of a pool of its own, for layers that read the newest
+    ``window`` keys only: a step's tokens take a window block wherever
+    they take a block, and when the step has been planned each session
+    keeps only the trailing ring that its next token can still see (the
+    blocks from position ``length - window + 1`` on) and the rest are free
+    again (the step still reads them).  A segment then ends in ``(place of
+    the first window block in the session, the window blocks)``.  One
+    policy: sessions are ended while EITHER pool is short, and an ended
+    session frees both.  ``place`` cuts a tick whose tokens begin more
+    blocks than the smaller pool has into steps that each fit, so no tick
+    is too wide for the table."""
 
     def __init__(self, n_blocks: int, context_tokens: int,
-                 block_tokens: int, state_slots: int = None):
+                 block_tokens: int, state_slots: int = None,
+                 window_blocks: int = None, window: int = None):
         self.context, self.block = int(context_tokens), int(block_tokens)
         self.free = collections.deque(range(1, int(n_blocks)))
         self.usable = len(self.free)
+        self.window = None if window_blocks is None else int(window)
+        self.free_win = None if window_blocks is None \
+            else collections.deque(range(1, int(window_blocks)))
+        self.usable_win = 0 if window_blocks is None else len(self.free_win)
+        self.evicted_by_window = self.win_freed = 0
+        self.steps_split_by_window = 0
+        if window_blocks is not None and self.context % self.block:
+            raise ValueError("with window blocks a session is a whole "
+                             "number of blocks")
+        if window_blocks is not None and state_slots is not None:
+            raise ValueError("a table of state slots AND window blocks: "
+                             "`place` cuts a tick by one of them")
         self.free_slots = None if state_slots is None \
             else collections.deque(range(1, int(state_slots)))
         self.usable_slots = 0 if state_slots is None \
@@ -123,6 +158,10 @@ class SessionTable:
     def slots_held(self) -> int:
         return self.usable_slots - len(self.free_slots or ())
 
+    @property
+    def win_blocks_held(self) -> int:
+        return self.usable_win - len(self.free_win or ())
+
     def _begin(self, tenant: int) -> Session:
         n = self.started.get(tenant, 0)
         self.started[tenant] = n + 1
@@ -135,6 +174,9 @@ class SessionTable:
         self.free.extend(s.blocks)
         if self.free_slots is not None:
             self.free_slots.append(s.slot)
+        if self.free_win is not None:
+            self.free_win.extend(s.ring)
+            self.win_freed += len(s.ring)
 
     def _needed(self, tenant: int, n: int) -> int:
         """Blocks ``n`` more tokens of ``tenant`` take now (a session
@@ -160,8 +202,12 @@ class SessionTable:
 
     def place(self, chunks: list) -> list:
         """A tick's chunks in ascending tenant order as the segments of
-        one step or, where the sessions they touch outnumber the slots,
-        of further steps."""
+        one step or, where the sessions they touch outnumber the slots or
+        their tokens' blocks outnumber a window pool's (or the pool's
+        beside it), of further steps: what a step passed or ended is free
+        before the next takes its own."""
+        if self.free_win is not None:
+            return self._place_by_blocks(chunks)
         if self.free_slots is None:
             return [self.append(chunks)]
         touched = lambda t, n: self._begun(t, n) + (t in self.sessions)
@@ -176,6 +222,28 @@ class SessionTable:
             demand += d
         return steps + [self.append(cur)]
 
+    def _place_by_blocks(self, chunks: list) -> list:
+        """Steps whose tokens begin no more blocks than the smaller pool
+        has, counted as of EMPTY sessions (a step's own sessions may be
+        ended to make its room, and an empty one takes no fewer), so a
+        step is placed whatever the table holds; a chunk that alone takes
+        more goes on in the next step."""
+        room = min(self.usable, self.usable_win)
+        steps, cur, left = [], [], room
+        queue = collections.deque(chunks)
+        while queue:
+            t, n = queue.popleft()
+            head = min(n, left * self.block)
+            if head:
+                cur.append((t, head))
+                left -= -(-head // self.block)
+            if head < n:
+                queue.appendleft((t, n - head))
+                steps.append(self.append(cur))
+                self.steps_split_by_window += 1
+                cur, left = [], room
+        return steps + [self.append(cur)]
+
     def append(self, chunks: list) -> list:
         for t, _ in chunks:
             if t in self.sessions:
@@ -186,11 +254,15 @@ class SessionTable:
         need = {t: self._needed(t, n) for t, n in chunks}
         short = sum(need.values()) - len(self.free)
         slots = self.free_slots is not None
+        # a step's tokens take as many window blocks as blocks (nothing
+        # of the ring is freed before the step has been planned)
+        wins = self.free_win is not None
+        short_win = sum(need.values()) - len(self.free_win) if wins else 0
         begun = {t: self._begun(t, n) for t, n in chunks} if slots else {}
         short_slots = sum(begun.values()) - len(self.free_slots) \
             if slots else 0
         sizes = dict(chunks)
-        while short > 0 or short_slots > 0:
+        while short > 0 or short_slots > 0 or short_win > 0:
             if not self.sessions:
                 raise RuntimeError(
                     "the pools cannot hold one step's tokens")
@@ -199,17 +271,20 @@ class SessionTable:
             self._end(s)
             self.evicted += 1
             self.evicted_by_slots += short_slots > 0
+            self.evicted_by_window += short_win > 0
             short -= len(s.blocks)
             short_slots -= slots
+            short_win -= len(s.ring)
             if victim in need:
                 fresh = self._needed(victim, sizes[victim])
                 short += fresh - need[victim]
+                short_win += (fresh - need[victim]) * wins
                 need[victim] = fresh
                 if slots:
                     again = self._begun(victim, sizes[victim])
                     short_slots += again - begun[victim]
                     begun[victim] = again
-        segments, ended = [], []
+        segments, ended, touched = [], [], []
         for tenant, n in chunks:
             while n > 0:
                 s = self.sessions.get(tenant) or self._begin(tenant)
@@ -217,15 +292,28 @@ class SessionTable:
                 for _ in range(-(-(s.length + take) // self.block)
                                - len(s.blocks)):
                     s.blocks.append(self.free.popleft())
+                    if wins:
+                        s.ring.append(self.free_win.popleft())
                 segments.append((tenant, s.number, s.length, take, s.blocks)
-                                + ((s.slot,) if slots else ()))
+                                + ((s.slot,) if slots else ())
+                                + (((s.ring_lo, s.ring),) if wins else ()))
                 s.length += take
                 n -= take
                 if s.length == self.context:
                     ended.append(self.sessions.pop(tenant))
                     self.rolled += 1
+                elif wins:
+                    touched.append(s)
         for s in ended:
             self._end(s)
+        for s in touched:
+            # what the session's next token can still see stays; a NEW
+            # list, the step's segments read the old one
+            passed = max(s.length - self.window + 1, 0) // self.block \
+                - s.ring_lo
+            self.free_win.extend(s.ring[:passed])
+            self.win_freed += passed
+            s.ring, s.ring_lo = s.ring[passed:], s.ring_lo + passed
         for t, _ in chunks:             # same-step sessions: by tenant id
             if t in self.sessions:
                 self.sessions.move_to_end(t)
@@ -300,9 +388,10 @@ class LatentMoE:
     """What the plane asks of a model (configuration, weights, pools,
     plan, step), for :mod:`anomod.models.latent_moe`."""
 
+    state_slots = window_blocks = window = None
+
     def __init__(self, spec: dict):
         self.cfg = lm.DecoderConfig.from_dict(spec)
-        self.state_slots = None
 
     def init_params(self, seed: int) -> dict:
         return lm.init_params(self.cfg, seed)
@@ -332,38 +421,60 @@ class LatentMoE:
         return ({"pool": pool, "h_last": h_last},) + tuple(out)
 
 
-class HybridSsmMoE:
-    """The same for :mod:`anomod.models.hybrid_ssm_moe`, whose sessions
-    hold a state slot beside their blocks."""
+class ModuleModel:
+    """The same for a model whose module has the plane's functions by
+    their own names (``init_params``, ``init_state``, ``plan_caps``,
+    ``empty_plan``, ``build_plan``, ``append_step``)."""
+
+    state_slots = window_blocks = window = None
+
+    def __init__(self, module, cfg):
+        self.module, self.cfg = module, cfg
+
+    def init_params(self, seed: int) -> dict:
+        return self.module.init_params(self.cfg, seed)
+
+    def init_state(self, n_tenants: int) -> dict:
+        return self.module.init_state(self.cfg, n_tenants)
+
+    def caps(self, tokens: int, segments: int) -> dict:
+        return self.module.plan_caps(self.cfg, tokens, segments)
+
+    def empty_plan(self, caps: dict, trash_row: int) -> dict:
+        return self.module.empty_plan(self.cfg, caps, trash_row)
+
+    def build_plan(self, caps, segments, tokens, tenant_ids, audit):
+        return self.module.build_plan(self.cfg, caps, segments, tokens,
+                                      tenant_ids, audit)
+
+    def step(self, params: dict, state: dict, plan: dict):
+        return self.module.append_step(self.cfg, params, state, plan)
+
+
+class HybridSsmMoE(ModuleModel):
+    """:mod:`anomod.models.hybrid_ssm_moe`, whose sessions hold a state
+    slot beside their blocks."""
 
     def __init__(self, spec: dict):
         from anomod.models import hybrid_ssm_moe as hm
-        self.hm, self.cfg = hm, hm.HybridConfig.from_dict(spec)
+        super().__init__(hm, hm.HybridConfig.from_dict(spec))
         self.state_slots = self.cfg.state_slots
 
-    def init_params(self, seed: int) -> dict:
-        return self.hm.init_params(self.cfg, seed)
 
-    def init_state(self, n_tenants: int) -> dict:
-        return self.hm.init_state(self.cfg, n_tenants)
+class SwaMoE(ModuleModel):
+    """:mod:`anomod.models.swa_moe`, whose sessions hold a trailing ring
+    of window blocks beside their blocks."""
 
-    def caps(self, tokens: int, segments: int) -> dict:
-        return self.hm.plan_caps(self.cfg, tokens, segments)
-
-    def empty_plan(self, caps: dict, trash_row: int) -> dict:
-        return self.hm.empty_plan(self.cfg, caps, trash_row)
-
-    def build_plan(self, caps, segments, tokens, tenant_ids, audit):
-        return self.hm.build_plan(self.cfg, caps, segments, tokens,
-                                  tenant_ids, audit)
-
-    def step(self, params: dict, state: dict, plan: dict):
-        return self.hm.append_step(self.cfg, params, state, plan)
+    def __init__(self, spec: dict):
+        from anomod.models import swa_moe as wm
+        super().__init__(wm, wm.SwaMoeConfig.from_dict(spec))
+        self.window_blocks = self.cfg.window_blocks
+        self.window = self.cfg.sliding_window
 
 
 #: ``model_type`` of a configuration -> its model; a configuration that
 #: names none is the latent-attention decoder's
-MODELS = {"nemotron_h": HybridSsmMoE}
+MODELS = {"nemotron_h": HybridSsmMoE, "laguna": SwaMoE}
 
 
 class SeqPlane:
@@ -401,7 +512,9 @@ class SeqPlane:
         #: ``pool``, ``h_last``, and a model's own beside them
         self.state = self.model.init_state(self.n_tenants)
         self.table = SessionTable(cfg.pool_blocks, cfg.context_tokens,
-                                  cfg.block_tokens, self.model.state_slots)
+                                  cfg.block_tokens, self.model.state_slots,
+                                  self.model.window_blocks,
+                                  self.model.window)
         self._step = named_jit("anomod_seq_step", self.model.step,
                                donate_argnums=(1,))
         self.counters = dict.fromkeys(COUNTERS, 0)
@@ -502,7 +615,8 @@ class SeqPlane:
             c.update(zip(GAUGES, (
                 table.rolled, table.evicted, table.blocks_held,
                 table.slots_held, table.evicted_by_slots,
-                table.steps_split)))
+                table.steps_split, table.win_blocks_held, table.win_freed,
+                table.evicted_by_window, table.steps_split_by_window)))
             self.tick_doc = {
                 "tokens": int(len(tokens)), "steps": len(steps),
                 "absorbed_group_blocks": sum(
@@ -514,6 +628,8 @@ class SeqPlane:
                 "blocks_held": self.table.blocks_held,
                 **({"slots_held": self.table.slots_held}
                    if self.model.state_slots else {}),
+                **({"win_blocks_held": self.table.win_blocks_held}
+                   if self.model.window_blocks else {}),
                 "digest": zlib.crc32(surprisal.tobytes())}
 
     def _plan_steps(self, segments: list, tokens: np.ndarray) -> list:

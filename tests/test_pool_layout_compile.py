@@ -300,3 +300,73 @@ def test_hybrid_step_writes_the_slot_pool_and_the_kv_pool_in_place(
     # 7.5e9
     assert mem.temp_size_in_bytes < (1.2e9 if tokens == 4096 else 2.0e9)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+
+
+# -- the window-and-full model's two K/V pools (PR 36) ------------------------
+
+@pytest.mark.parametrize("grid_size", [min, max], ids=["min", "max"])
+def test_swa_step_writes_both_kv_pools_in_place(one_chip, optimizing,
+                                                grid_size):
+    """The serving step of ``lxs2-fleet-overload`` at its published widths
+    and its real pools, compiled for the chip at the token grid's least
+    and largest size: both donated K/V pools come back in their own
+    buffers, no op copies a pool whole (a row is 2,048 columns: 16 lane
+    tiles), each kind's attention loops carry their call's name in the
+    device ops' metadata (which is what ``full_append_roofline`` and
+    ``swa_append_roofline`` find them by), and everything fits the chip
+    beside the 7.74 GB of weights."""
+    import json
+    import os
+
+    import numpy as np
+
+    from anomod.models import swa_moe as wm
+    from anomod.ops import gqa_attention
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "laguna-xs2-pp8-stage.json")) as f:
+        spec = json.load(f)
+    cfg = wm.SwaMoeConfig.from_dict(spec)
+
+    def sds(shape, dtype):
+        return SDS(tuple(shape), dtype, sharding=one_chip)
+
+    params = {}
+    for name, leaf in wm.param_shapes(cfg).items():
+        floats = lambda k, rule: jnp.float32 if (
+            k in wm.F32_LEAVES or rule is None) else jnp.bfloat16
+        params[name] = (
+            {k: sds(s, floats(k, r)) for k, (s, r) in leaf.items()}
+            if isinstance(leaf, dict) else sds(leaf[0], floats(name,
+                                                               leaf[1])))
+    n_tenants = spec["fleet"]["n_tenants"]
+    tokens = grid_size(spec["assumed"]["token_grid"])
+    caps = wm.plan_caps(cfg, tokens, 2 * n_tenants + 64)
+    plan = jax.tree_util.tree_map(
+        lambda a: sds(np.shape(a), jnp.int32), wm.empty_plan(cfg, caps, 0))
+    state = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: wm.init_state(cfg, n_tenants)))
+    a = spec["assumed"]
+    n_full, n_win = a["pool_tokens"] // 128, a["window_blocks"]
+    assert state["pool"].shape == (2, n_full, 128, 2048)
+    assert state["wpool"].shape == (3, n_win, 128, 2048)
+    step = jax.jit(lambda p, s, plan: wm.append_step(cfg, p, s, plan),
+                   donate_argnums=(1,))
+    compiled = step.lower(params, state, plan).compile()
+    text = compiled.as_text()
+    for rows in (2 * n_full, 3 * n_win):
+        for shape in (f"bf16[{rows},128,2048]", f"bf16[{rows * 128},2048]"):
+            assert not re.search(rf"= {re.escape(shape)}[^=\n]* copy\(",
+                                 text), shape
+    for scope in (gqa_attention.SCOPE, gqa_attention.SWA_SCOPE):
+        assert f"/{scope}/while/body/" in text
+    mem = compiled.memory_analysis()
+    held = sum(2 * int(np.prod(s.shape)) for s in state.values())
+    weights = 2 * 3_869_857_792 + 2 * 4 * 524_288   # the routers in float32
+    assert mem.alias_size_in_bytes >= held
+    assert abs(mem.argument_size_in_bytes - held - weights) < 0.05e9
+    print("MEM", tokens, mem.argument_size_in_bytes, mem.temp_size_in_bytes,
+          mem.alias_size_in_bytes, held)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
