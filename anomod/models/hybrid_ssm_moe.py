@@ -216,9 +216,7 @@ def plan_caps(cfg: HybridConfig, tokens: int, segments: int) -> dict:
     """Static sizes of a step's plan at ``tokens`` packed tokens."""
     seg = min(tokens, segments)
     return dict(ss.work_caps(tokens, seg, cfg.chunk_size), tokens=tokens,
-                segments=seg, audit=64,
-                pairs=ga.pairs_needed(seg, cfg.pool_tokens,
-                                      cfg.block_tokens))
+                segments=seg, audit=64, items=ga.items_needed(seg, tokens))
 
 
 def _zero_row(cfg: HybridConfig, caps: dict) -> int:
@@ -228,18 +226,22 @@ def _zero_row(cfg: HybridConfig, caps: dict) -> int:
 
 
 def empty_plan(cfg: HybridConfig, caps: dict, trash_row: int) -> dict:
-    """A plan of no work at ``caps`` (numpy, int32): every token a pad, no
-    pair, no chunk in the scan's work list; the convolution reads the zero
-    row everywhere and every segment row is the never-allocated slot 0."""
-    T, S, P = caps["tokens"], caps["segments"], caps["pairs"]
+    """A plan of no work at ``caps`` (numpy, int32): every token a pad, an
+    item of no token in attention's work list (which carries the block
+    rows its items read: the block table stays on the host), no chunk in
+    the scan's; the convolution reads the zero row everywhere and every
+    segment row is the never-allocated slot 0."""
+    T, S = caps["tokens"], caps["segments"]
     taps = cfg.conv_kernel - 1
     z = lambda *n: np.zeros(n, np.int32)
     zero_row = _zero_row(cfg, caps)
+    plan = seqcommon.empty_token_plan(T, S, cfg.session_blocks,
+                                      caps["audit"], trash_row)
+    del plan["seg_blocks"]
     return dict(
-        seqcommon.empty_token_plan(T, S, cfg.session_blocks, caps["audit"],
-                                   trash_row),
-        pairs={"seg": z(P), "q0": z(P), "n_tiles": z(P), "blk0": z(P),
-               "n_pairs": np.int32(0)},
+        plan,
+        items=ga.empty_items(caps["items"], ga.blocks_needed(
+            cfg.session_blocks, cfg.block_tokens)),
         seg_slot=z(S + 1),
         conv_src=np.full((taps, T), zero_row, np.int32),
         tail_src=np.full((S + 1, taps), zero_row, np.int32),
@@ -253,23 +255,19 @@ def build_plan(cfg: HybridConfig, caps: dict, segments: list,
     blocks, slot)`` whose tokens are packed in order in ``tokens``.
     Returns ``(plan, stats, audit_rows)``: ``stats`` holds the step's
     share of the work counters (tokens; visible (new, cached) attention
-    pairs and the keys read; the tokens of each scan form, the chunked
-    form's blocks, the slot states read and written)."""
+    pairs, the keys read and the kernel's work items; the tokens of each
+    scan form, the chunked form's blocks, the slot states read and
+    written)."""
     plan = empty_plan(cfg, caps, len(tenant_ids))
     T, S, n_tok = caps["tokens"], len(segments), len(tokens)
+    # the sessions' block table, for the host alone
+    plan["seg_blocks"] = np.zeros((S, cfg.session_blocks), np.int32)
     f = seqcommon.fill_token_plan(plan, caps, cfg.block_tokens, segments,
                                   tokens, tenant_ids, audit)
     start, n, off, total, seg = (f[k] for k in ("start", "n", "off",
                                                 "total", "seg"))
-    runs = -(-total // (ga.KV_BLOCKS * cfg.block_tokens))
-    P = int(runs.sum())
-    p = plan["pairs"]
-    p["seg"][:P] = np.repeat(np.arange(S), runs)
-    p["q0"][:P] = off[p["seg"][:P]]
-    p["n_tiles"][:P] = -(-n[p["seg"][:P]] // ga.Q_TILE)
-    p["blk0"][:P] = (np.arange(P) - np.repeat(np.cumsum(runs) - runs, runs)
-                     ) * ga.KV_BLOCKS
-    p["n_pairs"] = np.int32(P)
+    n_items = ga.fill_items(plan["items"], start, n, off,
+                            plan.pop("seg_blocks"), cfg.block_tokens)
     slot = plan["seg_slot"]
     slot[:S] = [s[5] for s in segments]
     # the convolution's earlier inputs: a packed token of the same chunk,
@@ -295,7 +293,7 @@ def build_plan(cfg: HybridConfig, caps: dict, segments: list,
             cfg.n_groups, cfg.chunk_size), cfg.chunk_size)
     stats.update(seq_tokens=n_tok,
                  gqa_pairs=int((n * start + n * (n + 1) // 2).sum()),
-                 gqa_keys=int(total.sum()),
+                 gqa_keys=int(total.sum()), gqa_items=n_items,
                  ssm_state_rows=S * cfg.count("mamba"))
     return plan, stats, f["audit_rows"]
 
@@ -386,15 +384,13 @@ def attention_mixer(cfg: HybridConfig, lp: dict, u, plan: dict, pool,
     slot = plan["tok_slot"]
     pool = pool.at[rows + slot // cfg.block_tokens,
                    slot % cfg.block_tokens].set(row)
-    pad = lambda a, fill=0: jnp.concatenate(
-        [a, jnp.full((ga.Q_TILE,) + a.shape[1:], fill, a.dtype)])
+    # a NAMED CALL, and one jitted function for every attention layer:
+    # as the scan's
     o = jax.named_call(
-        lambda q, pos, seg, pool, blocks, pairs: ga.append_attention(
-            q, pos, seg, pool, blocks, pairs, cfg.num_key_value_heads,
-            cfg.head_dim ** -0.5, cfg.block_tokens),
-        name=ga.SCOPE)(pad(q), pad(plan["tok_pos"]),
-                       pad(plan["tok_seg"], -1), pool,
-                       plan["seg_blocks"] + rows, plan["pairs"])[:T]
+        jax.jit(ga.append_attention, static_argnums=(4, 5, 6)),
+        name=ga.SCOPE)(q, pool, plan["items"], jnp.int32(rows),
+                       cfg.num_key_value_heads, cfg.head_dim ** -0.5,
+                       cfg.block_tokens)
     return dot(o, lp["w_o"], "thk,hkd->td"), pool
 
 

@@ -45,9 +45,9 @@ from anomod.ops import routed_experts as rx
 
 FULL, SWA = "full_attention", "sliding_attention"
 #: kind -> (the call name its attention runs under, the pool that caches
-#: its keys, the plan's block-table, slot and pair-list rows it reads)
-KINDS = {FULL: (ga.SCOPE, "pool", "seg_blocks", "tok_slot", "pairs"),
-         SWA: (ga.SWA_SCOPE, "wpool", "seg_wblocks", "tok_wslot", "wpairs")}
+#: its keys, the plan's slot row and work list it reads)
+KINDS = {FULL: (ga.SCOPE, "pool", "tok_slot", "items"),
+         SWA: (ga.SWA_SCOPE, "wpool", "tok_wslot", "witems")}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -231,25 +231,22 @@ def plan_caps(cfg: SwaMoeConfig, tokens: int, segments: int) -> dict:
     """Static sizes of a step's plan at ``tokens`` packed tokens."""
     seg = min(tokens, segments)
     return dict(tokens=tokens, segments=seg, audit=64,
-                pairs=ga.pairs_needed(seg, cfg.pool_tokens,
-                                      cfg.block_tokens),
-                wpairs=ga.window_pairs_needed(
-                    seg, tokens, cfg.sliding_window, cfg.block_tokens))
+                items=ga.items_needed(seg, tokens))
 
 
 def empty_plan(cfg: SwaMoeConfig, caps: dict, trash_row: int) -> dict:
     """A plan of no work at ``caps`` (numpy, int32): every token a pad
-    that writes the never-allocated block 0 of either pool, no pair in
-    either work list."""
-    T, S = caps["tokens"], caps["segments"]
-    z = lambda *n: np.zeros(n, np.int32)
-    pairs = lambda P: {"seg": z(P), "q0": z(P), "n_tiles": z(P),
-                       "blk0": z(P), "n_pairs": np.int32(0)}
-    return dict(
-        seqcommon.empty_token_plan(T, S, cfg.session_blocks, caps["audit"],
-                                   trash_row),
-        seg_wblocks=z(S + 1, cfg.session_blocks), tok_wslot=z(T),
-        pairs=pairs(caps["pairs"]), wpairs=pairs(caps["wpairs"]))
+    that writes the never-allocated block 0 of either pool, an item of no
+    token in either work list.  The block tables stay on the host: the
+    work lists carry the rows their items read."""
+    plan = seqcommon.empty_token_plan(
+        caps["tokens"], caps["segments"], cfg.session_blocks, caps["audit"],
+        trash_row)
+    del plan["seg_blocks"]
+    items = lambda window: ga.empty_items(caps["items"], ga.blocks_needed(
+        cfg.session_blocks, cfg.block_tokens, window))
+    return dict(plan, tok_wslot=np.zeros(caps["tokens"], np.int32),
+                items=items(None), witems=items(cfg.sliding_window))
 
 
 def build_plan(cfg: SwaMoeConfig, caps: dict, segments: list,
@@ -260,25 +257,27 @@ def build_plan(cfg: SwaMoeConfig, caps: dict, segments: list,
     are packed in order in ``tokens``.  Returns ``(plan, stats,
     audit_rows)``: ``stats`` holds the step's share of the work counters,
     by layer kind: visible (new, cached) pairs and the cached keys a chunk
-    reads (under the window: the newest ``sliding_window`` a token)."""
+    reads (under the window: the newest ``sliding_window`` a token), and
+    the kernel's work items."""
     plan = empty_plan(cfg, caps, len(tenant_ids))
     B, W, n_tok = cfg.block_tokens, cfg.sliding_window, len(tokens)
+    # the sessions' block tables, for the host alone
+    plan["seg_blocks"] = np.zeros((len(segments), cfg.session_blocks),
+                                  np.int32)
     f = seqcommon.fill_token_plan(plan, caps, B, segments, tokens,
                                   tenant_ids, audit)
+    blocks = plan.pop("seg_blocks")
+    wblocks = np.zeros_like(blocks)
     start, n, off, total, seg = (f[k] for k in ("start", "n", "off",
                                                 "total", "seg"))
     for s, (lo, ring) in enumerate(x[5] for x in segments):
-        plan["seg_wblocks"][s, lo:lo + len(ring)] = ring
+        wblocks[s, lo:lo + len(ring)] = ring
     pos = plan["tok_pos"][:n_tok].astype(np.int64)
-    plan["tok_wslot"][:n_tok] = plan["seg_wblocks"][seg, pos // B] * B \
-        + pos % B
-    for name, window in (("pairs", None), ("wpairs", W)):
-        runs, p = ga.pair_runs(start, n, off, B, window), plan[name]
-        P = len(runs["seg"])
-        for k, v in runs.items():
-            p[k][:P] = v
-        p["n_pairs"] = np.int32(P)
-    stats = {"seq_tokens": n_tok,
+    plan["tok_wslot"][:n_tok] = wblocks[seg, pos // B] * B + pos % B
+    n_items = ga.fill_items(plan["items"], start, n, off, blocks, B)
+    ga.fill_items(plan["witems"], start, n, off, wblocks, B, W)
+    stats = {"seq_tokens": n_tok, "full_items": n_items,
+             "swa_items": n_items,
              "full_pairs": int((n * start + n * (n + 1) // 2).sum()),
              "full_keys": int(total.sum()),
              "swa_pairs": int(np.minimum(pos + 1, W).sum()),
@@ -315,7 +314,7 @@ def attention(cfg: SwaMoeConfig, kind: str, lp: dict, u, plan: dict, pool,
     import jax.numpy as jnp
     f32 = jnp.float32
     T = u.shape[0]
-    scope, _, blocks_of, slot_of, pairs_of = KINDS[kind]
+    scope, _, slot_of, items_of = KINDS[kind]
     window = cfg.sliding_window if kind == SWA else None
     dot = lambda a, b, spec: jnp.einsum(spec, a, b,
                                         preferred_element_type=f32)
@@ -326,16 +325,16 @@ def attention(cfg: SwaMoeConfig, kind: str, lp: dict, u, plan: dict, pool,
     pool = pool.at[row0 + slot // cfg.block_tokens,
                    slot % cfg.block_tokens].set(
         jnp.concatenate([k.reshape(T, -1), v.reshape(T, -1)], axis=1))
-    pad = lambda a, fill=0: jnp.concatenate(
-        [a, jnp.full((ga.Q_TILE,) + a.shape[1:], fill, a.dtype)])
     # a NAMED CALL: its name reaches the device ops' metadata, which is
-    # how a trace reduction tells the two kinds' loops apart
+    # how a trace reduction tells the two kinds' kernels apart; and ONE
+    # jitted function for the layers of a kind (the layer's first pool row
+    # is an argument), so that a step's program traces and lowers each
+    # kind's kernel once, not a layer
     o = jax.named_call(
-        lambda q, pos, seg, pool, blocks, pairs: ga.append_attention(
-            q, pos, seg, pool, blocks, pairs, cfg.num_key_value_heads,
-            cfg.head_dim ** -0.5, cfg.block_tokens, window),
-        name=scope)(pad(q), pad(plan["tok_pos"]), pad(plan["tok_seg"], -1),
-                    pool, plan[blocks_of] + row0, plan[pairs_of])[:T]
+        jax.jit(ga.append_attention, static_argnums=(4, 5, 6, 7)),
+        name=scope)(q, pool, plan[items_of], jnp.int32(row0),
+                    cfg.num_key_value_heads, cfg.head_dim ** -0.5,
+                    cfg.block_tokens, window)
     gate = jax.nn.sigmoid(jnp.dot(u, lp["w_g"], preferred_element_type=f32))
     o = (o.astype(f32) * gate[:, :, None]).astype(u.dtype)
     return dot(o, lp["w_o"], "thk,hkd->td"), pool

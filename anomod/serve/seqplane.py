@@ -57,9 +57,11 @@ COUNTERS = ("seq_tokens", "seq_pairs", "seq_absorbed_tokens",
             "expert_tokens_max", "expert_tokens_mean", "sessions_rolled",
             "sessions_evicted", "pool_blocks_held",
             "ssm_recurrent_tokens", "ssm_scan_tokens", "ssm_scan_blocks",
-            "ssm_scan_pairs", "ssm_state_rows", "gqa_pairs", "gqa_keys", "state_slots_held",
+            "ssm_scan_pairs", "ssm_state_rows", "gqa_pairs", "gqa_keys",
+            "gqa_items", "state_slots_held",
             "sessions_evicted_by_slots", "steps_split_by_slots",
-            "full_pairs", "full_keys", "swa_pairs", "swa_keys",
+            "full_pairs", "full_keys", "full_items", "swa_pairs", "swa_keys",
+            "swa_items",
             "win_blocks_held", "win_blocks_freed",
             "sessions_evicted_by_window", "steps_split_by_window")
 #: counters that hold the table's present count, not a sum over steps

@@ -480,6 +480,9 @@ def test_the_plane_steps_the_hybrid_and_replays_from_its_served_log():
     assert c["sessions_rolled"] > 0 and c["sessions_evicted_by_slots"] > 0
     assert c["steps_split_by_slots"] > 0 and c["seq_pairs"] == 0
     assert 0 < c["state_slots_held"] <= 5 and c["gqa_pairs"] > 0
+    # the attention kernel's work items: at least a chunk's one
+    assert c["gqa_items"] >= sum(len(ch) for ch in log)
+    assert c["full_items"] == c["swa_items"] == 0
     assert c["ssm_state_rows"] >= 3 * sum(len(ch) for ch in log)
     policy = ref.SessionPolicy(plane.table.usable, 64, 8, 5)
     want = [s for chunks in log for s in policy.tick(chunks)]
@@ -507,6 +510,9 @@ def test_the_plane_steps_the_hybrid_and_replays_from_its_served_log():
         and k2.table.free_slots is None
     assert set(k2.counters) == set(sp.COUNTERS) and set(k2.state) == {
         "pool", "h_last"}
+    k2.step([_served(0, rng.integers(0, 5 * 16 * 12, 9), 0)])
+    assert k2.counters["seq_tokens"] == 9 and not any(
+        k2.counters[k] for k in ("gqa_items", "full_items", "swa_items"))
     k2.pool = None
     assert k2.state["pool"] is None and k2.h_last is not None
 

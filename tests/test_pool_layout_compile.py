@@ -224,6 +224,17 @@ def test_seq_step_absorbed_form_is_one_mosaic_kernel(one_chip, optimizing):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
 
 
+def mosaic_kernels(text: str, scope: str) -> int:
+    """The Mosaic custom calls of a compiled program whose metadata names
+    the call ``scope``; no loop may be left under that name."""
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and f"/{scope}/" in line]
+    assert all("/pallas_call" in k for k in kernels)
+    assert not re.search(rf'while\([^\n]*op_name="[^"]*/{scope}/', text)
+    return len(kernels)
+
+
 # -- the hybrid model's two kinds of cache (PR 34) ----------------------------
 
 @pytest.mark.parametrize("grid_size", [min, max], ids=["min", "max"])
@@ -236,8 +247,9 @@ def test_hybrid_step_writes_the_slot_pool_and_the_kv_pool_in_place(
     slot pool whole (a state is ``[128, 8192]``, a slot's convolution
     tail one row of 30,720), the recurrence is the Mosaic kernel (PR 35:
     one custom call a Mamba layer under the scan's call name, which is
-    what ``ssm_scan_roofline`` finds it by, and no loop left there), the
-    attention's named call reaches the device ops' metadata, and
+    what ``ssm_scan_roofline`` finds it by, and no loop left there), so is
+    attention (PR 37: one custom call an attention layer under its call
+    name, which is what ``gqa_append_roofline`` finds it by, no loop), and
     everything fits the chip beside the weights."""
     import json
     import os
@@ -283,14 +295,9 @@ def test_hybrid_step_writes_the_slot_pool_and_the_kv_pool_in_place(
     for shape in ("bf16[5,640,128,8192]", "bf16[5,640,30720]",
                   "bf16[3200,30720]", "bf16[524288,512]"):
         assert not re.search(rf"= {re.escape(shape)}[^=\n]* copy\(", text)
-    kernels = [line for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line
-               and f"/{ssm_scan.SCOPE}/" in line]
-    assert len(kernels) == cfg.count("mamba") \
-        and all("/pallas_call" in k for k in kernels)
-    assert not re.search(
-        rf'while\([^\n]*op_name="[^"]*/{ssm_scan.SCOPE}/', text)
-    assert f"/{gqa_attention.SCOPE}/while/body/" in text
+    for scope, layers in ((ssm_scan.SCOPE, cfg.count("mamba")),
+                          (gqa_attention.SCOPE, cfg.count("attn"))):
+        assert mosaic_kernels(text, scope) == layers
     mem = compiled.memory_analysis()
     held = sum(2 * int(np.prod(a.shape)) for a in state.values())
     assert held > 7.4e9 and mem.alias_size_in_bytes >= held
@@ -311,10 +318,11 @@ def test_swa_step_writes_both_kv_pools_in_place(one_chip, optimizing,
     and its real pools, compiled for the chip at the token grid's least
     and largest size: both donated K/V pools come back in their own
     buffers, no op copies a pool whole (a row is 2,048 columns: 16 lane
-    tiles), each kind's attention loops carry their call's name in the
-    device ops' metadata (which is what ``full_append_roofline`` and
-    ``swa_append_roofline`` find them by), and everything fits the chip
-    beside the 7.74 GB of weights."""
+    tiles), each kind's attention is the Mosaic kernel (PR 37: one custom
+    call a layer of the kind under the kind's call name, which is what
+    ``full_append_roofline`` and ``swa_append_roofline`` find it by, and
+    no loop left there), and everything fits the chip beside the 7.74 GB
+    of weights."""
     import json
     import os
 
@@ -360,8 +368,9 @@ def test_swa_step_writes_both_kv_pools_in_place(one_chip, optimizing,
         for shape in (f"bf16[{rows},128,2048]", f"bf16[{rows * 128},2048]"):
             assert not re.search(rf"= {re.escape(shape)}[^=\n]* copy\(",
                                  text), shape
-    for scope in (gqa_attention.SCOPE, gqa_attention.SWA_SCOPE):
-        assert f"/{scope}/while/body/" in text
+    for scope, kind in ((gqa_attention.SCOPE, wm.FULL),
+                        (gqa_attention.SWA_SCOPE, wm.SWA)):
+        assert mosaic_kernels(text, scope) == cfg.count(kind)
     mem = compiled.memory_analysis()
     held = sum(2 * int(np.prod(s.shape)) for s in state.values())
     weights = 2 * 3_869_857_792 + 2 * 4 * 524_288   # the routers in float32

@@ -293,87 +293,157 @@ def _dense_attention(q, k, v, pos, seg, window):
     return out
 
 
-@pytest.mark.parametrize("window", [None, 1, 7, 8, 9, 200])
-def test_the_window_is_exactly_its_keys_with_the_token_itself(window):
-    """``append_attention`` over chunks that start anywhere in a block,
-    walked by ``pair_runs``' list: ``window`` keys, the query's own among
-    them, whatever the place of the edge in a block or a tile."""
-    B, kv, H, d = 8, 2, 4, 16
-    rng = np.random.default_rng(0)
-    start = np.asarray([0, 5, 130, 64])
-    n = np.asarray([70, 3, 1, 90])
+#: a step's chunks ``(start position, tokens)``, blocks of 8 and windows
+#: of ``ga.Q_TILE`` packed tokens: a fresh session longer than a tile; a
+#: chunk that starts inside a block; ONE token deep in a session, inside
+#: a block; a chunk that spans tile edges from a block's edge; one token
+#: of a fresh session; one token on a tile's first row
+STEP_START = np.asarray([0, 5, 130, 64, 0, 11])
+STEP_N = np.asarray([70, 3, 1, 90, 1, 27])
+
+
+def _items_of(window, B=8, n_blocks=32):
+    """The step's work list over a pool in which session ``s`` holds the
+    blocks ``1 + s * n_blocks ..`` in order (block 0 is no session's)."""
+    start, n = STEP_START, STEP_N
     off = np.cumsum(n) - n
-    T = int(n.sum())
-    runs = ga.pair_runs(start, n, off, B, window)
-    P = len(runs["seg"])
-    assert P <= ga.window_pairs_needed(4, T, window or 10 ** 6, B) \
-        or window is None
-    # under a window no run lies wholly before a chunk's first visible key
-    if window is not None:
-        first = np.maximum(start - window + 1, 0) // B
-        assert (runs["blk0"] >= first[runs["seg"]]).all()
-        assert runs["n_tiles"].sum() <= ga.pair_runs(
-            start, n, off, B)["n_tiles"].sum()
-    # every session's earlier keys and this step's, in a pool of its own
-    # blocks (block 0 is no session's)
-    n_blocks = 32
-    table = np.zeros((5, n_blocks), np.int32)
-    pool = np.zeros((1 + 4 * n_blocks, B, 2 * kv * d), np.float32)
-    q = rng.standard_normal((T, H, d)).astype(np.float32)
+    T = -(-int(n.sum()) // ga.Q_TILE) * ga.Q_TILE + ga.Q_TILE
+    table = np.zeros((len(n), n_blocks), np.int32)
+    for s in range(len(n)):
+        held = -(-(start[s] + n[s]) // B)
+        table[s, :held] = 1 + s * n_blocks + np.arange(held)
+    items = ga.empty_items(ga.items_needed(len(n), T),
+                           ga.blocks_needed(n_blocks, B, window))
+    n_items = ga.fill_items(items, start, n, off, table, B, window)
+    return items, n_items, off, table, T
+
+
+@pytest.mark.parametrize("window", [None, 1, 7, 8, 9, 200])
+def test_the_work_list_covers_every_visible_pair_once(window):
+    """Every packed token is a row of exactly one item, whose blocks hold
+    every key the token sees (so every visible (query, key) pair is one
+    item's); under a window no item fetches a block that none of its
+    queries reaches; the static sizes suffice."""
+    B = 8
+    items, n_items, off, table, T = _items_of(window, B)
+    start, n = STEP_START, STEP_N
+    assert n_items == int(items["n_items"]) <= ga.items_needed(len(n), T)
+    assert int(items["n_tokens"]) == n.sum()
+    head = items["table"][:n_items, 0, :ga.HEAD].astype(np.int64)
+    rows = items["table"][:n_items, 0, ga.HEAD:]
+    win = items["win"][:n_items].astype(np.int64)
+    lo, hi, pos0, blk0, nblk, edge = (head[:, k] for k in (
+        ga.LO, ga.HI, ga.POS0, ga.BLK0, ga.NBLK, ga.EDGE))
+    assert (nblk >= 1).all() and nblk.max() <= rows.shape[1] \
+        == ga.blocks_needed(32, B, window)
+    assert rows.shape[1] % ga.KV_BLOCKS == 0 and (
+        rows.shape[1] < 32 or window in (None, 200))
+    assert (0 <= lo).all() and (lo < hi).all() and (hi <= ga.Q_TILE).all()
+    # in token order, a window opened by its first item alone and closed
+    # by its last
+    assert (np.diff(win) >= 0).all()
+    new = (np.diff(win) > 0).tolist()
+    assert (edge & 1).tolist() == [1] + new
+    assert (edge >> 1).tolist() == new + [1]
+    # what the kernel fetches ahead: the next item's first run
+    assert head[:, ga.NEXT_NBLK].tolist() == nblk[1:].tolist() + [0]
+    np.testing.assert_array_equal(head[:-1, ga.NEXT:],
+                                  rows[1:, :ga.KV_BLOCKS])
+    seg = np.repeat(np.arange(len(n)), n)
+    taken = np.zeros(n.sum(), int)
+    for i in range(n_items):
+        tok = win[i] * ga.Q_TILE + np.arange(lo[i], hi[i])
+        taken[tok] += 1
+        s = seg[tok[0]]
+        assert (seg[tok] == s).all()                 # one chunk's rows
+        pos = start[s] + tok - off[s]
+        np.testing.assert_array_equal(pos, pos0[i] + np.arange(lo[i], hi[i]))
+        oldest = 0 if window is None else max(pos[0] - window + 1, 0)
+        # from the block of the oldest key any row sees to the block of
+        # the newest: all of them and, under a window, no other
+        assert blk0[i] == (oldest // B if window is not None else 0)
+        assert blk0[i] + nblk[i] - 1 == pos[-1] // B
+        walked = blk0[i] + np.arange(nblk[i])
+        np.testing.assert_array_equal(rows[i, :nblk[i]], table[s, walked])
+        assert (rows[i, :nblk[i]] > 0).all() and not rows[i, nblk[i]:].any()
+    assert (taken == 1).all()
+
+
+@pytest.mark.parametrize("heads, kv, d", [(12, 2, 16), (8, 1, 16),
+                                          (16, 1, 8)],
+                         ids=["R6", "R8", "R16"])
+@pytest.mark.parametrize("window", [None, 1, 7, 8, 9, 200])
+def test_the_window_is_exactly_its_keys_with_the_token_itself(window, heads,
+                                                              kv, d):
+    """The kernel (under the Pallas interpreter) over chunks that start
+    anywhere in a block and a tile, walked by ``fill_items``' list, a
+    group's heads down the rows: ``window`` keys, the query's own among
+    them, whatever the place of the edge in a block or a tile; rows of no
+    chunk come back zero; a layer's rows are found from ``row0``."""
+    B, H = 8, heads
+    rng = np.random.default_rng(0)
+    start, n = STEP_START, STEP_N
+    items, n_items, off, table, T = _items_of(window, B)
+    n_tok, n_blocks = int(n.sum()), table.shape[1]
+    layer = 1 + len(n) * n_blocks                    # rows of a layer
+    pool = rng.standard_normal((2 * layer, B, 2 * kv * d)).astype(
+        np.float32)
+    q = np.zeros((T, H, d), np.float32)
+    q[:] = rng.standard_normal((T, H, d))            # pads: not zero
     want = np.zeros_like(q)
-    pos, seg = np.zeros(T, np.int32), np.zeros(T, np.int32)
-    for s in range(4):
+    for s in range(len(n)):
         total = start[s] + n[s]
-        table[s, :-(-total // B)] = 1 + s * n_blocks + np.arange(
-            -(-total // B))
         k, v = (rng.standard_normal((total, kv, d)).astype(np.float32)
                 for _ in range(2))
         rows = np.concatenate([k.reshape(total, -1), v.reshape(total, -1)],
                               axis=1)
-        flat_pool = pool[1 + s * n_blocks:1 + (s + 1) * n_blocks].reshape(
+        flat_pool = pool[layer + 1 + s * n_blocks:
+                         layer + 1 + (s + 1) * n_blocks].reshape(
             -1, 2 * kv * d)
         flat_pool[:total] = rows
         sl = slice(off[s], off[s] + n[s])
-        pos[sl], seg[sl] = start[s] + np.arange(n[s]), s
         qs = np.zeros((total, H, d), np.float32)
         qs[start[s]:] = q[sl]
         want[sl] = _dense_attention(
             qs, k, v, np.arange(total), np.zeros(total, int),
             window)[start[s]:]
-    pairs = {k: jnp.asarray(np.concatenate([v, np.zeros(4, np.int64)]),
-                            jnp.int32) for k, v in runs.items()}
-    pairs["n_pairs"] = jnp.int32(P)
-    pad = lambda a, fill=0: jnp.concatenate(
-        [jnp.asarray(a), jnp.full((ga.Q_TILE,) + a.shape[1:], fill,
-                                  a.dtype)])
-    got = jax.jit(lambda *a: ga.append_attention(
-        *a, kv, d ** -0.5, B, window))(
-            pad(q), pad(pos), pad(seg, -1), jnp.asarray(pool),
-            jnp.asarray(table), pairs)
-    np.testing.assert_allclose(np.asarray(got)[:T], want, atol=2e-5)
+    got = np.asarray(jax.jit(lambda q, pool, items, row0: ga.append_attention(
+        q, pool, items, row0, kv, d ** -0.5, B, window))(
+            jnp.asarray(q), jnp.asarray(pool), items, jnp.int32(layer)))
+    assert got.shape == q.shape
+    np.testing.assert_allclose(got[:n_tok], want[:n_tok], atol=2e-5)
+    assert np.abs(want[:n_tok]).max() > 0.1 and not got[n_tok:].any()
 
 
-def test_without_a_window_the_attention_is_the_parents_to_the_bit():
-    """``window=None`` (n3s's call): the same program as before the
-    argument was there, so the same bits."""
+def test_a_step_of_no_token_comes_back_zero():
+    items = ga.empty_items(ga.items_needed(4, 32), 4)
+    q = jnp.ones((32, 4, 16), jnp.float32)
+    pool = jnp.ones((9, 8, 64), jnp.float32)
+    got = ga.append_attention(q, pool, items, 0, 2, 0.25, 8)
+    assert got.shape == (32, 4, 16) and not np.asarray(got).any()
+    with pytest.raises(ValueError, match="not whole"):
+        ga.append_attention(q[:30], pool, items, 0, 2, 0.25, 8)
+    with pytest.raises(ValueError, match="the table holds 4"):
+        ga.fill_items(items, np.asarray([0]), np.asarray([40]),
+                      np.asarray([0]), np.zeros((1, 8), np.int32), 8)
+
+
+def test_without_a_window_the_kernel_is_one_program():
+    """``window=None`` (n3s's call) is the default: the same program with
+    the argument or without, another under a window."""
     B, kv, H, d, T = 8, 2, 4, 16, 64
     ks = jax.random.split(jax.random.PRNGKey(0), 2)
-    q = jax.random.normal(ks[0], (T + ga.Q_TILE, H, d), jnp.bfloat16)
+    q = jax.random.normal(ks[0], (T, H, d), jnp.bfloat16)
     pool = jax.random.normal(ks[1], (9, B, 2 * kv * d), jnp.bfloat16)
-    pos = jnp.concatenate([jnp.arange(T), jnp.zeros(ga.Q_TILE, jnp.int32)])
-    seg = jnp.concatenate([jnp.zeros(T, jnp.int32),
-                           jnp.full(ga.Q_TILE, -1, jnp.int32)])
-    table = jnp.asarray([[1, 2, 3, 4, 5, 6, 7, 8], [0] * 8], jnp.int32)
-    pairs = {"seg": jnp.zeros(2, jnp.int32), "q0": jnp.zeros(2, jnp.int32),
-             "n_tiles": jnp.ones(2, jnp.int32),
-             "blk0": jnp.asarray([0, 4], jnp.int32), "n_pairs": jnp.int32(2)}
-    args = (q, pos, seg, pool, table, pairs, kv, d ** -0.5, B)
-    text = lambda *a: jax.jit(lambda q, pos, seg, pool, table, pairs:
-                              ga.append_attention(
-                                  q, pos, seg, pool, table, pairs, *a)
-                              ).lower(*args[:6]).as_text()
-    assert text(kv, d ** -0.5, B) == text(kv, d ** -0.5, B, None)
-    assert text(kv, d ** -0.5, B) != text(kv, d ** -0.5, B, 9)
+    items = ga.empty_items(ga.items_needed(2, T), 8)
+    ga.fill_items(items, np.asarray([0]), np.asarray([T]), np.asarray([0]),
+                  np.arange(1, 9)[None, :], B)
+    text = lambda *a: jax.jit(
+        lambda q, pool, items: ga.append_attention(
+            q, pool, items, 0, kv, d ** -0.5, B, *a)
+    ).lower(q, pool, items).as_text()
+    assert text() == text(None)
+    assert text() != text(9)
 
 
 # -- rotary tables ------------------------------------------------------------
@@ -775,6 +845,10 @@ def test_the_plane_steps_the_model_and_replays_from_its_served_log(
     assert c["seq_steps"] > len(log)
     assert 0 < c["swa_pairs"] < c["full_pairs"]
     assert 0 < c["swa_keys"] < c["full_keys"]
+    # the kernel's work items, once a step whatever the layers: a (chunk,
+    # window of packed tokens) each, alike under either kind
+    assert c["full_items"] == c["swa_items"] >= c["seq_steps"]
+    assert c["gqa_items"] == 0
     # a window pool this short ends a session before it is full
     assert (c["sessions_rolled"] > 0) == (win_blocks == 36)
     assert c["sessions_evicted_by_window"] > 0 and c["win_blocks_freed"] > 0
