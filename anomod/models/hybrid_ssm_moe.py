@@ -33,12 +33,16 @@ import math
 import numpy as np
 
 from anomod.models import seqcommon
-from anomod.models.seqcommon import rmsnorm
+from anomod.models.seqcommon import MLP_SCOPE, PROJ_SCOPE, rmsnorm
 from anomod.ops import gqa_attention as ga
 from anomod.ops import routed_experts as rx
 from anomod.ops import ssm_scan as ss
 
 MIXERS = {"M": "mamba", "*": "attn", "E": "moe"}
+#: the name of the call that holds a Mamba mixer's causal convolution (the
+#: row gathers of the taps, their sum, the tail's write); one of the
+#: step's parts, as ``seqcommon.PROJ_SCOPE`` is
+CONV_SCOPE = "anomod_seq_conv"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -331,21 +335,37 @@ def mamba_mixer(cfg: HybridConfig, lp: dict, u, plan: dict, ssm, conv,
     T = u.shape[0]
     P, N, G = cfg.mamba_head_dim, cfg.ssm_state_size, cfg.n_groups
     di, C, taps = cfg.d_inner, cfg.conv_dim, cfg.conv_kernel - 1
-    proj = jnp.dot(u, lp["w_in"], preferred_element_type=f32)
-    z, xbc = (proj[:, :di].astype(u.dtype),
-              proj[:, di:di + C].astype(u.dtype))
-    dt = jax.nn.softplus(proj[:, di + C:] + lp["dt_bias"])
     slot = plan["seg_slot"]
-    src = jnp.concatenate([xbc, conv[layer, slot].reshape(-1, C),
-                           jnp.zeros((1, C), u.dtype)])
-    w = lp["conv_w"].astype(f32)
-    acc = lp["conv_b"] + xbc.astype(f32) * w[:, taps]
-    for back in range(1, taps + 1):
-        acc = acc + src[plan["conv_src"][back - 1]].astype(f32) \
-            * w[:, taps - back]
-    conv = conv.at[layer, slot].set(
-        src[plan["tail_src"]].reshape(-1, taps * C))
-    xbc = jax.nn.silu(acc).astype(u.dtype)
+
+    def project(u):
+        proj = jnp.dot(u, lp["w_in"], preferred_element_type=f32)
+        return (proj[:, :di].astype(u.dtype),
+                proj[:, di:di + C].astype(u.dtype),
+                jax.nn.softplus(proj[:, di + C:] + lp["dt_bias"]))
+
+    def convolve(xbc, conv):
+        src = jnp.concatenate([xbc, conv[layer, slot].reshape(-1, C),
+                               jnp.zeros((1, C), u.dtype)])
+        w = lp["conv_w"].astype(f32)
+        acc = lp["conv_b"] + xbc.astype(f32) * w[:, taps]
+        for back in range(1, taps + 1):
+            acc = acc + src[plan["conv_src"][back - 1]].astype(f32) \
+                * w[:, taps - back]
+        conv = seqcommon.write_rows(
+            conv, layer * conv.shape[1] + slot,
+            src[plan["tail_src"]].reshape(-1, taps * C))
+        return jax.nn.silu(acc).astype(u.dtype), conv
+
+    def gate_out(y, x, z):
+        y = y.astype(f32) + jnp.repeat(lp["d"], P) * x.astype(f32)
+        y = (y * jax.nn.silu(z.astype(f32))).reshape(T, G, di // G)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                              + cfg.layer_norm_epsilon)
+        y = (y.reshape(T, di) * lp["gate_norm"]).astype(u.dtype)
+        return jnp.dot(y, lp["w_out"], preferred_element_type=f32)
+
+    z, xbc, dt = jax.named_call(project, name=PROJ_SCOPE)(u)
+    xbc, conv = jax.named_call(convolve, name=CONV_SCOPE)(xbc, conv)
     x = xbc[:, :di]
     # a NAMED CALL: its name reaches the device ops' metadata, which is
     # how a trace reduction finds the recurrence; and ONE jitted function
@@ -356,12 +376,7 @@ def mamba_mixer(cfg: HybridConfig, lp: dict, u, plan: dict, ssm, conv,
             x, xbc[:, di:di + G * N], xbc[:, di + G * N:], dt,
             -jnp.exp(lp["a_log"]), ssm, jnp.int32(layer), plan["work"],
             chunk=cfg.chunk_size)
-    y = y.astype(f32) + jnp.repeat(lp["d"], P) * x.astype(f32)
-    y = (y * jax.nn.silu(z.astype(f32))).reshape(T, G, di // G)
-    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
-                          + cfg.layer_norm_epsilon)
-    y = (y.reshape(T, di) * lp["gate_norm"]).astype(u.dtype)
-    return jnp.dot(y, lp["w_out"], preferred_element_type=f32), ssm, conv
+    return jax.named_call(gate_out, name=PROJ_SCOPE)(y, x, z), ssm, conv
 
 
 def attention_mixer(cfg: HybridConfig, lp: dict, u, plan: dict, pool,
@@ -376,14 +391,18 @@ def attention_mixer(cfg: HybridConfig, lp: dict, u, plan: dict, pool,
     T = u.shape[0]
     dot = lambda a, b, spec: jnp.einsum(spec, a, b,
                                         preferred_element_type=f32)
-    q = dot(u, lp["w_q"], "td,dhk->thk").astype(u.dtype)
-    row = jnp.concatenate([
-        dot(u, lp[k], "td,dgk->tgk").astype(u.dtype).reshape(T, -1)
-        for k in ("w_k", "w_v")], axis=1)
     rows = layer * cfg.pool_blocks
-    slot = plan["tok_slot"]
-    pool = pool.at[rows + slot // cfg.block_tokens,
-                   slot % cfg.block_tokens].set(row)
+
+    def project(u, slot, pool):
+        q = dot(u, lp["w_q"], "td,dhk->thk").astype(u.dtype)
+        row = jnp.concatenate([
+            dot(u, lp[k], "td,dgk->tgk").astype(u.dtype).reshape(T, -1)
+            for k in ("w_k", "w_v")], axis=1)
+        return q, seqcommon.write_rows(
+            pool, rows * cfg.block_tokens + slot, row)
+
+    q, pool = jax.named_call(project, name=PROJ_SCOPE)(
+        u, plan["tok_slot"], pool)
     # a NAMED CALL, and one jitted function for every attention layer:
     # as the scan's
     o = jax.named_call(
@@ -391,15 +410,23 @@ def attention_mixer(cfg: HybridConfig, lp: dict, u, plan: dict, pool,
         name=ga.SCOPE)(q, pool, plan["items"], jnp.int32(rows),
                        cfg.num_key_value_heads, cfg.head_dim ** -0.5,
                        cfg.block_tokens)
-    return dot(o, lp["w_o"], "thk,hkd->td"), pool
+    out = jax.named_call(lambda o: dot(o, lp["w_o"], "thk,hkd->td"),
+                         name=PROJ_SCOPE)(o)
+    return out, pool
 
 
 def relu2_mlp(x, w_1, w_2):
+    import jax
     import jax.numpy as jnp
     f32 = jnp.float32
-    mid = jnp.square(jnp.maximum(
-        jnp.dot(x, w_1, preferred_element_type=f32), 0.0)).astype(x.dtype)
-    return jnp.dot(mid, w_2, preferred_element_type=f32)
+
+    def mlp(x, w_1, w_2):
+        mid = jnp.square(jnp.maximum(
+            jnp.dot(x, w_1, preferred_element_type=f32), 0.0)
+        ).astype(x.dtype)
+        return jnp.dot(mid, w_2, preferred_element_type=f32)
+
+    return jax.named_call(mlp, name=MLP_SCOPE)(x, w_1, w_2)
 
 
 def moe_parts(cfg: HybridConfig, lp: dict, h, valid, capacity: int):
@@ -407,18 +434,23 @@ def moe_parts(cfg: HybridConfig, lp: dict, h, valid, capacity: int):
     per held expert)`` for ``h`` ``[T, D]``, both parts float32 on the
     full width: the router scores ``h``, the held experts work on its
     latent and their weighted sum goes back up once."""
+    import jax
     import jax.numpy as jnp
     f32 = jnp.float32
+    # the latent's down and up projections: this mixer's dense work
+    # around its rounds
+    down = lambda h, w: jnp.dot(h, w, preferred_element_type=f32
+                                ).astype(h.dtype)
+    up = lambda r, w: jnp.dot(r.astype(h.dtype), w,
+                              preferred_element_type=f32)
     experts, weights = rx.route(
         h, lp["router"], lp["router_bias"], cfg.num_experts_per_tok,
         cfg.routed_scaling_factor, cfg.norm_topk_prob)
-    latent = jnp.dot(h, lp["w_dn"], preferred_element_type=f32
-                     ).astype(h.dtype)
+    latent = jax.named_call(down, name=PROJ_SCOPE)(h, lp["w_dn"])
     routed, counts = rx.held_expert_sum(
         latent, experts, weights, valid, rx.relu2, (lp["e_1"], lp["e_2"]),
         cfg.experts_lo, capacity)
-    routed = jnp.dot(routed.astype(h.dtype), lp["w_up"],
-                     preferred_element_type=f32)
+    routed = jax.named_call(up, name=PROJ_SCOPE)(routed, lp["w_up"])
     return routed, relu2_mlp(h, lp["s_1"], lp["s_2"]), counts
 
 

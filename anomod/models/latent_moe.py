@@ -32,6 +32,7 @@ import math
 import numpy as np
 
 from anomod.models import seqcommon
+from anomod.models.seqcommon import MLP_SCOPE, PROJ_SCOPE
 from anomod.models.seqcommon import rmsnorm  # noqa: F401  (the layers' norm)
 from anomod.ops import latent_attention as la
 from anomod.ops import routed_experts as rx
@@ -291,22 +292,26 @@ def attention_block(cfg: DecoderConfig, lp: dict, h, plan: dict, pool,
                                         preferred_element_type=f32)
     pos = plan["tok_pos"]
     amp = _rope_amplitude(cfg)
-    c_q = rmsnorm(dot(h, lp["w_qa"], "td,dr->tr").astype(h.dtype),
-                  lp["q_norm"], cfg.rms_norm_eps)
-    q = dot(c_q, lp["w_qb"], "tr,rhk->thk").astype(h.dtype)
-    q_nope, q_pe = q[..., :nope], rope(q[..., nope:], pos, inv_freq, amp)
-    kva = dot(h, lp["w_kva"], "td,dc->tc").astype(h.dtype)
-    lat = jnp.concatenate([
-        rmsnorm(kva[:, :R], lp["kv_norm"], cfg.rms_norm_eps),
-        rope(kva[:, R:], pos, inv_freq, amp),
-        jnp.zeros((T, cfg.pool_row_width - cfg.latent_width), h.dtype)],
-        axis=1)
     rows = layer * cfg.pool_blocks
-    slot = plan["tok_slot"]
-    pool = pool.at[rows + slot // cfg.block_tokens,
-                   slot % cfg.block_tokens].set(lat)
-    blocks = plan["seg_blocks"] + rows
     scale = softmax_scale(cfg)
+
+    def project(h, pos, slot, pool):
+        c_q = rmsnorm(dot(h, lp["w_qa"], "td,dr->tr").astype(h.dtype),
+                      lp["q_norm"], cfg.rms_norm_eps)
+        q = dot(c_q, lp["w_qb"], "tr,rhk->thk").astype(h.dtype)
+        kva = dot(h, lp["w_kva"], "td,dc->tc").astype(h.dtype)
+        lat = jnp.concatenate([
+            rmsnorm(kva[:, :R], lp["kv_norm"], cfg.rms_norm_eps),
+            rope(kva[:, R:], pos, inv_freq, amp),
+            jnp.zeros((T, cfg.pool_row_width - cfg.latent_width), h.dtype)],
+            axis=1)
+        pool = seqcommon.write_rows(pool, rows * cfg.block_tokens + slot, lat)
+        return q[..., :nope], rope(q[..., nope:], pos, inv_freq, amp), pool
+
+    q_nope, q_pe, pool = jax.named_call(project, name=PROJ_SCOPE)(
+        h, pos, plan["tok_slot"], pool)
+    blocks = plan["seg_blocks"] + rows
+
     def forms(q_nope, q_pe, pos, seg, expanded, pool, blocks, groups, pairs,
               w_kvb):
         # the absorbed form's query at the pool's row width, in its two
@@ -332,17 +337,24 @@ def attention_block(cfg: DecoderConfig, lp: dict, h, plan: dict, pool,
     o = jax.named_call(forms, name=ATTENTION_SCOPE)(
         q_nope, q_pe, pos, plan["tok_seg"], plan["tok_expanded"], pool,
         blocks, plan["groups"], plan["pairs"], lp["w_kvb"])
-    return dot(o, lp["w_o"], "thv,hvd->td").astype(h.dtype), pool
+    out = jax.named_call(
+        lambda o, w_o: dot(o, w_o, "thv,hvd->td").astype(h.dtype),
+        name=PROJ_SCOPE)(o, lp["w_o"])
+    return out, pool
 
 
 def swiglu(x, w_gate, w_up, w_down):
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
-    g = jnp.dot(x, w_gate, preferred_element_type=f32)
-    u = jnp.dot(x, w_up, preferred_element_type=f32)
-    return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), w_down,
-                   preferred_element_type=f32)
+
+    def mlp(x, w_gate, w_up, w_down):
+        g = jnp.dot(x, w_gate, preferred_element_type=f32)
+        u = jnp.dot(x, w_up, preferred_element_type=f32)
+        return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), w_down,
+                       preferred_element_type=f32)
+
+    return jax.named_call(mlp, name=MLP_SCOPE)(x, w_gate, w_up, w_down)
 
 
 def moe_parts(cfg: DecoderConfig, lp: dict, h, valid, capacity: int):

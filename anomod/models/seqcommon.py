@@ -15,6 +15,19 @@ import math
 import numpy as np
 
 
+#: call names the models' steps run their parts under, beside the kernels'
+#: own (``latent_moe.ATTENTION_SCOPE``, ``ssm_scan.SCOPE``, ``gqa_attention
+#: .SCOPE`` / ``.SWA_SCOPE``) and the experts' (``routed_experts.ROUTE_SCOPE``
+#: / ``.ROUNDS_SCOPE``): every device op of a part carries its name in the
+#: trace's op metadata, which is how a reduction prices the step by part.
+#: No part encloses another.  A mixer's dense work around its kernel:
+PROJ_SCOPE = "anomod_seq_proj"
+#: the dense MLP and the shared expert
+MLP_SCOPE = "anomod_seq_mlp"
+#: :func:`score_step`
+HEAD_SCOPE = "anomod_seq_head"
+
+
 def flat_spec(d: dict) -> dict:
     """A configuration file's object flattened for a model's dataclass:
     the public keys at the top level, the sizes this repo set under
@@ -36,6 +49,17 @@ def rmsnorm(x, w, eps: float):
     xf = x.astype(jnp.float32)
     y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (y * w).astype(x.dtype)
+
+
+def write_rows(pool, rows, values):
+    """``pool`` ``[..., width]`` with ``values`` ``[n, width]`` written at
+    ``rows`` of its leading dimensions flattened (in place where the pool
+    is donated).  The TPU compiler rewrites a row write on two indices
+    into this scatter on one, and the rewritten op carries no metadata;
+    written so here, the write's device op keeps the name of the call it
+    runs under."""
+    flat = pool.reshape((-1,) + pool.shape[-1:])
+    return flat.at[rows].set(values).reshape(pool.shape)
 
 
 # -- the seeded draw ----------------------------------------------------------
@@ -183,6 +207,13 @@ def score_step(x, params: dict, h_last, plan: dict, eps: float,
     context is the packed token before it, its session's last hidden state
     of an earlier step (``h_last``, final-normed), or nothing: a session's
     first token reads ``log(vocab_held)``."""
+    import jax
+    return jax.named_call(_score_step, name=HEAD_SCOPE)(
+        x, params, h_last, plan, eps, vocab_held)
+
+
+def _score_step(x, params: dict, h_last, plan: dict, eps: float,
+                vocab_held: int):
     import jax
     import jax.numpy as jnp
     T = plan["tok_id"].shape[0]

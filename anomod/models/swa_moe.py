@@ -39,7 +39,7 @@ import math
 import numpy as np
 
 from anomod.models import seqcommon
-from anomod.models.seqcommon import rmsnorm
+from anomod.models.seqcommon import MLP_SCOPE, PROJ_SCOPE, rmsnorm
 from anomod.ops import gqa_attention as ga
 from anomod.ops import routed_experts as rx
 
@@ -318,13 +318,22 @@ def attention(cfg: SwaMoeConfig, kind: str, lp: dict, u, plan: dict, pool,
     window = cfg.sliding_window if kind == SWA else None
     dot = lambda a, b, spec: jnp.einsum(spec, a, b,
                                         preferred_element_type=f32)
-    q = rotate(dot(u, lp["w_q"], "td,dhk->thk"), *rope).astype(u.dtype)
-    k = rotate(dot(u, lp["w_k"], "td,dgk->tgk"), *rope).astype(u.dtype)
-    v = dot(u, lp["w_v"], "td,dgk->tgk").astype(u.dtype)
-    slot = plan[slot_of]
-    pool = pool.at[row0 + slot // cfg.block_tokens,
-                   slot % cfg.block_tokens].set(
-        jnp.concatenate([k.reshape(T, -1), v.reshape(T, -1)], axis=1))
+
+    def project(u, slot, pool):
+        q = rotate(dot(u, lp["w_q"], "td,dhk->thk"), *rope).astype(u.dtype)
+        k = rotate(dot(u, lp["w_k"], "td,dgk->tgk"), *rope).astype(u.dtype)
+        v = dot(u, lp["w_v"], "td,dgk->tgk").astype(u.dtype)
+        return q, seqcommon.write_rows(
+            pool, row0 * cfg.block_tokens + slot,
+            jnp.concatenate([k.reshape(T, -1), v.reshape(T, -1)], axis=1))
+
+    def gate_out(o, u):
+        gate = jax.nn.sigmoid(jnp.dot(u, lp["w_g"],
+                                      preferred_element_type=f32))
+        o = (o.astype(f32) * gate[:, :, None]).astype(u.dtype)
+        return dot(o, lp["w_o"], "thk,hkd->td")
+
+    q, pool = jax.named_call(project, name=PROJ_SCOPE)(u, plan[slot_of], pool)
     # a NAMED CALL: its name reaches the device ops' metadata, which is
     # how a trace reduction tells the two kinds' kernels apart; and ONE
     # jitted function for the layers of a kind (the layer's first pool row
@@ -335,18 +344,21 @@ def attention(cfg: SwaMoeConfig, kind: str, lp: dict, u, plan: dict, pool,
         name=scope)(q, pool, plan[items_of], jnp.int32(row0),
                     cfg.num_key_value_heads, cfg.head_dim ** -0.5,
                     cfg.block_tokens, window)
-    gate = jax.nn.sigmoid(jnp.dot(u, lp["w_g"], preferred_element_type=f32))
-    o = (o.astype(f32) * gate[:, :, None]).astype(u.dtype)
-    return dot(o, lp["w_o"], "thk,hkd->td"), pool
+    return jax.named_call(gate_out, name=PROJ_SCOPE)(o, u), pool
 
 
 def swiglu(x, w_gate, w_up, w_down):
     import jax
     import jax.numpy as jnp
     f32 = jnp.float32
-    mid = (jax.nn.silu(jnp.dot(x, w_gate, preferred_element_type=f32))
-           * jnp.dot(x, w_up, preferred_element_type=f32)).astype(x.dtype)
-    return jnp.dot(mid, w_down, preferred_element_type=f32)
+
+    def mlp(x, w_gate, w_up, w_down):
+        mid = (jax.nn.silu(jnp.dot(x, w_gate, preferred_element_type=f32))
+               * jnp.dot(x, w_up, preferred_element_type=f32)
+               ).astype(x.dtype)
+        return jnp.dot(mid, w_down, preferred_element_type=f32)
+
+    return jax.named_call(mlp, name=MLP_SCOPE)(x, w_gate, w_up, w_down)
 
 
 def moe_parts(cfg: SwaMoeConfig, lp: dict, h, valid, capacity: int):
@@ -372,6 +384,7 @@ def append_step(cfg: SwaMoeConfig, params: dict, state: dict, plan: dict):
     has no context and reads ``log(vocab_held)``.  The experts take a
     step's token-expert pairs in ONE round (``capacity`` is all of
     them)."""
+    import jax
     import jax.numpy as jnp
     T = plan["tok_id"].shape[0]
     eps = cfg.rms_norm_eps
@@ -381,10 +394,15 @@ def append_step(cfg: SwaMoeConfig, params: dict, state: dict, plan: dict):
     valid = plan["tok_seg"] >= 0
     pos = plan["tok_pos"].astype(jnp.float32)
     ropes = {}
-    for kind in set(cfg.layer_types):
+    # in the order of first appearance: a set's order changes with the
+    # process's string hashing, and with it the lowered module's text and
+    # its compile-cache key (PR 38: two programs for one step, a cold
+    # ~40 s compile on the runs that drew the other order)
+    for kind in dict.fromkeys(cfg.layer_types):
         freq, amp = rope_table(cfg.rope_parameters[kind], cfg.head_dim)
-        ang = pos[:, None] * jnp.asarray(freq)
-        ropes[kind] = (jnp.cos(ang) * amp, jnp.sin(ang) * amp)
+        ropes[kind] = jax.named_call(
+            lambda ang, amp=amp: (jnp.cos(ang) * amp, jnp.sin(ang) * amp),
+            name=PROJ_SCOPE)(pos[:, None] * jnp.asarray(freq))
     x = params["embed"][plan["tok_id"]]
     at = dict.fromkeys(KINDS, 0)               # a layer's row of its pool
     counts = []

@@ -1737,6 +1737,7 @@ class ServeEngine:
             self._obs_ticks.inc()
             self._obs_tenants.set(len(self._tenant_det)
                                   or len(self._tenant_replay))
+            self.admission.observe_depths()
             if self.clock.ticks % self._scrape_every == 0:
                 self._registry.scrape(now_s=now)
         t_tick = time.perf_counter() - t_wall
@@ -1842,6 +1843,7 @@ class ServeEngine:
             self._obs_ticks.inc()
             self._obs_tenants.set(len(self._tenant_det)
                                   or len(self._tenant_replay))
+            self.admission.observe_depths()
             if self.clock.ticks % self._scrape_every == 0:
                 self._registry.scrape(now_s=now)
         t_tick = time.perf_counter() - t_wall

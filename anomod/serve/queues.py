@@ -390,7 +390,11 @@ class AdmissionController:
         self._obs_tenant_backlog = obs.gauge(
             "anomod_serve_max_tenant_backlog_spans")
 
-    def _obs_depths(self) -> None:
+    def observe_depths(self) -> None:
+        """Set the two depth gauges from the queue as it stands.  The
+        engine calls this once a tick where the registry is scraped
+        (``serve.scrape``): the deepest queue is a walk over every tenant
+        seen, which an offer or a drain does not pay."""
         self._obs_backlog.set(self.backlog_spans)
         self._obs_tenant_backlog.set(
             max(self._tenant_backlog.values(), default=0))
@@ -490,7 +494,6 @@ class AdmissionController:
         c.admitted_spans += n
         self._tot.admitted_spans += n
         self._obs_admitted.inc(n)
-        self._obs_depths()
         return True
 
     def _pop_eviction_candidate(self, incoming_priority: int):
@@ -562,8 +565,6 @@ class AdmissionController:
                 self._tot.served_batches += 1
                 self._obs_served.inc(qb.n_spans)
                 out.append(qb)
-            if out:
-                self._obs_depths()
             return out
         out: List[QueuedBatch] = []
         remaining = float(budget_spans)
@@ -585,8 +586,6 @@ class AdmissionController:
             self._tot.served_batches += 1
             self._obs_served.inc(qb.n_spans)
             out.append(qb)
-        if out:
-            self._obs_depths()
         return out
 
     # -- report helpers ---------------------------------------------------

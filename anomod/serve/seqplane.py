@@ -63,7 +63,8 @@ COUNTERS = ("seq_tokens", "seq_pairs", "seq_absorbed_tokens",
             "full_pairs", "full_keys", "full_items", "swa_pairs", "swa_keys",
             "swa_items",
             "win_blocks_held", "win_blocks_freed",
-            "sessions_evicted_by_window", "steps_split_by_window")
+            "sessions_evicted_by_window", "steps_split_by_window",
+            "seq_plan_bytes", "seq_fetch_bytes")
 #: counters that hold the table's present count, not a sum over steps
 GAUGES = ("sessions_rolled", "sessions_evicted", "pool_blocks_held",
           "state_slots_held", "sessions_evicted_by_slots",
@@ -546,11 +547,29 @@ class SeqPlane:
         for t in self.grid:
             self._run(self.model.empty_plan(self.caps(t), self.n_tenants))
 
-    def _run(self, plan: dict):
+    def _run(self, plan: dict, step: int = 0, tokens: int = 0,
+             audit_rows: bool = False):
+        """One step on the device in three spans: the plan to the device
+        and the dispatch (``serve.seq_issue``), the host waiting for the
+        device (``serve.seq_wait``), the answers onto the host
+        (``serve.seq_fetch``; the audit logits only where the step has
+        ``audit_rows``).  Returns ``(surprisal, tokens per held expert)``
+        as numpy, the audit logits as a third where asked for."""
         import jax
-        self.state, surprisal, audit, counts = self._step(
-            self.params, self.state, jax.device_put(plan))
-        return np.asarray(surprisal), audit, np.asarray(counts)
+        tags = {"step": step, "grid": len(plan["tok_id"]), "tokens": tokens}
+        sent = sum(a.nbytes for a in jax.tree_util.tree_leaves(plan))
+        with span_of(self.tracer, "serve.seq_issue", bytes=sent, **tags):
+            self.state, surprisal, audit, counts = self._step(
+                self.params, self.state, jax.device_put(plan))
+        with span_of(self.tracer, "serve.seq_wait", **tags):
+            jax.block_until_ready((self.state, surprisal, audit, counts))
+        wanted = (surprisal, counts, audit)[:3 if audit_rows else 2]
+        got = sum(a.nbytes for a in wanted)
+        with span_of(self.tracer, "serve.seq_fetch", bytes=got, **tags):
+            on_host = tuple(np.asarray(a) for a in wanted)
+        self.counters["seq_plan_bytes"] += sent
+        self.counters["seq_fetch_bytes"] += got
+        return on_host
 
     def close(self) -> None:
         """Free the device state (the pools first)."""
@@ -586,13 +605,13 @@ class SeqPlane:
                 at += n
         with span_of(self.tracer, "serve.seq_model", steps=len(steps)):
             surprisal = []
-            for plan, stats, audit_rows, pad in steps:
-                got, audit, ecounts = self._run(plan)
+            for number, (plan, stats, audit_rows, pad) in enumerate(steps):
+                got, ecounts, *rows = self._run(
+                    plan, number, stats["seq_tokens"], bool(audit_rows))
                 surprisal.append(got[:stats["seq_tokens"]])
                 if audit_rows:
-                    rows = np.asarray(audit)
                     self.audit_logits.extend(
-                        (t, s, p, rows[i])
+                        (t, s, p, rows[0][i])
                         for i, (t, s, p) in enumerate(audit_rows))
                 for k, v in stats.items():
                     c[k] += v

@@ -182,6 +182,11 @@ def test_seq_step_writes_the_latent_pool_in_place(one_chip, optimizing):
     # the attention kernels' device ops carry their call's name in the
     # metadata a trace keeps (what `mla_append_roofline` finds them by)
     assert f'/{lm.ATTENTION_SCOPE}/while/body/' in compiled.as_text()
+    from anomod.models import seqcommon
+    flat = f"bf16[{pool_shape[0] * pool_shape[1] * pool_shape[2]},640]"
+    writes = pool_writes(compiled.as_text(), flat)
+    assert writes and all(
+        w.endswith(f"/{seqcommon.PROJ_SCOPE}/scatter") for w in writes)
     pool_bytes = 2 * int(np.prod(pool_shape))
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // 2
@@ -222,6 +227,18 @@ def test_seq_step_absorbed_form_is_one_mosaic_kernel(one_chip, optimizing):
     # chip's 16.9e9 with 0.5e9 left for the sketch planes' state
     assert mem.temp_size_in_bytes < 0.55 * pool_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.4e9
+
+
+def pool_writes(text: str, shape: str) -> list:
+    """The ``op_name`` of every fusion of a compiled program whose result
+    is the flat pool ``shape``: the in-place row writes.  Written on one
+    flattened index (``seqcommon.write_rows``, PR 38) the scatter keeps
+    its call's name; a write on two indices is rewritten by the compiler
+    into an op with no metadata, which no trace reduction can place."""
+    lines = [line for line in text.splitlines()
+             if re.search(rf"= {re.escape(shape)}[^=\n]* fusion\(", line)]
+    return [(re.search(r'op_name="([^"]*)"', line) or [None, ""])[1]
+            for line in lines]
 
 
 def mosaic_kernels(text: str, scope: str) -> int:
@@ -298,6 +315,13 @@ def test_hybrid_step_writes_the_slot_pool_and_the_kv_pool_in_place(
     for scope, layers in ((ssm_scan.SCOPE, cfg.count("mamba")),
                           (gqa_attention.SCOPE, cfg.count("attn"))):
         assert mosaic_kernels(text, scope) == layers
+    from anomod.models import seqcommon
+    for shape, scope, layers in (
+            ("bf16[3200,30720]", hm.CONV_SCOPE, cfg.count("mamba")),
+            ("bf16[524288,512]", seqcommon.PROJ_SCOPE, cfg.count("attn"))):
+        writes = pool_writes(text, shape)
+        assert len(writes) >= layers and all(
+            w.endswith(f"/{scope}/scatter") for w in writes), writes
     mem = compiled.memory_analysis()
     held = sum(2 * int(np.prod(a.shape)) for a in state.values())
     assert held > 7.4e9 and mem.alias_size_in_bytes >= held
@@ -368,6 +392,10 @@ def test_swa_step_writes_both_kv_pools_in_place(one_chip, optimizing,
         for shape in (f"bf16[{rows},128,2048]", f"bf16[{rows * 128},2048]"):
             assert not re.search(rf"= {re.escape(shape)}[^=\n]* copy\(",
                                  text), shape
+        writes = pool_writes(text, f"bf16[{rows * 128},2048]")
+        from anomod.models import seqcommon
+        assert writes and all(
+            w.endswith(f"/{seqcommon.PROJ_SCOPE}/scatter") for w in writes)
     for scope, kind in ((gqa_attention.SCOPE, wm.FULL),
                         (gqa_attention.SWA_SCOPE, wm.SWA)):
         assert mosaic_kernels(text, scope) == cfg.count(kind)

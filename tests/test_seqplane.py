@@ -2,6 +2,10 @@
 tokeniser, the session policy against the reference's replay, the
 sketch planes untouched by the plane, its spans, counters and refusals."""
 
+import contextlib
+import types
+
+import jax
 import numpy as np
 import pytest
 
@@ -123,3 +127,95 @@ def test_a_vocabulary_slice_too_small_for_the_tokeniser_is_refused():
     with pytest.raises(ValueError, match="event ids"):
         run_power_law(seq_model=dict(SPEC, vocab_held=256, vocab_size=256),
                       **RUN)
+
+
+class Recording:
+    """A tracer that keeps every span's opening and closing in order."""
+
+    def __init__(self):
+        self.events = []
+
+    @contextlib.contextmanager
+    def span(self, name, **tags):
+        self.events.append(("open", name, tags))
+        try:
+            yield
+        finally:
+            self.events.append(("close", name, tags))
+
+
+def _tick_of(rng, n_tenants, n_spans):
+    """One tick's served batches: ``n_spans`` spans over the tenants."""
+    served, left = [], n_spans
+    for t in range(n_tenants):
+        n = left if t == n_tenants - 1 else int(rng.integers(1, left // 2))
+        left -= n
+        served.append(types.SimpleNamespace(
+            tenant_id=t, n_spans=n, spans=types.SimpleNamespace(
+                service=rng.integers(0, 8, n),
+                duration_us=rng.integers(1, 10 ** 6, n),
+                status=rng.choice([200, 404, 500], n),
+                kind=rng.integers(0, 3, n),
+                start_us=np.sort(rng.integers(0, 10 ** 7, n)))))
+    return served
+
+
+def test_a_step_is_issue_wait_and_fetch_inside_seq_model_and_changes_nothing():
+    spec = dict(SPEC, audit_tenants=[0, 3],
+                assumed=dict(SPEC["assumed"], token_grid=[64]))
+    rec = Recording()
+    traced, plain = (sp.SeqPlane(spec, range(6), 8, 16, 5_000_000, tracer=t)
+                     for t in (rec, None))
+    plans = []
+    build = traced.model.build_plan
+    traced.model.build_plan = lambda *a: plans.append(build(*a)) or plans[-1]
+    rng = np.random.default_rng(7)
+    n_steps = fetched = 0
+    for _ in range(3):
+        served = _tick_of(rng, 6, 150)
+        at = len(rec.events)
+        traced.step(served)
+        plain.step(served)
+        assert traced.tick_doc == plain.tick_doc
+        assert traced.tick_doc["steps"] == 3        # 150 tokens, 64 a step
+        events = rec.events[at:]
+        names = [(what, name) for what, name, _ in events]
+        leaf = [(w, "serve.seq_" + part)
+                for part in ("issue", "wait", "fetch")
+                for w in ("open", "close")]
+        assert names == (
+            [("open", "serve.seq_stage"), ("close", "serve.seq_stage"),
+             ("open", "serve.seq_model")] + 3 * leaf
+            + [("close", "serve.seq_model"), ("open", "serve.seq_score"),
+               ("close", "serve.seq_score")])
+        opened = [(name, tags) for what, name, tags in events
+                  if what == "open" and name[6:] in ("seq_issue", "seq_wait",
+                                                     "seq_fetch")]
+        for i, (name, tags) in enumerate(opened):
+            plan, stats, audit_rows = plans[n_steps + i // 3]
+            assert (tags["step"], tags["grid"], tags["tokens"]) \
+                == (i // 3, 64, stats["seq_tokens"])
+            if name == "serve.seq_issue":
+                assert tags["bytes"] == sum(
+                    a.nbytes for a in jax.tree_util.tree_leaves(plan))
+            elif name == "serve.seq_fetch":
+                # surprisals and expert counts; the audit logits only
+                # where the step holds an audit tenant's last token
+                assert tags["bytes"] == 64 * 4 + 2 * 4 * 4 \
+                    + (64 * 2048 * 4 if audit_rows else 0)
+                fetched += tags["bytes"]
+        n_steps += 3
+    c = traced.counters
+    assert c["seq_steps"] == n_steps == len(plans) == 9
+    assert c["seq_plan_bytes"] == sum(
+        a.nbytes for plan, *_ in plans
+        for a in jax.tree_util.tree_leaves(plan)) > 0
+    assert c["seq_fetch_bytes"] == fetched > 9 * 64 * 4
+    # to the bit what the plane without a tracer scores
+    assert c == plain.counters
+    assert list(traced.scores) == list(plain.scores) and traced.scores
+    assert len(traced.audit_logits) == len(plain.audit_logits) > 0
+    for a, b in zip(traced.audit_logits, plain.audit_logits):
+        assert a[:3] == b[:3] and a[3].tobytes() == b[3].tobytes()
+    for a, b in zip(traced.audit_segments, plain.audit_segments):
+        assert a[4].tobytes() == b[4].tobytes()
