@@ -299,7 +299,10 @@ def build_plan(cfg: BlockDiffusionConfig, caps: dict, segments: list,
              "bd_prefix_pairs": int((lim + 1).sum()),
              "bd_prefix_keys": int(np.maximum.reduceat(lim, off).sum()
                                    + S),
-             "bd_inblock_pairs": R * L, "bd_items": n_items}
+             "bd_inblock_pairs": R * L, "bd_items": n_items,
+             "moe_one_round_layers": cfg.num_hidden_layers * rx.one_round(
+                 caps["tokens"], cfg.num_experts_per_tok,
+                 expert_capacity(cfg, caps["tokens"]))}
     return plan, stats, audit_rows
 
 
@@ -429,6 +432,12 @@ def head_scores(cfg: BlockDiffusionConfig, params: dict, x, plan: dict):
         x, params, plan["score_row"], plan["score_tok"], plan["audit"])
 
 
+def expert_capacity(cfg: BlockDiffusionConfig, rows: int) -> int:
+    """Rows a round of the routed experts takes in a step of ``rows``
+    packed rows: every pair of the step."""
+    return rows * cfg.num_experts_per_tok
+
+
 def append_step(cfg: BlockDiffusionConfig, params: dict, state: dict,
                 plan: dict):
     """One forward over the packed rows of a step.
@@ -465,7 +474,7 @@ def append_step(cfg: BlockDiffusionConfig, params: dict, state: dict,
         y, n = rx.held_expert_sum(
             u, experts, weights, valid, rx.gated_silu,
             (lp["e_gate"], lp["e_up"], lp["e_down"]), cfg.experts_lo,
-            T * cfg.num_experts_per_tok)
+            expert_capacity(cfg, T))
         counts.append(n)
         x = x + y.astype(x.dtype)
     surprisal, audit = head_scores(cfg, params, x, plan)

@@ -298,7 +298,9 @@ def build_plan(cfg: HybridConfig, caps: dict, segments: list,
     stats.update(seq_tokens=n_tok,
                  gqa_pairs=int((n * start + n * (n + 1) // 2).sum()),
                  gqa_keys=int(total.sum()), gqa_items=n_items,
-                 ssm_state_rows=S * cfg.count("mamba"))
+                 ssm_state_rows=S * cfg.count("mamba"),
+                 moe_one_round_layers=cfg.count("moe") * rx.one_round(
+                     T, cfg.num_experts_per_tok, expert_capacity(cfg, T)))
     return plan, stats, f["audit_rows"]
 
 
@@ -429,6 +431,13 @@ def relu2_mlp(x, w_1, w_2):
     return jax.named_call(mlp, name=MLP_SCOPE)(x, w_1, w_2)
 
 
+def expert_capacity(cfg: HybridConfig, tokens: int) -> int:
+    """Rows a round of the routed experts takes in a step of ``tokens``
+    packed tokens."""
+    del cfg
+    return max(tokens, 8)
+
+
 def moe_parts(cfg: HybridConfig, lp: dict, h, valid, capacity: int):
     """``(routed part of the held experts, shared expert's part, tokens
     per held expert)`` for ``h`` ``[T, D]``, both parts float32 on the
@@ -486,7 +495,8 @@ def append_step(cfg: HybridConfig, params: dict, state: dict, plan: dict):
         elif kind == "attn":
             y, pool = attention_mixer(cfg, lp, u, plan, pool, i)
         else:
-            routed, shared, n = moe_parts(cfg, lp, u, valid, max(T, 8))
+            routed, shared, n = moe_parts(cfg, lp, u, valid,
+                                          expert_capacity(cfg, T))
             y = routed + shared
             counts.append(n)
         x = x + y.astype(x.dtype)

@@ -357,6 +357,13 @@ def swiglu(x, w_gate, w_up, w_down):
     return jax.named_call(mlp, name=MLP_SCOPE)(x, w_gate, w_up, w_down)
 
 
+def expert_capacity(cfg: DecoderConfig, tokens: int) -> int:
+    """Rows a round of the routed experts takes in a step of ``tokens``
+    packed tokens."""
+    del cfg
+    return max(tokens // 2, 8)
+
+
 def moe_parts(cfg: DecoderConfig, lp: dict, h, valid, capacity: int):
     """``(routed part of the held experts, shared experts' part, tokens
     per held expert)`` for ``h`` ``[T, D]``, both parts float32."""
@@ -387,7 +394,7 @@ def append_step(cfg: DecoderConfig, params: dict, pool, h_last, plan: dict):
     pool = pool.reshape((-1,) + shape[2:])
     inv_freq = rope_inv_freq(cfg)
     valid = plan["tok_seg"] >= 0
-    capacity = max(T // 2, 8)
+    capacity = expert_capacity(cfg, T)
     x = params["embed"][plan["tok_id"]]
 
     def layer(kind):

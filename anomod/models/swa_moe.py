@@ -261,6 +261,7 @@ def build_plan(cfg: SwaMoeConfig, caps: dict, segments: list,
     the kernel's work items."""
     plan = empty_plan(cfg, caps, len(tenant_ids))
     B, W, n_tok = cfg.block_tokens, cfg.sliding_window, len(tokens)
+    T = caps["tokens"]
     # the sessions' block tables, for the host alone
     plan["seg_blocks"] = np.zeros((len(segments), cfg.session_blocks),
                                   np.int32)
@@ -281,7 +282,10 @@ def build_plan(cfg: SwaMoeConfig, caps: dict, segments: list,
              "full_pairs": int((n * start + n * (n + 1) // 2).sum()),
              "full_keys": int(total.sum()),
              "swa_pairs": int(np.minimum(pos + 1, W).sum()),
-             "swa_keys": int((total - np.maximum(start - W + 1, 0)).sum())}
+             "swa_keys": int((total - np.maximum(start - W + 1, 0)).sum()),
+             "moe_one_round_layers": cfg.mlp_layer_types.count("sparse")
+             * rx.one_round(T, cfg.num_experts_per_tok,
+                            expert_capacity(cfg, T))}
     return plan, stats, f["audit_rows"]
 
 
@@ -361,6 +365,12 @@ def swiglu(x, w_gate, w_up, w_down):
     return jax.named_call(mlp, name=MLP_SCOPE)(x, w_gate, w_up, w_down)
 
 
+def expert_capacity(cfg: SwaMoeConfig, tokens: int) -> int:
+    """Rows a round of the routed experts takes in a step of ``tokens``
+    packed tokens: every pair of the step."""
+    return tokens * cfg.num_experts_per_tok
+
+
 def moe_parts(cfg: SwaMoeConfig, lp: dict, h, valid, capacity: int):
     """``(routed part of the held experts, shared expert's part, tokens
     per held expert)`` for ``h`` ``[T, D]``, both parts float32."""
@@ -420,7 +430,7 @@ def append_step(cfg: SwaMoeConfig, params: dict, state: dict, plan: dict):
             y = swiglu(u, lp["w_gate"], lp["w_up"], lp["w_down"])
         else:
             routed, shared, n = moe_parts(
-                cfg, lp, u, valid, T * cfg.num_experts_per_tok)
+                cfg, lp, u, valid, expert_capacity(cfg, T))
             y = routed + shared
             counts.append(n)
         x = x + y.astype(x.dtype)

@@ -52,6 +52,7 @@ import numpy as np
 from anomod.models import latent_moe as lm
 from anomod.models import seqcommon
 from anomod.ops import latent_attention as la
+from anomod.ops import routed_experts as rx
 from anomod.utils.tracing import span_of
 
 #: every plane counts all of these; a model's own read 0 under the other
@@ -71,7 +72,7 @@ COUNTERS = ("seq_tokens", "seq_pairs", "seq_absorbed_tokens",
             "seq_plan_bytes", "seq_fetch_bytes",
             "bd_round_rows", "bd_commit_rows", "bd_blocks_committed",
             "bd_pending_tokens", "bd_prefix_pairs", "bd_prefix_keys",
-            "bd_inblock_pairs", "bd_items")
+            "bd_inblock_pairs", "bd_items", "moe_one_round_layers")
 #: counters that hold the table's present count, not a sum over steps
 GAUGES = ("sessions_rolled", "sessions_evicted", "pool_blocks_held",
           "state_slots_held", "sessions_evicted_by_slots",
@@ -391,13 +392,17 @@ def build_plan(cfg, caps: dict, segments: list, tokens: np.ndarray,
         g["seg"][:G], g["nblk"][:G] = g_seg[order], g_nblk[order]
         g["n_groups"] = np.int32(G)
     pairs_of = n * start + n * (n + 1) // 2
+    T = caps["tokens"]
     return plan, {"seq_tokens": n_tok, "seq_pairs": int(pairs_of.sum()),
                   "seq_absorbed_tokens": int(n[~expanded].sum()),
                   "seq_absorbed_pairs": int(pairs_of[~expanded].sum()),
                   "seq_absorbed_group_blocks":
                       int(plan["groups"]["nblk"].sum()),
                   "seq_expanded_keys": int(total[expanded].sum()),
-                  "seq_keys": int(total.sum())}, f["audit_rows"]
+                  "seq_keys": int(total.sum()),
+                  "moe_one_round_layers": cfg.n_moe * rx.one_round(
+                      T, cfg.num_experts_per_tok,
+                      lm.expert_capacity(cfg, T))}, f["audit_rows"]
 
 
 class LatentMoE:
